@@ -1,7 +1,7 @@
 # Dev targets (the reference Makefile:1-15 has only release/docker; we add
 # the working set).
 
-.PHONY: test test-core test-pallas test-mesh-fused test-fused-staging test-snapshot test-qos test-obs test-chaos test-analytics test-overlap test-chain test-frontdoor test-tiers test-devprof test-algorithms proto bench bench-smoke docker lint cluster
+.PHONY: test test-core test-pallas test-mesh-fused test-fused-staging test-snapshot test-qos test-obs test-chaos test-analytics test-overlap test-chain test-frontdoor test-tiers test-devprof test-algorithms proto bench chip-smoke bench-smoke docker lint cluster
 
 test:
 	python -m pytest tests/ -x -q
@@ -111,31 +111,35 @@ test-algorithms:
 proto:
 	cd gubernator_tpu/api/proto && protoc --python_out=. gubernator.proto peers.proto
 
+# On the machine with the chip: one process, fails if the device is not a
+# TPU, exits non-zero when a tier raises.
 bench:
 	python bench.py
 
-# bench-regression gate: fresh CPU smoke run of bench.py diffed against
-# the best prior BENCH_r*.json cpu numbers (10% noise floor); fails loudly
-# when e2e/device/host decisions-per-sec regress.  Then the open-loop
-# overlap probe prints the pipeline's stage split + realized overlap, and
-# a short front-door sweep (in-process baseline vs 2 acceptor workers)
-# reports e2e decisions/s + shm ring stall % through the worker path.
-# Finally the chain probe sweeps the deferred-fetch stride (raw link +
-# simulated tunnel RTT) and prints the device-tier vs serving-drain
-# reconciliation (kernel census + per-dispatch wall), and the tier probe
-# sweeps arena fraction under Zipf traffic (warm hit rate, promotions/s,
-# window p99, tiers-on vs tiers-off).  The trace-overhead probe closes
-# the loop: it asserts the continuous device profiler (GUBER_DEVPROF=
-# periodic) costs <2% of the untraced serving rate.
+# The quickest proof the system still starts on the chip (run it through
+# the chip tool; `python chip_smoke.py --chips 4` is the cross-chip path).
+# Here, without a chip, it must fail: `JAX_PLATFORMS=cpu python
+# chip_smoke.py --tiny` rehearses the control flow and still ends non-zero.
+chip-smoke:
+	python chip_smoke.py
+
+# bench-regression gate: fresh CPU smoke run of bench.py (JAX_PLATFORMS=cpu,
+# --platform cpu) diffed against this host's best prior run (10% noise
+# floor); fails loudly when e2e/device/host decisions-per-sec regress.
+# Then the census, the open-loop overlap probe (the pipeline's stage split
+# + realized overlap), a short front-door sweep (in-process baseline vs 2
+# acceptor workers: e2e decisions/s + shm ring stall %), the tier probe
+# (arena fraction under Zipf traffic), and the trace-overhead probe (the
+# continuous device profiler, GUBER_DEVPROF=periodic, must cost <2% of the
+# untraced serving rate).  All of it is CPU; none of it is a device number.
 bench-smoke:
 	python scripts/bench_compare.py
-	GUBER_PROBE_PLATFORM=cpu python scripts/probe_census.py
-	GUBER_PROBE_PLATFORM=cpu python scripts/probe_trace_overhead.py
-	GUBER_PROBE_PLATFORM=cpu python scripts/probe_overlap.py
-	GUBER_PROBE_PLATFORM=cpu GUBER_PROBE_FD_WORKERS=0,2 GUBER_PROBE_SECONDS=2 python scripts/probe_frontdoor.py
-	GUBER_PROBE_PLATFORM=cpu GUBER_PROBE_B=1024 GUBER_PROBE_C=4096 GUBER_PROBE_SECONDS=1 python scripts/probe_chain.py
-	GUBER_PROBE_PLATFORM=cpu GUBER_PROBE_TIER_NS=8192 GUBER_PROBE_TIER_WINDOWS=120 GUBER_PROBE_B=128 python scripts/probe_tiers.py
-	GUBER_PROBE_PLATFORM=cpu GUBER_CLUSTER_NODES=1 GUBER_CLUSTER_SECONDS=2 GUBER_CLUSTER_RATE=20 GUBER_CLUSTER_BATCH=32 GUBER_CLUSTER_FRONTDOOR=2 python scripts/load_cluster.py
+	JAX_PLATFORMS=cpu python scripts/probe_census.py
+	JAX_PLATFORMS=cpu python scripts/probe_trace_overhead.py
+	JAX_PLATFORMS=cpu python scripts/probe_overlap.py
+	JAX_PLATFORMS=cpu GUBER_PROBE_FD_WORKERS=0,2 GUBER_PROBE_SECONDS=2 python scripts/probe_frontdoor.py
+	JAX_PLATFORMS=cpu GUBER_PROBE_TIER_NS=8192 GUBER_PROBE_TIER_WINDOWS=120 GUBER_PROBE_B=128 python scripts/probe_tiers.py
+	JAX_PLATFORMS=cpu GUBER_CLUSTER_NODES=1 GUBER_CLUSTER_SECONDS=2 GUBER_CLUSTER_RATE=20 GUBER_CLUSTER_BATCH=32 GUBER_CLUSTER_FRONTDOOR=2 python scripts/load_cluster.py
 
 docker:
 	docker build -t gubernator-tpu:latest .

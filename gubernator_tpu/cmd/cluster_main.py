@@ -17,8 +17,6 @@ ADDRESSES = [f"127.0.0.1:{port}" for port in range(9090, 9096)]
 
 
 async def _amain() -> None:
-    from gubernator_tpu.daemon import apply_platform_env
-    apply_platform_env()
     c = await cluster_mod.start_with(ADDRESSES)
     print("Ready", flush=True)
     try:

@@ -29,18 +29,6 @@ from gubernator_tpu.server import GrpcServer
 log = logging.getLogger("gubernator.daemon")
 
 
-def apply_platform_env() -> None:
-    """Honor GUBER_JAX_PLATFORM (e.g. 'cpu', 'tpu') before first device use.
-
-    Needed because ambient JAX_PLATFORMS may be pinned by site config; this
-    routes through jax.config which wins over the environment."""
-    import os
-    platform = os.environ.get("GUBER_JAX_PLATFORM")
-    if platform:
-        import jax
-        jax.config.update("jax_platforms", platform)
-
-
 class Daemon:
     def __init__(self, conf: DaemonConfig):
         self.conf = conf
@@ -93,7 +81,8 @@ class Daemon:
 
     async def start(self) -> None:
         c = self.conf
-        apply_platform_env()
+        from gubernator_tpu.config import place_compile_cache
+        place_compile_cache()
 
         # Mesh mode: join the jax.distributed runtime BEFORE any device use;
         # the arena then shards over every process's chips and all hosts

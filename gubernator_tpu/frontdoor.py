@@ -623,12 +623,11 @@ def worker_main(worker_id: int, prefix: str, slots: int, slab_bytes: int,
     """Spawn entry point (multiprocessing 'spawn' context).  The package
     __init__ imported jax; pin this process to the CPU platform before
     anything could lazily initialize a backend — the accelerator belongs
-    to the engine process alone."""
-    try:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
+    to the engine process alone.  A worker that cannot pin itself dies
+    here: the hub sees a dead worker, never a second claimant of the
+    chip."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     logging.basicConfig(level=logging.INFO)
     asyncio.run(_worker_amain(worker_id, prefix, slots, slab_bytes,
                               listen_host, port_hint, fastpath_min,

@@ -1,15 +1,21 @@
 """Native host runtime: ctypes bindings for the C++ window router.
 
-Compiles host_router.cc on first use (g++ -O2 -shared) and caches the .so
-next to the source; falls back cleanly if no toolchain is present — callers
-check `available()` and use the Python router otherwise.
+Compiles host_router.cc on first use (g++ -O2 -shared) into a .so named
+after the source's content hash, next to the source — so a tree copy, a
+checkout or an edit can never pair a stale library with a newer source.
+A host without a C++ toolchain has no native router (`available()` is
+False and `use_native="auto"` engines route in Python); a toolchain that
+FAILS to build or load the committed source is an error, never a quiet
+fallback.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -20,32 +26,41 @@ log = logging.getLogger("gubernator.native")
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "host_router.cc")
-_SO = os.path.join(_HERE, "libhost_router.so")
 
 _lib = None
 _lib_lock = threading.Lock()
-_lib_failed = False
 
 
-def _build() -> None:
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", _SO, _SRC]
-    subprocess.run(cmd, check=True, capture_output=True)
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libhost_router-{digest}.so")
+
+
+def _build(so: str) -> None:
+    # build under a private name, then rename: concurrent first users (test
+    # workers, front-door workers) never load a half-written library
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
+    proc = subprocess.run(cmd, capture_output=True, text=True)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"native router build failed (rc={proc.returncode}): "
+            f"{proc.stderr[-2000:]}")
+    os.replace(tmp, so)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _lib_failed
+    global _lib
     with _lib_lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
-        try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                _build()
-            lib = ctypes.CDLL(_SO)
-        except Exception as e:
-            log.warning("native router unavailable (%s); using Python path", e)
-            _lib_failed = True
-            return None
+        so = _so_path()
+        if not os.path.exists(so):
+            if shutil.which("g++") is None:
+                return None
+            _build(so)
+        lib = ctypes.CDLL(so)
 
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -6,9 +6,9 @@ from the traced jaxpr — a box-independent program property — but cannot
 say which kernels own the ~0.15 ms/kernel dispatch wall.  This module is
 the measurement side of that reconciliation (ROADMAP item 1):
 
-  * `parse_run_dir` / `load_trace_events` — parse the `trace.json.gz`
-    files a `jax.profiler` capture leaves under its run dir (gzip+json,
-    dependency-free) into chrome-trace complete events;
+  * `parse_run_dir` / `load_trace_events` — read the `.xplane.pb` files
+    a `jax.profiler` capture leaves under its run dir (through
+    `jax.profiler.ProfileData`) into chrome-trace-style complete events;
   * `self_times` — per-(pid, tid) interval nesting turns the raw events
     into per-kernel SELF time (a fusion nested inside an executable
     wrapper is not double-counted) and attributes each kernel to a
@@ -35,8 +35,6 @@ must never fail a request or a bench run.
 
 from __future__ import annotations
 
-import gzip
-import json
 import logging
 import os
 import shutil
@@ -90,34 +88,36 @@ def _annotation_arm(name: str) -> Optional[str]:
 
 
 def find_trace_files(run_dir: str) -> List[str]:
-    """Every `*.trace.json.gz` under a jax.profiler run dir (the profiler
-    nests them under plugins/profile/<timestamp>/<host>.trace.json.gz)."""
+    """Every `*.xplane.pb` under a jax.profiler run dir (the profiler
+    nests them under plugins/profile/<timestamp>/<host>.xplane.pb)."""
     out: List[str] = []
     for root, _dirs, files in os.walk(run_dir):
         for f in files:
-            if f.endswith(".trace.json.gz"):
+            if f.endswith(".xplane.pb"):
                 out.append(os.path.join(root, f))
     return sorted(out)
 
 
 def load_trace_events(path: str) -> List[dict]:
-    """Chrome-trace complete events (ph == "X", positive duration) from
-    one trace file; malformed input degrades to a logged empty list."""
+    """One xplane file as chrome-trace-style complete events (ts/dur in
+    microseconds, pid/tid = plane/line index, positive duration only);
+    malformed input degrades to a logged empty list."""
+    from jax.profiler import ProfileData
     try:
-        with gzip.open(path, "rt", encoding="utf-8", errors="replace") as fh:
-            data = json.load(fh)
-        events = data.get("traceEvents")
-        if not isinstance(events, list):
-            log.warning("devprof: %s has no traceEvents list", path)
-            return []
-        return [e for e in events
-                if isinstance(e, dict) and e.get("ph") == "X"
-                and isinstance(e.get("dur"), (int, float)) and e["dur"] > 0
-                and isinstance(e.get("ts"), (int, float))
-                and isinstance(e.get("name"), str)]
-    except (OSError, ValueError, EOFError) as e:
+        planes = list(ProfileData.from_file(path).planes)
+    except Exception as e:  # noqa: BLE001 — RuntimeError / JaxRuntimeError
         log.warning("devprof: unreadable trace %s: %s", path, e)
         return []
+    events: List[dict] = []
+    for pid, plane in enumerate(planes):
+        for tid, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.duration_ns > 0:
+                    events.append({
+                        "ph": "X", "pid": pid, "tid": tid, "name": ev.name,
+                        "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
+                        "plane": plane.name, "line": line.name})
+    return events
 
 
 def parse_run_dir(run_dir: str) -> List[dict]:
@@ -126,7 +126,7 @@ def parse_run_dir(run_dir: str) -> List[dict]:
     events: List[dict] = []
     files = find_trace_files(run_dir)
     if not files:
-        log.warning("devprof: no trace.json.gz under %s", run_dir)
+        log.warning("devprof: no xplane.pb under %s", run_dir)
         return events
     for path in files:
         events.extend(load_trace_events(path))
@@ -384,16 +384,12 @@ class DevprofController:
                 # traffic too idle to complete N drains inside the budget:
                 # stop the capture and fold whatever it caught
                 self.profile.cancel()
-            # `armed` flips False BEFORE jax.profiler.stop_trace finishes
-            # dumping (the engine thread drops the lock first), so wait
-            # for the trace files to land before parsing the dir
-            settle = time.monotonic() + 5.0
-            while (not find_trace_files(tmp)
-                   and time.monotonic() < settle
+            # `armed` drops only once stop_trace has written the capture
+            # (on the engine thread, or here through cancel)
+            settle = time.monotonic() + 10.0
+            while (self.profile.armed and time.monotonic() < settle
                    and not self._stop.is_set()):
-                time.sleep(0.05)
-            if find_trace_files(tmp):
-                time.sleep(0.1)  # let the in-flight dump finish its write
+                time.sleep(0.02)
             w1 = self.windows_fn() if self.windows_fn is not None else 0
             windows = max(1, w1 - w0) if self.windows_fn else self.drains
             events = parse_run_dir(tmp)

@@ -36,6 +36,7 @@ class ProfileCapture:
         self._remaining = 0
         self._dir = ""
         self._active = False
+        self._stopping = False
 
     @property
     def armed(self) -> bool:
@@ -84,30 +85,34 @@ class ProfileCapture:
             self._remaining -= 1
             if self._remaining > 0:
                 return
-            self._active = False
-        try:
-            import jax
-            jax.profiler.stop_trace()
-            log.info("profile capture stopped -> %s", self._dir)
-        except Exception:
-            log.exception("profile capture failed to stop")
+        self._stop("stopped")
 
     def cancel(self) -> None:
         """Disarm an in-flight capture (continuous profiling's recovery
         path when traffic never completes the armed drain count): stop the
         device trace if it started, drop any remaining armed drains."""
         with self._lock:
-            was_active = self._active
-            self._active = False
             self._remaining = 0
-        if not was_active:
-            return
+        self._stop("cancelled")
+
+    def _stop(self, what: str) -> None:
+        """stop_trace() returns once the profiler has written its files;
+        `armed` stays true until then, so a reader that waits for it to
+        drop never parses a half-written capture."""
+        with self._lock:
+            if not self._active or self._stopping:
+                return
+            self._stopping = True
         try:
             import jax
             jax.profiler.stop_trace()
-            log.info("profile capture cancelled -> %s", self._dir)
+            log.info("profile capture %s -> %s", what, self._dir)
         except Exception:
-            log.exception("profile capture failed to cancel")
+            log.exception("profile capture failed to stop")
+        finally:
+            with self._lock:
+                self._active = False
+                self._stopping = False
 
     def status(self) -> dict:
         with self._lock:

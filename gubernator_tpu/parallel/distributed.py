@@ -114,9 +114,8 @@ def agree_epoch_ms(mesh) -> int:
         first = lax.axis_index(SHARD_AXIS) == 0
         return lax.psum(jnp.where(first, v[0], jnp.int64(0)), SHARD_AXIS)[None]
 
-    from gubernator_tpu.compat import shard_map
-    out = jax.jit(shard_map(fn, mesh=mesh, in_specs=P(SHARD_AXIS),
-                            out_specs=P(SHARD_AXIS)))(gv)
+    out = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P(SHARD_AXIS),
+                                out_specs=P(SHARD_AXIS)))(gv)
     return int(np.asarray(out.addressable_shards[0].data)[0])
 
 

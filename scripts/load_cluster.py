@@ -42,7 +42,7 @@ Environment knobs (defaults in parentheses):
 
 Example:
 
-    GUBER_PROBE_PLATFORM=cpu GUBER_CLUSTER_NODES=3 \
+    JAX_PLATFORMS=cpu GUBER_CLUSTER_NODES=3 \
         GUBER_CLUSTER_RATE=100 python scripts/load_cluster.py
 """
 

@@ -34,7 +34,7 @@ Arms:
                        — the algorithm plane is select depth, not kernels)
   composed_analytics   K=8 composed drain + GLOBAL + analytics reduction
 
-Env: GUBER_PROBE_PLATFORM (cpu for smoke), GUBER_PROBE_JSON=<path> to
+Env: JAX_PLATFORMS (cpu for smoke), GUBER_PROBE_JSON=<path> to
 also write the table as json, GUBER_PROBE_MEASURE=1 to ALSO compile and
 run each arm under a real `jax.profiler` capture and report measured
 ms/window next to the census count (box-dependent — never gated

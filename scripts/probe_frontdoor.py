@@ -20,9 +20,9 @@ spreads them across the acceptor workers — and prints:
 `make bench-smoke` runs a short 0-vs-2 sweep after the overlap probe;
 standalone:
 
-    GUBER_PROBE_PLATFORM=cpu python scripts/probe_frontdoor.py
+    JAX_PLATFORMS=cpu python scripts/probe_frontdoor.py
     GUBER_PROBE_FD_WORKERS=1,2,4 GUBER_PROBE_SECONDS=5 \
-        GUBER_PROBE_PLATFORM=cpu python scripts/probe_frontdoor.py
+        JAX_PLATFORMS=cpu python scripts/probe_frontdoor.py
 
 On a single-core box every process shares one CPU, so the multi-worker
 rows understate the win; the sweep is still a live differential check of

@@ -6,7 +6,7 @@ scores the reported top-K against the TRUE heavy hitters of the sampled
 trace: precision@K = |reported-K intersect true-K| / K.  The acceptance
 bar mirrored in tests/test_analytics.py is precision@10 >= 0.9 at s=1.1.
 
-  GUBER_PROBE_PLATFORM=cpu python scripts/probe_hotkey.py
+  JAX_PLATFORMS=cpu python scripts/probe_hotkey.py
   GUBER_PROBE_KEYS=5000 GUBER_PROBE_DECISIONS=100000 ... # bigger trace
 """
 

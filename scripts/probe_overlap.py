@@ -18,7 +18,7 @@ on this box, separate from the columnar host-path wins (which depth 1
 keeps).  `make bench-smoke` runs the default sweep (depths 1 and 3,
 ~3 s each) after the regression gate; standalone:
 
-    GUBER_PROBE_PLATFORM=cpu python scripts/probe_overlap.py
+    JAX_PLATFORMS=cpu python scripts/probe_overlap.py
     GUBER_PROBE_DEPTHS=1,2,3 GUBER_PROBE_SECONDS=5 ... # custom sweep
 """
 

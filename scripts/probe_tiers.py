@@ -16,7 +16,7 @@ the probe reports what the tier machinery costs and buys:
 
 Standalone (CPU smoke):
 
-    GUBER_PROBE_PLATFORM=cpu python scripts/probe_tiers.py
+    JAX_PLATFORMS=cpu python scripts/probe_tiers.py
 
 Knobs: GUBER_PROBE_TIER_NS (namespace, default 32768),
 GUBER_PROBE_TIER_FRACS (comma fractions, default 1/64,1/16,1/4),

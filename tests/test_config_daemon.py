@@ -161,3 +161,24 @@ def test_replay_cap_default(clean_env):
                           global_capacity=8, global_batch_per_shard=4,
                           max_global_updates=4)
     assert eng.replay_cap == 128
+
+
+def test_place_compile_cache_one_place(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: nothing is set in code.  Unset: the
+    cache is <checkout>/.jax_cache, derived from the package's own path."""
+    import jax
+
+    from gubernator_tpu import config
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert config.place_compile_cache() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(config.__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert config.place_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]

@@ -20,7 +20,6 @@ specs (`build_census_arms`), so the join can never drift.  Around it:
 """
 
 import asyncio
-import gzip
 import json
 import os
 import threading
@@ -61,32 +60,41 @@ CENSUS_CLASSES = ("int64_xla", "compact32_xla", "fused_window",
 # --------------------------------------------------------------- trace parsing
 
 
-def _gz(path, obj):
-    with gzip.open(path, "wt", encoding="utf-8") as fh:
-        fh.write(json.dumps(obj))
-
-
 def test_malformed_and_empty_traces_degrade(tmp_path):
     run = tmp_path / "plugins" / "profile" / "t1"
     run.mkdir(parents=True)
-    # not gzip at all
-    bad = run / "host.trace.json.gz"
-    bad.write_bytes(b"definitely not gzip")
+    # not an XSpace at all
+    bad = run / "host.xplane.pb"
+    bad.write_bytes(b"definitely not a protobuf")
     assert load_trace_events(str(bad)) == []
-    # gzip, but no traceEvents list
-    no_events = run / "h2.trace.json.gz"
-    _gz(no_events, {"displayTimeUnit": "ns"})
-    assert load_trace_events(str(no_events)) == []
-    # gzip + traceEvents, garbage entries filtered, one valid X event kept
-    mixed = run / "h3.trace.json.gz"
-    _gz(mixed, {"traceEvents": [
-        "junk", {"ph": "M", "name": "meta"},
-        {"ph": "X", "name": "neg", "ts": 1, "dur": -5},
-        {"ph": "X", "name": "nodur", "ts": 1},
-        {"ph": "X", "name": "fusion.1", "ts": 10.0, "dur": 2.5},
-    ]})
-    evs = load_trace_events(str(mixed))
-    assert [e["name"] for e in evs] == ["fusion.1"]
+    # an empty XSpace: no planes, no events
+    empty = run / "h2.xplane.pb"
+    empty.write_bytes(b"")
+    assert load_trace_events(str(empty)) == []
+    assert parse_run_dir(str(tmp_path)) == []
+    # a real capture: zero-duration runtime markers are filtered, the
+    # annotation span and the executable's ops are kept in microseconds
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    cap = tmp_path / "cap"
+    jax.profiler.start_trace(str(cap))
+    try:
+        with jax.profiler.TraceAnnotation("guber_drain"):
+            f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    evs = parse_run_dir(str(cap))
+    assert evs and all(e["ph"] == "X" and e["dur"] > 0 for e in evs)
+    span = [e for e in evs if e["name"] == "guber_drain"]
+    assert len(span) == 1
+    inside = [e for e in evs if not e["name"].startswith("$")
+              and span[0]["ts"] <= e["ts"]
+              and e["ts"] + e["dur"] <= span[0]["ts"] + span[0]["dur"]
+              and e is not span[0]]
+    assert inside, "no runtime event landed inside the annotation span"
     # a run dir with no trace files at all
     assert parse_run_dir(str(tmp_path / "nothing-here")) == []
     # folding an empty capture is a counted no-op, never an error

@@ -335,7 +335,7 @@ def test_pipeline_drain_fused_parity(monkeypatch):
 def test_fused_fresh_interpreter_no_recursion_leak():
     """Running the fused megakernel (interpret mode) must not leave a
     raised recursion limit behind: the mosaic_recursion_guard scoping is
-    per lowering call, never process-global (ADVICE.md #1).  Fresh
+    per lowering call, never process-global.  Fresh
     interpreter so the check sees exactly this code path's side effects."""
     code = (
         "import sys; base = sys.getrecursionlimit()\n"

@@ -261,3 +261,36 @@ def test_algo_switch_within_window():
     assert rs[0].remaining == 4
     assert rs[1].remaining == 4  # re-initialized as leaky
     assert rs[1].reset_time == 0
+
+
+@pytest.mark.parametrize("bits_a,bits_b", [(63, 63), (63, 16), (40, 31),
+                                           (31, 4), (8, 1)])
+def test_floordiv_rolled_equals_floor_divide(bits_a, bits_b):
+    """kernel.floordiv's TPU branch (the rolled 64-step long division that
+    keeps int64 ladders compilable for the chip) is bit-identical to
+    jnp.floor_divide — signs, inexact quotients, INT64_MIN and x/0
+    included.  The branch lowers only for a TPU, so it is pinned here by
+    calling it directly."""
+    import jax
+    import numpy as np
+
+    from gubernator_tpu.ops import kernel
+
+    rng = np.random.default_rng(bits_a * 64 + bits_b)
+    lim = np.iinfo(np.int64)
+    edge = np.array([0, 1, -1, 2, -2, 3, -7, lim.max, lim.min, lim.max - 1,
+                     lim.min + 1, 1 << 31, -(1 << 31), (1 << 32) + 1,
+                     1 << 62, -(1 << 62), 60_000, 1_790_000_000_000],
+                    np.int64)
+    ea, eb = (m.ravel() for m in np.meshgrid(edge, edge))
+    hi_a, hi_b = (1 << bits_a) - 1, (1 << bits_b) - 1
+    a = np.concatenate([ea, rng.integers(-hi_a, hi_a, 4000, dtype=np.int64)])
+    b = np.concatenate([eb, rng.integers(-hi_b, hi_b, 4000, dtype=np.int64)])
+    got = np.asarray(jax.jit(kernel._floordiv_rolled)(a, b))
+    want = np.asarray(jax.jit(lambda x, y: x // y)(a, b))
+    bad = np.nonzero(got != want)[0]
+    assert bad.size == 0, [(a[i], b[i], got[i], want[i]) for i in bad[:5]]
+    # the dispatcher itself: int64 in, the native op on this (CPU) backend;
+    # int32 never leaves the native op
+    assert np.array_equal(np.asarray(kernel.floordiv(a, b)), want)
+    assert kernel.floordiv(np.int32(-7), np.int32(2)).dtype == np.int32

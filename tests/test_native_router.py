@@ -6,6 +6,7 @@ to identical responses over randomized workloads, and the router's own LRU /
 eviction / overflow semantics.
 """
 
+import os
 import random
 
 import numpy as np
@@ -163,3 +164,29 @@ def test_differential_exact_key_guard():
                    (y.status, y.limit, y.remaining, y.reset_time), \
                    f"window {w} item {i}"
         now += rng.choice([0, 1, 40])
+
+
+def test_native_library_is_keyed_on_source_content(monkeypatch, tmp_path):
+    """The .so is named after host_router.cc's sha256, so a copied or
+    edited tree can never pair a stale library with a newer source; and a
+    toolchain that fails to build the source raises instead of quietly
+    handing the engine the Python router."""
+    import hashlib
+    import shutil
+
+    with open(native._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert native._so_path().endswith(f"libhost_router-{digest}.so")
+    assert native.available() and os.path.exists(native._so_path())
+
+    broken = tmp_path / "host_router.cc"
+    broken.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", str(broken))
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    monkeypatch.setattr(native, "_lib", None)
+    if shutil.which("g++") is None:
+        assert native._load() is None
+    else:
+        with pytest.raises(RuntimeError, match="native router build failed"):
+            native._load()
+    assert not list(tmp_path.glob("*.so"))
