@@ -1,0 +1,130 @@
+"""Shared pieces of the benchmark's own tests: a tiny copy of the data files
+in a temporary root, and a simulated windowed server for the comparison."""
+
+import json
+import os
+import random
+import shutil
+
+import numpy as np
+
+from benchmark import traffic
+from benchmark.reference import serial
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# the child daemon of a test runs on one CPU device, whatever the test
+# process itself was given
+CPU_CHILD = {"JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""}
+
+
+def tiny_root(tmp, duration_ms=60000):
+    """BENCHMARK.json and the data files it names, cut to a size a test run
+    can hold: same names, same code, small arenas and few keys."""
+    tmp = str(tmp)
+    for d in ("configs", "traffic", "cells"):
+        os.makedirs(os.path.join(tmp, "benchmark", d))
+    for d in ("layer_metrics", "reference"):
+        shutil.copytree(os.path.join(REPO, "benchmark", d),
+                        os.path.join(tmp, "benchmark", d))
+    shutil.copy(os.path.join(REPO, "benchmark", "peaks.json"),
+                os.path.join(tmp, "benchmark", "peaks.json"))
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    for c in spec["configs"]:
+        with open(os.path.join(REPO, c["file"])) as f:
+            cfg = json.load(f)
+        cfg["daemon_env"] = {"GUBER_TPU_CAPACITY_PER_SHARD": "8192",
+                             "GUBER_TPU_BATCH_PER_SHARD": "256"}
+        cfg["keyspace"]["population"] = 3000
+        cfg["keyspace"]["duration_ms"] = duration_ms
+        cfg["fill_keys"] = 2000
+        with open(os.path.join(tmp, c["file"]), "w") as f:
+            json.dump(cfg, f)
+    for w in spec["workloads"]:
+        path = os.path.join("benchmark", "traffic", w["traffic"] + ".json")
+        with open(os.path.join(REPO, path)) as f:
+            mix = json.load(f)
+        mix.update(generator_procs=2, connections=8, warm_s=1.5,
+                   pool_rpcs_per_proc=256, trace_drains=5,
+                   grace_s=5, fill_connections=8)
+        mix["check"]["min_checked_decisions"] = 100
+        if mix["items_per_rpc"] > 50:
+            mix["items_per_rpc"] = 50
+            mix["check"]["sample_mod"] = 4
+        with open(os.path.join(tmp, path), "w") as f:
+            json.dump(mix, f)
+        if mix["loop"] == "open":
+            with open(os.path.join(tmp, "benchmark", "cells",
+                                   w["name"] + ".json"), "w") as f:
+                json.dump({"rate_rps": 80}, f)
+    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    return tmp
+
+
+def keyspace(algorithms="parity", duration_ms=60000, population=100000):
+    return traffic.KeySpace({
+        "population": population, "zipf_s": 1.1, "algorithms": algorithms,
+        "limits": [10, 100, 1000, 10000], "duration_ms": duration_ms,
+        "name": "n", "key_prefix": "k:"})
+
+
+def simulate(ks, seed, fault=None, nops=20000, span_ms=70000, window_ms=21,
+             lag_ms=21):
+    """A history as the clients of a windowed server would record it: the
+    reference applied window by window, every request of a window at the
+    window's one timestamp.  `fault`: None, or
+      stale    every request of a window reads the row as it stood before it
+      frozen   every fourth window leaves the state as it was
+      altered  every fourth window alters one answer."""
+    rng = random.Random(seed)
+    nrng = np.random.default_rng(seed)
+    ranks = traffic.Zipf(ks.population, ks.zipf_s).draw(nrng, nops)
+    arrive = np.sort(nrng.uniform(0, span_ms, nops)) + 1_700_000_000_000
+    store = serial.SerialStore()
+    cols = {k: [] for k in ("rank", "sent", "recv", "status", "remaining",
+                            "reset", "hint")}
+    i, last_now, windows = 0, 0, 0
+    while i < nops:
+        j = i
+        while j < nops and arrive[j] < arrive[i] + window_ms:
+            j += 1
+        now = max(last_now, int(arrive[i] + window_ms + rng.random() * 3))
+        last_now = now
+        windows += 1
+        bad = fault in ("frozen", "altered") and windows % 4 == 0
+        before, saved = {}, {}
+        for k in range(i, j):
+            rk = int(ranks[k])
+            args = (1, ks.limit(rk), ks.duration_ms, ks.algo(rk), now)
+            if bad and fault == "frozen" and rk not in saved:
+                old = store.rows.get(rk)
+                saved[rk] = old.copy() if old else None
+            if fault == "stale":
+                if rk not in before:
+                    old = store.rows.get(rk)
+                    before[rk] = old.copy() if old else None
+                row = before[rk].copy() if before[rk] else None
+                _, resp = serial.apply(row, *args)
+                store.hit(rk, *args)
+            else:
+                resp = store.hit(rk, *args)
+            if bad and fault == "altered":
+                resp = (resp[0], resp[1], resp[2] + 1, resp[3])
+                bad = False
+            cols["rank"].append(rk)
+            cols["sent"].append(arrive[k] - rng.random() * 2)
+            cols["recv"].append(now + lag_ms + rng.random() * 3)
+            cols["status"].append(resp[0])
+            cols["remaining"].append(resp[2])
+            cols["reset"].append(resp[3])
+            cols["hint"].append(0)
+        for rk, old in saved.items():
+            if old is None:
+                store.rows.pop(rk, None)
+            else:
+                store.rows[rk] = old
+        i = j
+    return {k: np.asarray(v, dtype=np.float64 if k in ("sent", "recv")
+                          else np.int64) for k, v in cols.items()}
