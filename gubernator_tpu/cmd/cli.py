@@ -163,6 +163,10 @@ def cmd_debug(args) -> int:
         print(f"arena: {eng.get('live', 0)} live / "
               f"{eng.get('expired', 0)} expired / {eng.get('free', 0)} free "
               f"of {cap} slots ({100.0 * eng.get('live', 0) / cap:.1f}% live)")
+    mem = snap.get("device", {}).get("memory")
+    if mem:
+        print("device memory:", " ".join(
+            f"{k}={v / 1e6:.1f}MB" for k, v in mem.items()))
     adm = snap.get("admission")
     if adm:
         print(f"admission: pending={adm['pending']} "
@@ -211,7 +215,12 @@ def cmd_debug(args) -> int:
     pipe = snap.get("pipeline")
     if pipe:
         print("pipeline:", " ".join(
-            f"{k}={v}" for k, v in sorted(pipe.items())))
+            f"{k}={v}" for k, v in sorted(pipe.items())
+            if k != "pump_hold_seconds"))
+        hold = pipe.get("pump_hold_seconds")
+        if hold:
+            print("pump held (s):", " ".join(
+                f"{k}={v:.3f}" for k, v in hold.items()))
     an = snap.get("analytics")
     if an:
         tot = an.get("totals", {})

@@ -53,8 +53,8 @@ class WindowBatcher:
         # for per-request stage spans (sampled requests only)
         self.tracer = tracer
         # on-demand device capture (observability/introspect.py), armed by
-        # POST /v1/admin/profile; checked on the engine thread around each
-        # dispatch, so disarmed costs one integer compare
+        # POST /v1/admin/profile; the engine thread reads one bool around
+        # each dispatch and never calls into the profiler itself
         from gubernator_tpu.observability import ProfileCapture
         self.profile = ProfileCapture()
         # QoSManager (gubernator_tpu/qos/) or None: admission control on
@@ -310,9 +310,7 @@ class WindowBatcher:
 
         def run_profiled():
             prof = self.profile
-            profiling = prof is not None and prof.armed
-            if profiling:
-                prof.before_drain()
+            profiling = prof is not None and prof.tracing
             try:
                 return run()
             finally:
@@ -482,9 +480,7 @@ class WindowBatcher:
             if FAULTS.enabled:
                 FAULTS.on_sync(SEAM_ENGINE_DISPATCH, "window")
             prof = self.profile
-            profiling = prof is not None and prof.armed
-            if profiling:
-                prof.before_drain()
+            profiling = prof is not None and prof.tracing
             try:
                 now = self.now_fn() if self.now_fn is not None else None
                 resps = self.engine.process(reqs, now, accumulate,

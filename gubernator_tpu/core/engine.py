@@ -1523,6 +1523,15 @@ class RateLimitEngine:
         Per-host compact ELIGIBILITY never changes the executable: an
         unsound host just stops staging lanes (core/pipeline.py
         lockstep mode) while still issuing the dispatch.
+
+        The `guber_drain` annotation (here and in
+        pipeline_dispatch_global) covers this call alone: the host's
+        enqueue of the executable.  It closes when the call returns,
+        long before the device has run the drain; the device's own time
+        is the trace's `XLA Modules` line.  The host stages around it
+        are `guber_pack`, `guber_fetch`, `guber_decode`, `guber_commit`
+        (core/pipeline.py) and `guber_rpc_in` / `guber_rpc_out`
+        (server.py).
         """
         if self.multiprocess:
             packed = self._sharded_in_stacked(np.ascontiguousarray(packed))

@@ -77,6 +77,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from gubernator_tpu.api.types import (
     Algorithm,
@@ -92,6 +93,7 @@ from gubernator_tpu.config import (CHAIN_LINGER_MS_DEFAULT,
 from gubernator_tpu.core.engine import PIPELINE_K_BUCKETS
 from gubernator_tpu.core.window_buffers import RequestColumns, WindowArenaRing
 from gubernator_tpu.net.faults import FAULTS, SEAM_ENGINE_DISPATCH
+from gubernator_tpu.observability.metrics import PUMP_HOLD_REASONS
 from gubernator_tpu.observability.tracing import current_context
 from gubernator_tpu.ops import kernel
 from gubernator_tpu.qos import interleave_by_tenant
@@ -205,7 +207,7 @@ class RpcJob:
 
     __slots__ = ("data", "fut", "n", "row", "lane", "pos", "limit", "off",
                  "mlen", "remote_idx", "forward_task", "peer_mode",
-                 "ctx", "enq")
+                 "ctx", "enq", "committed")
 
     def __init__(self, data: bytes, fut: asyncio.Future,
                  peer_mode: bool = False):
@@ -216,6 +218,8 @@ class RpcJob:
         # SpanContext this RPC rode in on, and when it joined the queue
         self.ctx = None
         self.enq = 0.0
+        # when the commit resolved this job's future (reply_wake's start)
+        self.committed = 0.0
         self.n = 0
         self.row = None
         self.lane = None
@@ -255,7 +259,7 @@ class ListJob:
     (singles) or one future with the response list (batch)."""
 
     __slots__ = ("reqs", "futs", "fut", "row", "lane", "pos", "n", "_cols",
-                 "ctxs", "enq")
+                 "ctxs", "enq", "enq_lag", "committed")
 
     def __init__(self, reqs: Sequence[RateLimitReq],
                  futs: Optional[List[asyncio.Future]] = None,
@@ -268,6 +272,11 @@ class ListJob:
         # singles chunks, single-element for batch jobs) + oldest enqueue
         self.ctxs = ctxs
         self.enq = enq
+        # a singles chunk holds len(futs) requests, each with an enqueue
+        # time of its own: enq_lag = Σ (t_enq − enq), so that the chunk's
+        # summed queue wait is n·(started − enq) − enq_lag
+        self.enq_lag = 0.0
+        self.committed = 0.0
         self.n = len(self.reqs)
         self.row = None
         self.lane = None
@@ -337,7 +346,7 @@ class ColsJob:
     copy because the slab stays valid until the hub completes the record."""
 
     __slots__ = ("cols", "futs", "fut", "row", "lane", "pos", "n",
-                 "ctxs", "enq", "want_cols")
+                 "ctxs", "enq", "want_cols", "committed")
 
     def __init__(self, cols: tuple, n: int, fut: asyncio.Future,
                  want_cols: bool = False):
@@ -346,6 +355,7 @@ class ColsJob:
         self.futs = None
         self.ctxs = None
         self.enq = 0.0
+        self.committed = 0.0
         self.n = n
         self.row = None
         self.lane = None
@@ -426,7 +436,9 @@ class _DrainResult:
                  "an_decay", "staged", "fallback",
                  "leftover", "now", "n_decisions", "n_lanes", "k_used",
                  "error", "started", "ring_peers",
-                 "pack_done", "dispatch_done", "fetch_start", "fetch_done",
+                 "pumped", "pack_done", "dispatch_done", "dispatched_cb",
+                 "fetch_start", "fetch_ready", "fetch_done", "completed_cb",
+                 "committed",
                  "oldest_enq", "arena", "cols_owner", "cfut", "deferred",
                  "arm", "chain_fetch_start", "chain_fetch_done")
 
@@ -460,14 +472,37 @@ class _DrainResult:
         self.error = None
         self.started = 0.0
         self.ring_peers = ()
-        # stage boundaries (monotonic): window_fill = started→pack_done,
-        # device_dispatch = pack_done→dispatch_done, drain_commit =
-        # fetch_start→fetch_done; admission_wait = oldest_enq→started.
+        # stage boundaries (time.monotonic(), each taken where the work
+        # happens), in pipeline order:
+        #   pumped         loop: _pump hands the drain to the engine executor
+        #   started        engine thread: the drain begins
+        #   pack_done      engine thread: every job parsed/packed
+        #   dispatch_done  engine thread: the executable is enqueued
+        #   dispatched_cb  loop: _on_dispatched runs (beside the fetch
+        #                  when the engine thread submitted it itself)
+        #   fetch_start    fetch thread: a worker picks the drain up
+        #   fetch_ready    fetch thread: the device reads have returned
+        #   fetch_done     fetch thread: every job.finish() done
+        #   completed_cb   loop: _on_completed runs
+        #   committed      loop: every future resolved
+        # engine_queue = pumped→started, window_fill = started→pack_done,
+        # device_dispatch = pack_done→dispatch_done, dispatch_hop =
+        # dispatch_done→dispatched_cb, fetch_queue = dispatch_done→
+        # fetch_start, device_wait = fetch_start→fetch_ready, decode =
+        # fetch_ready→fetch_done (drain_commit = both), complete_hop =
+        # fetch_done→completed_cb, commit = completed_cb→committed;
+        # admission_wait = oldest_enq→started.  Without dispatch_hop,
+        # which runs beside the fetch, they add up to committed − pumped.
         # 0.0 = the boundary was never reached (error paths observe nothing)
+        self.pumped = 0.0
         self.pack_done = 0.0
         self.dispatch_done = 0.0
+        self.dispatched_cb = 0.0
         self.fetch_start = 0.0
+        self.fetch_ready = 0.0
         self.fetch_done = 0.0
+        self.completed_cb = 0.0
+        self.committed = 0.0
         self.oldest_enq = 0.0
         # devprof attribution: which executable family served this drain
         # (composed_analytics / composed_drain / fused_window /
@@ -594,7 +629,7 @@ class DispatchPipeline:
             thread_name_prefix="guber-fetch")
         # staging arenas (ring of reusable buffers) + columnar singles
         # accumulation — see core/window_buffers.py and module docstring
-        self._arena_ring = WindowArenaRing(metrics=metrics)
+        self._arena_ring = WindowArenaRing()
         self._cols = RequestColumns()
         self._cols_pool: List[RequestColumns] = []
         # per-fetch-thread response encode buffer (RpcJob.finish)
@@ -607,6 +642,15 @@ class DispatchPipeline:
                            "fetch_decode": 0.0}
         self.active_wall = 0.0
         self._active_since = 0.0
+        # why the pump is not dispatching right now, since when, and the
+        # seconds every reason has held so far (loop thread only)
+        self._hold_reason: Optional[str] = None
+        self._hold_since = 0.0
+        self.pump_hold = dict.fromkeys(PUMP_HOLD_REASONS, 0.0)
+        # reply_wake of the requests that have resumed since the last
+        # commit (plain floats; the next commit flushes them)
+        self._wake_seconds = 0.0
+        self._wake_requests = 0
         self._singles: List[tuple] = []   # (req, fut, t_enq, ctx, col_idx)
         # GLOBAL singles (lockstep mode only): staged into the tick drain's
         # composed GLOBAL window, never mixed into regular ListJobs
@@ -764,7 +808,9 @@ class DispatchPipeline:
             self.tracer.record_span(job.ctx, "enqueue", job.enq, job.enq)
         self._jobs.append(job)
         self._pump()
-        return await fut
+        out = await fut
+        self._woke(job, job.ctx)
+        return out
 
     async def submit_cols(self, cols: tuple, n: int,
                           want_cols: bool = False,
@@ -799,7 +845,9 @@ class DispatchPipeline:
                 self.tracer.record_span(ctx, "enqueue", job.enq, job.enq)
         self._jobs.append(job)
         self._pump()
-        return await fut
+        out = await fut
+        self._woke(job, ctx)
+        return out
 
     async def submit_one(self, req: RateLimitReq) -> RateLimitResp:
         self._loop = asyncio.get_running_loop()
@@ -822,18 +870,46 @@ class DispatchPipeline:
             self._singles.append((req, fut, t_enq, ctx,
                                   self._cols.append(req)))
         self._pump()
-        return await fut
+        out = await fut
+        # _take_jobs hung the chunk this request rode in on its future
+        self._woke(getattr(fut, "job", None), ctx)
+        return out
 
     async def submit_many(self, reqs: Sequence[RateLimitReq]
                           ) -> List[RateLimitResp]:
         self._loop = asyncio.get_running_loop()
         fut = self._loop.create_future()
         ctx = current_context()
-        self._jobs.append(ListJob(reqs, fut=fut,
-                                  ctxs=[ctx] if ctx is not None else None,
-                                  enq=time.monotonic()))
+        job = ListJob(reqs, fut=fut,
+                      ctxs=[ctx] if ctx is not None else None,
+                      enq=time.monotonic())
+        self._jobs.append(job)
         self._pump()
-        return await fut
+        out = await fut
+        self._woke(job, ctx)
+        return out
+
+    def _woke(self, job, ctx) -> None:
+        """The coroutine that awaited a request runs again (loop thread):
+        reply_wake = its drain's commit → now, added to plain floats that
+        the next commit flushes into the counters.  A request that never
+        rode a committed drain (fallback, GLOBAL single) has no stamp."""
+        if job is None or not job.committed:
+            return
+        now = time.monotonic()
+        self._wake_seconds += now - job.committed
+        self._wake_requests += 1
+        if ctx is not None and self.tracer is not None:
+            self.tracer.record_span(ctx, "reply_wake", job.committed, now)
+
+    def flush_reply_wake(self) -> None:
+        """Move the reply_wake of the requests that resumed since the last
+        commit into guber_tpu_request_stage_*_total (loop thread; every
+        commit and close() call it)."""
+        n, seconds = self._wake_requests, self._wake_seconds
+        self._wake_requests, self._wake_seconds = 0, 0.0
+        if n and self.metrics is not None:
+            self.metrics.observe_request_stage("reply_wake", seconds, n)
 
     def eligible(self, req: RateLimitReq) -> bool:
         """May this request ride the pipeline?  Mirrors the C-side range
@@ -916,6 +992,10 @@ class DispatchPipeline:
                               futs=[t[1] for t in chunk],
                               ctxs=[t[3] for t in chunk],
                               enq=min(t[2] for t in chunk))
+                job.enq_lag = (sum(t[2] for t in chunk)
+                               - len(chunk) * job.enq)
+                for t in chunk:
+                    t[1].job = job  # submit_one reads job.committed
                 # zero-copy when the chunk is contiguous in submission
                 # order (the common no-QoS case); a tenant-fair or
                 # budget-cut permutation gathers instead
@@ -952,7 +1032,13 @@ class DispatchPipeline:
             # the chain needs stride drains pending fetch PLUS one being
             # packed/dispatched, or it could never reach its stride
             depth = max(depth, stride + 1)
-        if self._closed or self._in_flight >= depth:
+        if self._closed:
+            return
+        if self._in_flight >= depth:
+            # full, with work behind it: held by depth.  Full with nothing
+            # queued is no hold at all.
+            self._note_hold("depth" if self._singles or self._jobs
+                            else None)
             return
         if self.gate_enabled and self._in_flight >= 1 and self.gate_frac > 0:
             # occupancy gate: a drain is already hiding the device time, so
@@ -970,6 +1056,7 @@ class DispatchPipeline:
             eng = self.engine
             if lanes_est < (self.gate_frac * eng.batch_per_shard
                             * eng.num_local_shards):
+                self._note_hold("gate" if pending else "empty")
                 return
         if not force and self.coalesce_wait > 0:
             # RpcJobs are unparsed here: estimate items from the wire size
@@ -981,6 +1068,7 @@ class DispatchPipeline:
                 if self._coalesce_handle is None:
                     self._coalesce_handle = self._loop.call_later(
                         self.coalesce_wait, self._coalesce_fire)
+                self._note_hold("coalesce")
                 return
         if self._coalesce_handle is not None:
             self._coalesce_handle.cancel()
@@ -994,13 +1082,42 @@ class DispatchPipeline:
                 # adds latency (e.g. a prior unchained drain just
                 # committed and re-pumped an empty queue)
                 self._chain_flush()
+            self._note_hold("empty")
             return
+        self._note_hold(None)
         self._note_inflight(1)
         self._predispatch += 1
         fut = self._loop.run_in_executor(self._engine_executor,
                                          self._drain_sync, jobs, None, None,
-                                         None, cols)
+                                         None, cols, time.monotonic())
         fut.add_done_callback(lambda f: self._on_dispatched(f, jobs))
+
+    def _note_hold(self, reason: Optional[str]) -> None:
+        """_pump returns without dispatching for `reason`, or dispatches
+        (None).  The reason and its start are noted once; the seconds go
+        to guber_tpu_pump_hold_seconds_total{reason} when the reason
+        changes or a dispatch ends the hold.  `empty` runs only while
+        nothing is queued and fewer drains are in flight than the depth
+        allows: every submit pumps, and that pump either dispatches or
+        names another reason."""
+        held = self._hold_reason
+        if reason == held:
+            return
+        now = time.monotonic()
+        if held is not None:
+            seconds = now - self._hold_since
+            self.pump_hold[held] += seconds
+            if self.metrics is not None:
+                self.metrics.pump_hold_seconds.labels(
+                    reason=held).inc(seconds)
+        self._hold_reason, self._hold_since = reason, now
+
+    def pump_hold_snapshot(self) -> dict:
+        """Seconds held per reason so far, the running hold included."""
+        out = dict(self.pump_hold)
+        if self._hold_reason is not None:
+            out[self._hold_reason] += time.monotonic() - self._hold_since
+        return out
 
     def _coalesce_fire(self) -> None:
         self._coalesce_handle = None
@@ -1047,8 +1164,6 @@ class DispatchPipeline:
         held back by the occupancy gate re-arms the linger timer as the
         backstop: a chained commit is never more than chain_linger late."""
         self._chain.append(res)
-        if self.metrics is not None:
-            self.metrics.chain_inflight_windows.set(len(self._chain))
         idle = (not self._jobs and not self._singles
                 and self._predispatch == 0)
         if len(self._chain) >= self._stride_target or idle or self._closed:
@@ -1071,11 +1186,7 @@ class DispatchPipeline:
         self.chain_flushes += 1
         self.fetch_elided += len(group) - 1
         if self.metrics is not None:
-            m = self.metrics
-            m.chain_inflight_windows.set(0)
-            m.chain_fetch_stride.set(self._stride_target)
-            if len(group) > 1:
-                m.chain_fetch_elided.inc(len(group) - 1)
+            self.metrics.chain_fetch_stride.set(self._stride_target)
         if self.qos is not None:
             self.qos.congestion.observe_chain(self._backlog_windows(),
                                               self.fetch_stride_max)
@@ -1129,8 +1240,10 @@ class DispatchPipeline:
                     res.stats_host = eng._fetch_local(res.stats)
                 except Exception:
                     log.exception("analytics stats fetch failed")
-            outs = [job.finish(self, wflat, clflat, res.now)
-                    for job in res.staged]
+            res.fetch_ready = time.monotonic()
+            with TraceAnnotation("guber_decode"):
+                outs = [job.finish(self, wflat, clflat, res.now)
+                        for job in res.staged]
             res.fetch_done = time.monotonic()
             pairs.append((res, outs))
         return pairs
@@ -1141,6 +1254,7 @@ class DispatchPipeline:
         group fetch fails EVERY member's jobs — one stacked fetch means
         one failure domain, and none of the members' arenas can prove the
         device finished with them (all dropped)."""
+        t_cb = time.monotonic()
         try:
             pairs = fut.result()
         except Exception as e:
@@ -1159,6 +1273,7 @@ class DispatchPipeline:
                     "chain_fetch",
                     head.chain_fetch_done - head.chain_fetch_start)
         for res, outs in pairs:
+            res.completed_cb = t_cb
             self._commit_completed(res, outs)
 
     def _take_global_job(self) -> Optional[_GlobalJob]:
@@ -1208,17 +1323,20 @@ class DispatchPipeline:
         all_jobs = jobs + ([gjob] if gjob is not None else [])
         self._note_inflight(1)
         self._predispatch += 1
+        pumped = time.monotonic()
         fut = self._loop.run_in_executor(
             self._engine_executor,
             lambda: self._drain_sync(jobs, now=now, k_fixed=k_stack,
-                                     gjob=gjob, cols=cols))
+                                     gjob=gjob, cols=cols, pumped=pumped))
         fut.add_done_callback(lambda f: self._on_dispatched(f, all_jobs))
         return fut
 
     def _on_dispatched(self, fut, jobs) -> None:
+        t_cb = time.monotonic()
         self._predispatch -= 1
         try:
             res: _DrainResult = fut.result()
+            res.dispatched_cb = t_cb
         except Exception as e:  # drain itself crashed (bug): fail ITS jobs
             log.exception("pipeline drain failed")
             self._note_inflight(-1)
@@ -1371,6 +1489,7 @@ class DispatchPipeline:
                     one_chunk(owner_idx, items[base:base + MAX_BATCH_SIZE]))
 
     def _on_completed(self, fut, res: _DrainResult) -> None:
+        res.completed_cb = time.monotonic()
         try:
             _, outs = fut.result()
         except Exception as e:  # fetch/demux failed: fail THIS drain's jobs
@@ -1396,6 +1515,10 @@ class DispatchPipeline:
         self._pump(force=True)
 
     def _commit_completed(self, res: _DrainResult, outs) -> None:
+        with TraceAnnotation("guber_commit"):
+            self._commit(res, outs)
+
+    def _commit(self, res: _DrainResult, outs) -> None:
         self._note_inflight(-1)
         self._cols_release(res.cols_owner)
         res.cols_owner = None
@@ -1420,6 +1543,22 @@ class DispatchPipeline:
                     self.rpc_served += 1
                 if not job.fut.done():
                     job.fut.set_result(out)
+        res.committed = t_commit = time.monotonic()
+        # one request's stages, summed over this drain's requests: each
+        # job's own wait (a singles chunk is len(futs) requests, see
+        # ListJob.enq_lag), and the stamp its coroutine measures
+        # reply_wake from (no coroutine runs before this callback returns)
+        n_req, queue_wait = 0, 0.0
+        for job in res.staged:
+            enq = getattr(job, "enq", 0.0)
+            if not enq:
+                continue  # a _GlobalJob carries no enqueue stamp
+            futs = getattr(job, "futs", None)
+            n = 1 if futs is None else len(futs)
+            n_req += n
+            queue_wait += (n * (res.started - enq)
+                           - getattr(job, "enq_lag", 0.0))
+            job.committed = t_commit
         # ONE clock for control and observability: the drain wall time is
         # the traced stage boundary (started→fetch_done), so the AIMD's
         # EWMA and the guber_tpu_stage_duration_ms histograms read the
@@ -1438,13 +1577,6 @@ class DispatchPipeline:
         sb["host_encode"] += t_he
         sb["device_dispatch"] += t_disp
         sb["fetch_decode"] += t_fetch
-        if self.metrics is not None:
-            wall = self.active_wall
-            if self._active_since:
-                wall += time.monotonic() - self._active_since
-            if wall > 0:
-                self.metrics.pipeline_overlap_ratio.set(
-                    sum(sb.values()) / wall)
         if self.qos is not None and res.n_decisions:
             self.qos.congestion.observe_drain(
                 drain_wall, depth=max(1, res.k_used))
@@ -1469,24 +1601,41 @@ class DispatchPipeline:
             m.window_duration.observe(drain_wall)
             m.agg_decisions.inc(res.n_decisions)
             m.agg_lanes.inc(res.n_lanes)
-            # fused-path adoption + per-drain window depth (ISSUE 2
-            # observability): how deep the stacks actually run, and whether
-            # the drains lower to the fused megakernel
-            m.drain_depth.observe(res.k_used)
-            if self.fused_serving:
-                m.fused_drains.inc()
             # stage-latency decomposition from the drain's boundary stamps
             # (0.0 boundary = never reached, e.g. an idle lockstep tick)
             if res.oldest_enq:
                 m.observe_stage("admission_wait", res.started - res.oldest_enq)
+            if res.pumped:
+                m.observe_stage("engine_queue", res.started - res.pumped)
             if res.pack_done:
                 m.observe_stage("window_fill", res.pack_done - res.started)
             if res.dispatch_done and res.pack_done:
                 m.observe_stage("device_dispatch",
                                 res.dispatch_done - res.pack_done)
+            if res.dispatch_done and res.dispatched_cb:
+                m.observe_stage("dispatch_hop",
+                                res.dispatched_cb - res.dispatch_done)
             if res.fetch_done and res.fetch_start:
+                if res.dispatch_done and not res.chain_fetch_start:
+                    # a chained drain waits for its group, not for a worker
+                    m.observe_stage("fetch_queue",
+                                    res.fetch_start - res.dispatch_done)
                 m.observe_stage("drain_commit",
                                 res.fetch_done - res.fetch_start)
+                if res.fetch_ready:
+                    m.observe_stage("device_wait",
+                                    res.fetch_ready - res.fetch_start)
+                    m.observe_stage("decode",
+                                    res.fetch_done - res.fetch_ready)
+                if res.completed_cb:
+                    m.observe_stage("complete_hop",
+                                    res.completed_cb - res.fetch_done)
+            if res.completed_cb:
+                m.observe_stage("commit", t_commit - res.completed_cb)
+            m.observe_request_stage("queue_wait", queue_wait, n_req)
+            m.observe_request_stage("in_drain",
+                                    n_req * (t_commit - res.started), n_req)
+            self.flush_reply_wake()
         # window clock (observability/devprof.py): dispatch→fetch-ready
         # per executable arm, EWMA + histogram; slow windows capture
         # trace-ID exemplars lazily (the thunk only runs on a slow window)
@@ -1520,6 +1669,9 @@ class DispatchPipeline:
                 if c.enqueued_at:
                     tr.record_span(c, "admission_wait", c.enqueued_at,
                                    res.started)
+                    tr.record_span(c, "queue_wait", c.enqueued_at,
+                                   res.started)
+                tr.record_span(c, "in_drain", res.started, t_commit)
                 if res.pack_done:
                     tr.record_span(c, "window_fill", res.started,
                                    res.pack_done)
@@ -1552,6 +1704,10 @@ class DispatchPipeline:
                 else:
                     parts.append(fwd[i])
             if not job.fut.done():
+                # reply_wake runs from the resolve, not from the drain's
+                # commit: the wait for the owners is no wake-up latency
+                if job.committed:
+                    job.committed = time.monotonic()
                 job.fut.set_result(b"".join(parts))
         except BaseException as e:  # noqa: BLE001 — a cancelled task must
             # still resolve the RPC future it owes (same contract as
@@ -1603,28 +1759,30 @@ class DispatchPipeline:
     def _drain_sync(self, jobs: List[object], now: Optional[int] = None,
                     k_fixed: Optional[int] = None,
                     gjob: Optional[_GlobalJob] = None,
-                    cols: Optional[RequestColumns] = None) -> _DrainResult:
-        """Engine-thread drain entry: wraps the real drain in the armed
-        jax.profiler capture when POST /v1/admin/profile requested one
-        (plain int read when disarmed — the hot path pays nothing)."""
+                    cols: Optional[RequestColumns] = None,
+                    pumped: float = 0.0) -> _DrainResult:
+        """Engine-thread drain entry: counts the drain into the running
+        jax.profiler capture when POST /v1/admin/profile armed one.  The
+        capture's own thread starts and stops the profiler
+        (observability/introspect.py); this thread reads one bool and,
+        under a capture, decrements one int."""
         prof = self.profile
-        if prof is not None and prof.armed:
-            prof.before_drain()
+        if prof is not None and prof.tracing:
             try:
                 return self._drain_sync_inner(jobs, now=now,
                                               k_fixed=k_fixed, gjob=gjob,
-                                              cols=cols)
+                                              cols=cols, pumped=pumped)
             finally:
                 prof.after_drain()
         return self._drain_sync_inner(jobs, now=now, k_fixed=k_fixed,
-                                      gjob=gjob, cols=cols)
+                                      gjob=gjob, cols=cols, pumped=pumped)
 
     def _drain_sync_inner(self, jobs: List[object],
                           now: Optional[int] = None,
                           k_fixed: Optional[int] = None,
                           gjob: Optional[_GlobalJob] = None,
-                          cols: Optional[RequestColumns] = None
-                          ) -> _DrainResult:
+                          cols: Optional[RequestColumns] = None,
+                          pumped: float = 0.0) -> _DrainResult:
         """Pack every job into one stacked compact dispatch (engine thread).
 
         Staging comes from the arena ring (core/window_buffers.py): the
@@ -1654,6 +1812,7 @@ class DispatchPipeline:
         B = eng.batch_per_shard
         K = self.k_max if k_fixed is None else k_fixed
         res = _DrainResult()
+        res.pumped = pumped
         res.started = time.monotonic()
         if now is None:
             now = self.now_fn()
@@ -1663,71 +1822,72 @@ class DispatchPipeline:
         list_ok = (eng._compact_sound if self.lockstep
                    else eng._compact_enabled)
 
-        arena = self._arena_ring.acquire(K, S, B)
-        res.arena = arena
-        arena.dirty = True
-        # the arena may be deeper than K (ring matches K >=); trailing
-        # rows stay zero, and the k-stride is K-independent, so the C
-        # calls and the [:kb] dispatch slices below are unaffected
-        packed = arena.packed
-        fills = arena.fills
-        kcur = arena.kcur
-        native.drain_begin()
-        stack_empty = True
-        res.ring_peers = self._ring_peers
-        for idx, job in enumerate(jobs):
-            if isinstance(job, RpcJob):
-                if not rpc_ok:
-                    res.fallback.append(job)
-                    continue
-                scr = arena.acquire_scratch()
-                job.row, job.lane, job.pos = scr.row, scr.lane, scr.pos
-                job.limit, job.off, job.mlen = scr.limit, scr.off, scr.mlen
-                n = native.parse_stack_fast(
-                    job.data, now, B, K, MAX_BATCH_SIZE, arena, scr,
-                    use_ring=not job.peer_mode)
-                if n >= 0:
-                    job.n = n
-                    job.remote_idx = np.flatnonzero(job.row[:n] < -1)
-                    res.staged.append(job)
-                    if len(job.remote_idx):
-                        # the forward coroutines keep reading off/mlen on
-                        # the loop after this drain completes: the block
-                        # leaves the pool with the job (recycle drops it)
-                        scr.leased = True
-                    if len(job.remote_idx) < n:
+        with TraceAnnotation("guber_pack"):
+            arena = self._arena_ring.acquire(K, S, B)
+            res.arena = arena
+            arena.dirty = True
+            # the arena may be deeper than K (ring matches K >=); trailing
+            # rows stay zero, and the k-stride is K-independent, so the C
+            # calls and the [:kb] dispatch slices below are unaffected
+            packed = arena.packed
+            fills = arena.fills
+            kcur = arena.kcur
+            native.drain_begin()
+            stack_empty = True
+            res.ring_peers = self._ring_peers
+            for idx, job in enumerate(jobs):
+                if isinstance(job, RpcJob):
+                    if not rpc_ok:
+                        res.fallback.append(job)
+                        continue
+                    scr = arena.acquire_scratch()
+                    job.row, job.lane, job.pos = scr.row, scr.lane, scr.pos
+                    job.limit, job.off, job.mlen = scr.limit, scr.off, scr.mlen
+                    n = native.parse_stack_fast(
+                        job.data, now, B, K, MAX_BATCH_SIZE, arena, scr,
+                        use_ring=not job.peer_mode)
+                    if n >= 0:
+                        job.n = n
+                        job.remote_idx = np.flatnonzero(job.row[:n] < -1)
+                        res.staged.append(job)
+                        if len(job.remote_idx):
+                            # the forward coroutines keep reading off/mlen on
+                            # the loop after this drain completes: the block
+                            # leaves the pool with the job (recycle drops it)
+                            scr.leased = True
+                        if len(job.remote_idx) < n:
+                            stack_empty = False
+                    elif n == -6 and not stack_empty:
+                        res.leftover = jobs[idx:]
+                        break
+                    else:
+                        res.fallback.append(job)
+                else:
+                    if not list_ok:
+                        res.fallback.append(job)
+                        continue
+                    jcols = job.columns()
+                    if job.n > MAX_BATCH_SIZE:
+                        # oversized submit_many batch: the C router rejects it
+                        # (-3) before writing, but the scratch block could not
+                        # hold its demux anyway — route it to the legacy lane
+                        res.fallback.append(job)
+                        continue
+                    scr = arena.acquire_scratch()
+                    # slice to job.n: finish()'s fancy-indexed demux must see
+                    # exactly n entries (the views share the cached C pointers)
+                    job.row = scr.row[:job.n]
+                    job.lane = scr.lane[:job.n]
+                    job.pos = scr.pos[:job.n]
+                    rc = native.pack_stack_fast(*jcols, now, B, K, arena, scr)
+                    if rc >= 0:
+                        res.staged.append(job)
                         stack_empty = False
-                elif n == -6 and not stack_empty:
-                    res.leftover = jobs[idx:]
-                    break
-                else:
-                    res.fallback.append(job)
-            else:
-                if not list_ok:
-                    res.fallback.append(job)
-                    continue
-                jcols = job.columns()
-                if job.n > MAX_BATCH_SIZE:
-                    # oversized submit_many batch: the C router rejects it
-                    # (-3) before writing, but the scratch block could not
-                    # hold its demux anyway — route it to the legacy lane
-                    res.fallback.append(job)
-                    continue
-                scr = arena.acquire_scratch()
-                # slice to job.n: finish()'s fancy-indexed demux must see
-                # exactly n entries (the views share the cached C pointers)
-                job.row = scr.row[:job.n]
-                job.lane = scr.lane[:job.n]
-                job.pos = scr.pos[:job.n]
-                rc = native.pack_stack_fast(*jcols, now, B, K, arena, scr)
-                if rc >= 0:
-                    res.staged.append(job)
-                    stack_empty = False
-                elif rc == -6 and not stack_empty:
-                    res.leftover = jobs[idx:]
-                    break
-                else:
-                    res.fallback.append(job)
+                    elif rc == -6 and not stack_empty:
+                        res.leftover = jobs[idx:]
+                        break
+                    else:
+                        res.fallback.append(job)
 
         res.pack_done = time.monotonic()
         enqs = [e for e in (getattr(j, "enq", 0.0) for j in res.staged) if e]
@@ -2058,9 +2218,11 @@ class DispatchPipeline:
                 res.stats_host = eng._fetch_local(res.stats)
             except Exception:
                 log.exception("analytics stats fetch failed")
-        outs = [job.finish_global(gflat) if isinstance(job, _GlobalJob)
-                else job.finish(self, wflat, clflat, res.now)
-                for job in res.staged]
+        res.fetch_ready = time.monotonic()
+        with TraceAnnotation("guber_decode"):
+            outs = [job.finish_global(gflat) if isinstance(job, _GlobalJob)
+                    else job.finish(self, wflat, clflat, res.now)
+                    for job in res.staged]
         res.fetch_done = time.monotonic()
         return res, outs
 
@@ -2090,3 +2252,4 @@ class DispatchPipeline:
         # was already queued
         self._chain_flush()
         self._fetch_executor.shutdown(wait=False)
+        self.flush_reply_wake()

@@ -21,8 +21,8 @@ reusable arenas:
     (fetch done ⇒ device execution done ⇒ the H2D transfer that read the
     buffers is finished).  Error paths simply drop the arena — the ring
     allocates a replacement later, which is self-healing and keeps the
-    transfer-safety argument trivial.  Reuse vs. realloc is reported via
-    guber_tpu_window_buffer_reuse_total{event=reuse|alloc}.
+    transfer-safety argument trivial.  Reuse vs. realloc counts are in
+    /v1/admin/debug (pipeline.overlap.arena_reuse_events / _alloc_events).
   * `RequestColumns` — columnar accumulation of single-request submits:
     hits/limit/duration/algorithm land in preallocated numpy columns at
     submit time, so a drain takes window columns as array slices (the
@@ -133,16 +133,13 @@ class WindowArena:
 class WindowArenaRing:
     """Free list of WindowArenas keyed by stack shape.  Acquire happens on
     the engine thread, release on the event loop (drain completion), so
-    the list sits behind a lock.  `metrics` (observability.Metrics or
-    None) receives reuse/alloc events as
-    guber_tpu_window_buffer_reuse_total{event=...}."""
+    the list sits behind a lock."""
 
-    def __init__(self, metrics=None, max_free: int = 8):
+    def __init__(self, max_free: int = 8):
         self._free: List[WindowArena] = []
         self._lock = threading.Lock()
         self._max_free = max_free
-        self.metrics = metrics
-        # telemetry mirrors of the counter (tests + probe read these)
+        # read by overlap_snapshot (/v1/admin/debug), tests and the probe
         self.reuse_events = 0
         self.alloc_events = 0
 
@@ -155,10 +152,8 @@ class WindowArenaRing:
                     break
         if arena is not None:
             self.reuse_events += 1
-            self._count("reuse")
             return arena
         self.alloc_events += 1
-        self._count("alloc")
         return WindowArena(K, S, B)
 
     def release(self, arena: Optional[WindowArena]) -> None:
@@ -172,10 +167,6 @@ class WindowArenaRing:
         with self._lock:
             if len(self._free) < self._max_free:
                 self._free.append(arena)
-
-    def _count(self, event: str) -> None:
-        if self.metrics is not None:
-            self.metrics.window_buffer_reuse.labels(event=event).inc()
 
 
 class RequestColumns:

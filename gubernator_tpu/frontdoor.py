@@ -339,7 +339,7 @@ class _Worker:
                 dmin = deadline
         # ONE trace region per record: the first traced member's context
         # rides the slab; every other traced member is an honest drop
-        # (guber_tpu_frontdoor_trace_drops_total)
+        # (trace_drops per worker in /v1/admin/debug, frontdoor.per_worker)
         carried = next((t for t in tps if t is not None), None)
         extra = sum(1 for t in tps if t is not None) - (1 if carried else 0)
         if extra > 0:

@@ -305,8 +305,6 @@ class WindowClock:
             ew = ms if prev is None else prev + self.ALPHA * (ms - prev)
             self._ewma[arm] = ew
             self._count[arm] = self._count.get(arm, 0) + 1
-        if m is not None:
-            m.device_window_ewma.labels(arm=arm).set(ew)
         # slow = past the absolute floor AND well past this arm's norm
         if ms < self.slow_ms or ms < 3.0 * ew:
             return False
@@ -385,7 +383,7 @@ class DevprofController:
                 # stop the capture and fold whatever it caught
                 self.profile.cancel()
             # `armed` drops only once stop_trace has written the capture
-            # (on the engine thread, or here through cancel)
+            # (on the capture's own thread; cancel waits for it)
             settle = time.monotonic() + 10.0
             while (self.profile.armed and time.monotonic() < settle
                    and not self._stop.is_set()):

@@ -7,11 +7,12 @@ congestion window, per-stage latency quantiles, and recent-trace
 summaries — every number from the same accessors the control loops read,
 so what the operator sees is what the controllers saw.
 
-`ProfileCapture` wraps the next N pipeline drains in
+`ProfileCapture` records the next N pipeline drains under
 `jax.profiler.start_trace/stop_trace` (the GUBER_PROFILE plumbing from
-bench.py, now armable at runtime via `POST /v1/admin/profile`).  The
-armed check runs on the single engine thread around each dispatch, so
-when disarmed the hot path pays one integer compare.
+bench.py, armable at runtime via `POST /v1/admin/profile`).  The profiler
+is started and stopped on a thread of the capture's own: the stop writes
+the trace for seconds, and the single engine thread must not sit through
+it.  The engine thread only counts the armed drains down.
 """
 
 from __future__ import annotations
@@ -25,99 +26,105 @@ log = logging.getLogger("gubernator.introspect")
 
 
 class ProfileCapture:
-    """Arm-and-forget device profiler: `arm(n, dir)` from the admin plane,
-    `before_drain()`/`after_drain()` from the engine thread around each
-    dispatch.  All state transitions happen under the lock, but the
-    disarmed fast path reads the plain int `_remaining` first — stale
-    reads only ever delay a capture by one drain, never corrupt one."""
+    """Arm-and-forget device profiler.  `arm(n, dir)` (admin plane) starts
+    the capture thread, which calls `start_trace`, waits until the engine
+    thread's `after_drain()` has counted `n` drains (or `cancel()`), calls
+    `stop_trace`, and only then clears `active`: a reader that waits for
+    `armed` to drop never parses a half-written capture.
+
+    The engine thread never calls into the profiler.  It reads the plain
+    bool `tracing` before a drain and, when it was set, calls
+    `after_drain()` after it, so every counted drain began after
+    `start_trace` returned.  Drains dispatched while `stop_trace` runs are
+    in the capture too: it may hold more drains than were armed."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._remaining = 0
         self._dir = ""
         self._active = False
-        self._stopping = False
+        self._done = threading.Event()
+        self._thread = None
+        # set by the capture thread between start_trace's return and the
+        # end of the count; read without the lock on the engine thread (a
+        # stale read only moves the count by one drain)
+        self.tracing = False
 
     @property
     def armed(self) -> bool:
-        return self._remaining > 0 or self._active
+        return self._active
 
     def arm(self, drains: int, trace_dir: str = "") -> dict:
-        """Schedule a capture of the next `drains` dispatches.  Default
+        """Start a capture of the next `drains` dispatches.  Default
         directory comes from GUBER_PROFILE (bench.py's knob) or a
         timestamped /tmp path."""
         trace_dir = (trace_dir or os.environ.get("GUBER_PROFILE", "")
                      or f"/tmp/guber-profile-{int(time.time())}")
         with self._lock:
-            if self._active or self._remaining > 0:
+            if self._active:
                 return {"armed": False, "error": "capture already in "
                         "progress", "dir": self._dir}
-            self._remaining = max(1, int(drains))
-            self._dir = trace_dir
-        return {"armed": True, "drains": self._remaining, "dir": trace_dir}
-
-    # ------------------------------------------------- engine-thread hooks
-
-    def before_drain(self) -> None:
-        """Engine thread, just before a dispatch: start the device trace
-        on the first armed drain."""
-        with self._lock:
-            if self._remaining <= 0 or self._active:
-                return
             self._active = True
+            self._remaining = drains = max(1, int(drains))
+            self._dir = trace_dir
+            self._done.clear()
+            self._thread = threading.Thread(
+                target=self._run, name="guber-profile", daemon=True)
+            self._thread.start()
+        return {"armed": True, "drains": drains, "dir": trace_dir,
+                "note": "drains dispatched while the profiler stops are "
+                        "recorded too: the capture may hold more than "
+                        "were armed"}
+
+    def _run(self) -> None:
+        """The capture thread: start, let the engine thread count, stop."""
+        import jax
         try:
-            import jax
             jax.profiler.start_trace(self._dir)
-            log.info("profile capture started -> %s (%d drains)",
-                     self._dir, self._remaining)
         except Exception:
             log.exception("profile capture failed to start")
             with self._lock:
                 self._active = False
                 self._remaining = 0
-
-    def after_drain(self) -> None:
-        """Engine thread, after a dispatch completed: stop once the armed
-        count runs out."""
-        with self._lock:
-            if not self._active:
-                return
-            self._remaining -= 1
-            if self._remaining > 0:
-                return
-        self._stop("stopped")
-
-    def cancel(self) -> None:
-        """Disarm an in-flight capture (continuous profiling's recovery
-        path when traffic never completes the armed drain count): stop the
-        device trace if it started, drop any remaining armed drains."""
-        with self._lock:
-            self._remaining = 0
-        self._stop("cancelled")
-
-    def _stop(self, what: str) -> None:
-        """stop_trace() returns once the profiler has written its files;
-        `armed` stays true until then, so a reader that waits for it to
-        drop never parses a half-written capture."""
-        with self._lock:
-            if not self._active or self._stopping:
-                return
-            self._stopping = True
+            return
+        log.info("profile capture started -> %s (%d drains)",
+                 self._dir, self._remaining)
+        self.tracing = True
+        self._done.wait()
+        self.tracing = False
         try:
-            import jax
             jax.profiler.stop_trace()
-            log.info("profile capture %s -> %s", what, self._dir)
+            log.info("profile capture stopped -> %s", self._dir)
         except Exception:
             log.exception("profile capture failed to stop")
         finally:
             with self._lock:
                 self._active = False
-                self._stopping = False
+                self._remaining = 0
+
+    def after_drain(self) -> None:
+        """Engine thread, after a dispatch that began with `tracing` set:
+        one drain less to record; the last one wakes the capture thread."""
+        with self._lock:
+            self._remaining -= 1
+            if self._remaining > 0:
+                return
+        self._done.set()
+
+    def cancel(self, timeout: float = 30.0) -> None:
+        """End a capture that traffic never completed (continuous
+        profiling's recovery path): the capture thread stops the trace if
+        it started.  Returns once it has, so a caller may arm again."""
+        with self._lock:
+            thread = self._thread
+        self._done.set()
+        if thread is not None:
+            thread.join(timeout)
 
     def status(self) -> dict:
         with self._lock:
-            return {"active": self._active, "remaining": self._remaining,
-                    "dir": self._dir}
+            return {"active": self._active,
+                    "remaining": max(0, self._remaining), "dir": self._dir}
 
 
 def _jsonable(d: dict) -> dict:
@@ -136,6 +143,23 @@ def _jsonable(d: dict) -> dict:
     return out
 
 
+def device_memory(devices) -> dict:
+    """`memory_stats()` of the fullest of `devices`: bytes_in_use,
+    peak_bytes_in_use, bytes_limit, each only where the backend reports
+    it (the CPU backend reports none: the result is then empty)."""
+    best: dict = {}
+    for d in devices:
+        try:
+            stats = d.memory_stats() or {}
+        except Exception:
+            continue
+        if stats.get("bytes_in_use", 0) >= best.get("bytes_in_use", -1):
+            best = stats
+    return {k: int(best[k]) for k in
+            ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+            if k in best}
+
+
 def build_debug_snapshot(instance) -> dict:
     """One coherent operator view of a core.service.Instance."""
     out: dict = {
@@ -143,6 +167,8 @@ def build_debug_snapshot(instance) -> dict:
         "mesh_mode": instance.mesh_mode,
         "standalone": instance.standalone,
         "engine": _jsonable(instance.engine.cache_stats()),
+        "device": {"memory": device_memory(
+            instance.engine.mesh.local_devices)},
     }
     if instance.qos is not None:
         adm = instance.qos.admission
@@ -199,6 +225,7 @@ def build_debug_snapshot(instance) -> dict:
             "lockstep": pipe.lockstep,
             "depth": pipe.depth,
             "overlap": pipe.overlap_snapshot(),
+            "pump_hold_seconds": pipe.pump_hold_snapshot(),
         }
     analytics = getattr(instance, "analytics", None)
     if analytics is not None:

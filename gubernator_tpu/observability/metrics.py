@@ -32,13 +32,33 @@ from prometheus_client import CONTENT_TYPE_LATEST
 # `cli debug` table line up column-for-column.
 STAGES = (
     "enqueue",          # submit -> appended to the pending window
-    "admission_wait",   # time queued before a dispatch takes the request
+    "admission_wait",   # oldest request of a drain: queued -> drain started
+    "engine_queue",     # loop hands the drain over -> engine thread starts it
     "window_fill",      # host-side window build (pack keys, stage cols)
-    "device_dispatch",  # engine thread: device step launch through done
-    "drain_commit",     # fetch thread: device->host readback + replies
+    "device_dispatch",  # engine thread: device step launch (the enqueue)
+    "dispatch_hop",     # dispatch done -> _on_dispatched runs on the loop
+    "fetch_queue",      # dispatch done -> a fetch worker picks the drain up
+    "drain_commit",     # fetch thread: device_wait + decode
+    "device_wait",      # fetch thread blocked on the device->host read
+    "decode",           # fetch thread: every job's finish() (decode, encode)
+    "complete_hop",     # fetch done -> _on_completed runs on the loop
+    "commit",           # loop thread: futures resolved, arena released
     "peer_forward",     # non-owner hop: peer-lane RPC round trip
     "global_broadcast", # GLOBAL lane: owner's broadcast to all peers
 )
+
+# Stages of ONE request, summed per drain into
+# guber_tpu_request_stage_{seconds,requests}_total (core/pipeline.py):
+# queued -> its drain started -> its drain committed -> its coroutine ran
+# again.  With the handler's own grpc_request_duration_milliseconds they
+# split the server's time per RPC into measured parts.
+REQUEST_STAGES = ("queue_wait", "in_drain", "reply_wake")
+
+# Why _pump returned without dispatching
+# (guber_tpu_pump_hold_seconds_total): `empty` = room for a drain and
+# nothing queued, `gate` = the occupancy gate, `coalesce` = the batch-wait
+# timer, `depth` = work queued behind a full pipeline.
+PUMP_HOLD_REASONS = ("empty", "gate", "coalesce", "depth")
 
 
 class _StageRing:
@@ -153,22 +173,6 @@ class Metrics:
             "Wall time of one device window step.",
             registry=self.registry,
         )
-        # fused-path adoption + drain depth (core/pipeline.py): how many
-        # drains lowered to the fused megakernel, and how many windows deep
-        # each drain's K-stack actually ran — rate(fused)/rate(windows) is
-        # live adoption, the depth histogram is the decisions-per-dispatch
-        # lever the cost model optimizes
-        self.fused_drains = Counter(
-            "guber_tpu_fused_drains_total",
-            "Pipeline drains served by the fused Pallas megakernel.",
-            registry=self.registry,
-        )
-        self.drain_depth = Histogram(
-            "guber_tpu_drain_depth_windows",
-            "Occupied window depth K per pipeline drain.",
-            buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512),
-            registry=self.registry,
-        )
         # kernel-ladder scoreboard (daemon boot + the devprof admin
         # endpoint): executed-kernel census of the composed serving
         # arm, kernels per window.  A property of the traced program — the
@@ -182,66 +186,33 @@ class Metrics:
             registry=self.registry,
         )
         # overlapped drain pipeline (core/pipeline.py): concurrent drains in
-        # flight, the host/device/fetch overlap achieved, and staging arena
-        # recycling (core/window_buffers.py) — overlap_ratio is
-        # sum(stage busy) / pipeline-active wall, so 1.0 means strictly
-        # serial stages and ~depth means perfect overlap
+        # flight (the overlap ratio and the arena ring's reuse counts are
+        # in /v1/admin/debug, pipeline.overlap)
         self.pipeline_inflight_windows = Gauge(
             "guber_tpu_pipeline_inflight_windows",
             "Drain windows currently in flight between dispatch and commit.",
             registry=self.registry,
         )
-        self.pipeline_overlap_ratio = Gauge(
-            "guber_tpu_pipeline_overlap_ratio",
-            "Aggregate stage busy time divided by pipeline-active wall time "
-            "(1.0 = serial, >1 = host/device/fetch stages overlapped).",
-            registry=self.registry,
-        )
-        self.window_buffer_reuse = Counter(
-            "guber_tpu_window_buffer_reuse_total",
-            "Drain staging arena acquisitions by outcome.",
-            ["event"],  # reuse | alloc
-            registry=self.registry,
-        )
         # deferred-fetch dispatch chain (core/pipeline.py): the adaptive
-        # stride (drains per stacked D2H fetch), how many dispatched
-        # drains currently await the chain's shared fetch, and the fetch
-        # round trips the chain has elided altogether
+        # stride (drains per stacked D2H fetch); chained_pending and
+        # fetch_elided are in /v1/admin/debug, pipeline.overlap
         self.chain_fetch_stride = Gauge(
             "guber_tpu_chain_fetch_stride",
             "Current deferred-fetch chain stride (drains per stacked "
             "fetch; 1 = fetch every drain).",
             registry=self.registry,
         )
-        self.chain_inflight_windows = Gauge(
-            "guber_tpu_chain_inflight_windows",
-            "Dispatched drains currently chained awaiting the shared "
-            "stacked fetch.",
-            registry=self.registry,
-        )
-        self.chain_fetch_elided = Counter(
-            "guber_tpu_chain_fetch_elided_total",
-            "Device-to-host fetch round trips elided by chaining drains "
-            "behind one stacked fetch.",
-            registry=self.registry,
-        )
         # device-time flight recorder (observability/devprof.py): the
         # always-on dispatch->fetch-ready window clock per executable arm
-        # (fused_window / composed_drain / composed_analytics), its EWMA,
-        # and the continuous-mode capture outcomes
+        # (fused_window / composed_drain / composed_analytics; its EWMA is
+        # in /v1/admin/debug, devprof.clock) and the continuous-mode
+        # capture outcomes
         self.device_window_ms = Histogram(
             "guber_tpu_device_window_ms",
             "Dispatch-to-fetch-ready wall time of one drain window, by "
             "executable arm.",
             ["arm"],
             buckets=(0.25, 0.5, 1, 2.5, 5, 10, 25, 50, 100, 250, 1000),
-            registry=self.registry,
-        )
-        self.device_window_ewma = Gauge(
-            "guber_tpu_device_window_ewma_ms",
-            "EWMA of the dispatch-to-fetch-ready window time, by "
-            "executable arm.",
-            ["arm"],
             registry=self.registry,
         )
         self.devprof_captures = Counter(
@@ -408,6 +379,38 @@ class Metrics:
                      250, 500, 1000, 2500),
             registry=self.registry,
         )
+        # one request's stages, summed per drain (REQUEST_STAGES above):
+        # seconds / requests is the mean per request, over the very
+        # requests grpc_request_duration_milliseconds counts
+        self.request_stage_seconds = Counter(
+            "guber_tpu_request_stage_seconds_total",
+            "Seconds requests spent in a stage of their own lifecycle, "
+            "summed over the requests of every committed drain.",
+            ["stage"],
+            registry=self.registry,
+        )
+        self.request_stage_requests = Counter(
+            "guber_tpu_request_stage_requests_total",
+            "Requests counted into guber_tpu_request_stage_seconds_total.",
+            ["stage"],
+            registry=self.registry,
+        )
+        self.pump_hold_seconds = Counter(
+            "guber_tpu_pump_hold_seconds_total",
+            "Seconds the pump held no dispatch, by reason (empty = room "
+            "for a drain and nothing queued | gate | coalesce | depth).",
+            ["reason"],
+            registry=self.registry,
+        )
+        # a labelled child that was never touched is absent from /metrics,
+        # and a reader cannot tell absent from zero: make every child now
+        for stage in STAGES:
+            self.stage_duration.labels(stage=stage)
+        for stage in REQUEST_STAGES:
+            self.request_stage_seconds.labels(stage=stage)
+            self.request_stage_requests.labels(stage=stage)
+        for reason in PUMP_HOLD_REASONS:
+            self.pump_hold_seconds.labels(reason=reason)
         # traffic analytics (ops/analytics.py device reduction +
         # observability/analytics.py host merge): hot keys, per-tenant
         # accounting, device-computed arena occupancy/churn
@@ -553,19 +556,6 @@ class Metrics:
             "guber_tpu_frontdoor_batch_flushes_total",
             "Multi-RPC batch records published to the shm ring "
             "(KIND_BATCH_COLS), per worker.",
-            ["worker"],
-            registry=self.registry,
-        )
-        # trace propagation across the shm hand-off (frontdoor.py): RPCs
-        # that arrived with a sampled traceparent the worker could NOT
-        # carry through the slab record (raw-bytes fallback records have
-        # no trace region; a coalesced batch carries only its first
-        # member's context)
-        self.frontdoor_trace_drops = Counter(
-            "guber_tpu_frontdoor_trace_drops_total",
-            "Sampled trace contexts dropped at the shm hand-off, per "
-            "worker (raw-record fallback, or non-first members of a "
-            "coalesced batch).",
             ["worker"],
             registry=self.registry,
         )
@@ -735,7 +725,6 @@ class Metrics:
                        path="engine")
                 _delta(w, _sr.W_BATCH_RPCS, self.frontdoor_batched_rpcs)
                 _delta(w, _sr.W_BATCH_FLUSHES, self.frontdoor_batch_flushes)
-                _delta(w, _sr.W_TRACE_DROPS, self.frontdoor_trace_drops)
                 if hub.chans:
                     self.shm_ring_depth.labels(worker=w).set(
                         hub.chans[i].sub_depth())
@@ -833,6 +822,16 @@ class Metrics:
             with self._stage_rings_lock:
                 ring = self._stage_rings.setdefault(stage, _StageRing())
         ring.observe(seconds)
+
+    def observe_request_stage(self, stage: str, seconds: float,
+                              requests: int) -> None:
+        """One drain's worth of one request stage: the summed seconds of
+        `requests` requests (one increment per stage per drain)."""
+        if requests <= 0:
+            return
+        self.request_stage_seconds.labels(stage=stage).inc(
+            max(0.0, seconds))
+        self.request_stage_requests.labels(stage=stage).inc(requests)
 
     def stage_snapshot(self) -> Dict[str, dict]:
         """Rolling per-stage quantiles, `engine.cache_stats`-style: one

@@ -19,6 +19,7 @@ import time
 from typing import Optional
 
 import grpc
+from jax.profiler import TraceAnnotation
 
 from gubernator_tpu.api import pb
 from gubernator_tpu.api.grpc_api import add_peers_servicer, add_v1_servicer
@@ -106,8 +107,35 @@ async def serve_get_rate_limits(inst: Instance, data: bytes,
     kind, val = await serve_get_rate_limits_inner(inst, data, context)
     if kind == "bytes":
         return val
-    return pb.GetRateLimitsResp(
-        responses=[pb.resp_to_pb(r) for r in val]).SerializeToString()
+    # the handler's synchronous half after its await, in the device trace
+    with TraceAnnotation("guber_rpc_out"):
+        return pb.GetRateLimitsResp(
+            responses=[pb.resp_to_pb(r) for r in val]).SerializeToString()
+
+
+def _parse_get_rate_limits(inst: Instance, data: bytes, context):
+    """The Python path's synchronous half before its await: protobuf
+    parse, the propagated deadline, request objects, caller identity.
+    Returns (reqs, deadline, client_id), or None for malformed bytes.
+    `guber_rpc_in` puts it into the device trace; it wraps no await (a
+    wait is not work)."""
+    with TraceAnnotation("guber_rpc_in"):
+        try:
+            request = pb.GetRateLimitsReq.FromString(data)
+        except Exception:
+            return None
+        deadline = None
+        if inst.qos is not None:
+            remaining = None
+            tr = getattr(context, "time_remaining", None)
+            if callable(tr):
+                remaining = tr()
+            deadline = inst.qos.deadline_from_timeout(remaining)
+        reqs = [pb.req_from_pb(r) for r in request.requests]
+        client_id = _client_id_from(context)
+        if any(r.algorithm == _Algorithm.CONCURRENCY for r in reqs):
+            _arm_lease_stream_close(inst, context, client_id)
+        return reqs, deadline, client_id
 
 
 async def serve_get_rate_limits_inner(inst: Instance, data: bytes, context):
@@ -140,23 +168,12 @@ async def serve_get_rate_limits_inner(inst: Instance, data: bytes, context):
             m.observe_rpc("/pb.gubernator.V1/GetRateLimits", start,
                           ok=True)
             return "bytes", out
-    try:
-        request = pb.GetRateLimitsReq.FromString(data)
-    except Exception:
+    parsed = _parse_get_rate_limits(inst, data, context)
+    if parsed is None:
         m.observe_rpc("/pb.gubernator.V1/GetRateLimits", start, ok=False)
         await context.abort(grpc.StatusCode.INVALID_ARGUMENT,
                             "malformed GetRateLimitsReq")
-    deadline = None
-    if inst.qos is not None:
-        remaining = None
-        tr = getattr(context, "time_remaining", None)
-        if callable(tr):
-            remaining = tr()
-        deadline = inst.qos.deadline_from_timeout(remaining)
-    reqs = [pb.req_from_pb(r) for r in request.requests]
-    client_id = _client_id_from(context)
-    if any(r.algorithm == _Algorithm.CONCURRENCY for r in reqs):
-        _arm_lease_stream_close(inst, context, client_id)
+    reqs, deadline, client_id = parsed
     try:
         resps = await inst.get_rate_limits(
             reqs, deadline=deadline, client_id=client_id)

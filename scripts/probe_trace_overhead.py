@@ -1,13 +1,20 @@
 """Measure the tracing instrumentation's cost on the serving drain.
 
 Two sweeps over the same in-process Instance (CPU or chip, whatever JAX
-finds): batched single-key submits through the full pipeline drain with
+finds): rounds of single-key requests, each under a root span of its own
+as the gRPC servicer starts one (server.py), through the full pipeline
+drain with
 
   (a) tracing OFF  (sample=0.0, the default) — the hot path should pay
       one attribute check per request; and
   (b) tracing ON   (sample=1.0) — every request records its full span
-      set (enqueue, admission_wait, window_fill, device_dispatch,
-      drain_commit).
+      set (enqueue, admission_wait, queue_wait, window_fill,
+      device_dispatch, drain_commit, in_drain, reply_wake); the sweep
+      fails if one of the per-request three is missing.
+
+Both pay the always-on part: a drain's eleven boundary stamps, its stage
+observations, the per-request stage counters and five inactive
+TraceAnnotations (core/pipeline.py, server.py).
 
 Prints decisions/s for both and the relative overhead.  The acceptance
 bar is <5% for the OFF case relative to the median of its own warm
@@ -52,6 +59,9 @@ def make_reqs():
     ]
 
 
+REQUEST_SPANS = {"queue_wait", "in_drain", "reply_wake"}
+
+
 async def sweep(sample: float, devprof: bool = False) -> float:
     conf = Config(engine=EngineConfig(capacity_per_shard=4096,
                                       batch_per_shard=1024))
@@ -66,13 +76,23 @@ async def sweep(sample: float, devprof: bool = False) -> float:
     inst.engine.warmup()
     reqs = make_reqs()
     rates = []
+
+    async def one(req):
+        # the servicer's root span: a no-op unless the request is sampled
+        with inst.tracer.start_trace("rpc"):
+            return await inst.get_rate_limits([req])
     try:
         for r in range(ROUNDS):
             t0 = time.monotonic()
-            await inst.get_rate_limits(reqs)
+            await asyncio.gather(*[one(q) for q in reqs])
             dt = time.monotonic() - t0
             if r >= WARMUP:
                 rates.append(N_KEYS / dt)
+        if sample > 0.0:
+            missing = REQUEST_SPANS - {s.name for s in inst.tracer.spans()}
+            if missing:
+                raise SystemExit(f"FAIL: no {sorted(missing)} span recorded "
+                                 f"at sample={sample}")
     finally:
         inst.close()
     return statistics.median(rates)

@@ -242,8 +242,8 @@ def test_window_clock_feeds_metrics():
     g = m.registry.get_sample_value
     assert g("guber_tpu_device_window_ms_count",
              {"arm": "compact32_xla"}) == 1.0
-    assert g("guber_tpu_device_window_ewma_ms",
-             {"arm": "compact32_xla"}) == pytest.approx(4.0)
+    assert clk.snapshot()["arms"]["compact32_xla"]["ewma_ms"] == \
+        pytest.approx(4.0)
 
 
 # ------------------------------------------------------------ shm trace region

@@ -431,9 +431,8 @@ def test_lockstep_fused_serving_end_to_end(monkeypatch):
     assert [r.remaining for r in gouts] == [49, 49, 48]
     assert all(not r.error for r in gouts)
     assert b.pipeline.decisions_staged >= 15  # 12 regular + 3 GLOBAL
-    # observability: fused adoption + drain depth advanced with the drains
-    fused_drains = m.registry.get_sample_value("guber_tpu_fused_drains_total")
-    depth_count = m.registry.get_sample_value(
-        "guber_tpu_drain_depth_windows_count")
-    assert fused_drains and fused_drains > 0
-    assert depth_count and depth_count >= fused_drains
+    # observability: the drains were counted and lowered to the fused
+    # megakernel (what /v1/admin/debug shows as pipeline.fused_serving)
+    drains = m.registry.get_sample_value("guber_tpu_windows_total")
+    assert drains and drains > 0
+    assert b.pipeline.fused_serving

@@ -11,7 +11,9 @@ import time
 
 import pytest
 
-from gubernator_tpu.observability.metrics import STAGES, Metrics
+from gubernator_tpu.observability.metrics import (PUMP_HOLD_REASONS,
+                                                  REQUEST_STAGES, STAGES,
+                                                  Metrics)
 
 pytestmark = pytest.mark.obs
 
@@ -39,12 +41,12 @@ NATIVE_NAMES = (
     "guber_slo_firing",
     # overlapped drain pipeline (core/pipeline.py, core/window_buffers.py)
     "guber_tpu_pipeline_inflight_windows",
-    "guber_tpu_pipeline_overlap_ratio",
-    "guber_tpu_window_buffer_reuse_total",
+    # one request's stages and the pump's holds (core/pipeline.py)
+    "guber_tpu_request_stage_seconds_total",
+    "guber_tpu_request_stage_requests_total",
+    "guber_tpu_pump_hold_seconds_total",
     # deferred-fetch dispatch chain (core/pipeline.py)
     "guber_tpu_chain_fetch_stride",
-    "guber_tpu_chain_inflight_windows",
-    "guber_tpu_chain_fetch_elided_total",
     # multi-process front door (frontdoor.py, core/shm_ring.py)
     "guber_tpu_frontdoor_workers",
     "guber_tpu_frontdoor_rpcs",
@@ -65,9 +67,7 @@ NATIVE_NAMES = (
     "guber_tpu_tier_warm_bytes",
     # device-time flight recorder (observability/devprof.py)
     "guber_tpu_device_window_ms",
-    "guber_tpu_device_window_ewma_ms",
     "guber_tpu_devprof_captures",
-    "guber_tpu_frontdoor_trace_drops",
     # kernel-ladder scoreboard (daemon boot, staged drain)
     "guber_tpu_kernels_per_window",
     # algorithm plane + concurrency-lease book (algorithms/leases.py)
@@ -166,13 +166,52 @@ def test_no_orphaned_collectors():
 
 def test_stage_labels_are_canonical():
     """Every stage histogram child uses a label from STAGES — dashboards
-    key on exactly these seven."""
+    key on exactly these, in pipeline order."""
     m = Metrics()
     for stage in STAGES:
         m.observe_stage(stage, 0.001)
     for stage in STAGES:
         assert m.registry.get_sample_value(
             "guber_tpu_stage_duration_ms_count", {"stage": stage}) == 1.0
-    assert set(STAGES) == {
-        "enqueue", "admission_wait", "window_fill", "device_dispatch",
-        "drain_commit", "peer_forward", "global_broadcast"}
+    assert STAGES == (
+        "enqueue", "admission_wait", "engine_queue", "window_fill",
+        "device_dispatch", "dispatch_hop", "fetch_queue", "drain_commit",
+        "device_wait", "decode", "complete_hop", "commit", "peer_forward",
+        "global_broadcast")
+
+
+# series that were removed because nothing read them; their numbers are in
+# /v1/admin/debug (PERF.md section 3 names the field for each)
+REMOVED_NAMES = (
+    "guber_tpu_pipeline_overlap_ratio",
+    "guber_tpu_fused_drains_total",
+    "guber_tpu_drain_depth_windows",
+    "guber_tpu_chain_fetch_elided_total",
+    "guber_tpu_chain_inflight_windows",
+    "guber_tpu_window_buffer_reuse_total",
+    "guber_tpu_device_window_ewma_ms",
+    "guber_tpu_frontdoor_trace_drops_total",
+)
+
+
+@pytest.mark.parametrize("name", REMOVED_NAMES)
+def test_removed_series_stay_removed(name):
+    text = Metrics().expose().decode("utf-8")
+    assert f"# TYPE {name}" not in text
+
+
+@pytest.mark.parametrize("series,label,values", [
+    ("guber_tpu_stage_duration_ms_count", "stage", STAGES),
+    ("guber_tpu_stage_duration_ms_sum", "stage", STAGES),
+    ("guber_tpu_request_stage_seconds_total", "stage", REQUEST_STAGES),
+    ("guber_tpu_request_stage_requests_total", "stage", REQUEST_STAGES),
+    ("guber_tpu_pump_hold_seconds_total", "reason", PUMP_HOLD_REASONS),
+])
+def test_labelled_children_exist_at_zero(series, label, values):
+    """A child that was never incremented is absent from /metrics, and a
+    reader cannot tell absent from zero: a fresh Metrics exposes every
+    stage and reason at 0."""
+    m = Metrics()
+    m.expose()
+    for v in values:
+        assert m.registry.get_sample_value(series, {label: v}) == 0.0, v

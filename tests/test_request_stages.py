@@ -1,0 +1,457 @@
+"""One timeline for a request and a drain (PR 28): the drain's boundary
+stamps, the per-request stage counters, the pump's hold reasons, the
+capture thread, the guber_* annotations in a profiler trace, and the layer
+metrics that read them."""
+
+import asyncio
+import glob
+import json
+import os
+import threading
+import time
+
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+import gubernator_tpu  # noqa: F401
+from benchmark import harness, reduce_trace
+from gubernator_tpu import native
+from gubernator_tpu.api.http_gateway import build_app
+from gubernator_tpu.api.types import RateLimitReq
+from gubernator_tpu.client import AsyncClient
+from gubernator_tpu.config import BehaviorConfig, Config, EngineConfig
+from gubernator_tpu.core import pipeline as pipeline_mod
+from gubernator_tpu.core.batcher import WindowBatcher
+from gubernator_tpu.core.engine import RateLimitEngine
+from gubernator_tpu.core.service import Instance
+from gubernator_tpu.observability.metrics import (PUMP_HOLD_REASONS,
+                                                  REQUEST_STAGES, Metrics)
+from gubernator_tpu.server import GrpcServer
+from tests.benchmark import xplane_writer
+
+pytestmark = pytest.mark.obs
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+METHOD = {"method": "/pb.gubernator.V1/GetRateLimits"}
+NEW_LAYER_METRICS = ("queue_wait_ms", "loop_hop_ms", "pump_empty_pct",
+                     "pump_gated_pct", "decode_ms", "reply_wake_ms",
+                     "server_rpc_ms", "outside_server_ms")
+
+
+def reqs(prefix, n=8):
+    return [RateLimitReq(name="rs", unique_key=f"{prefix}{i}", hits=1,
+                         limit=1000, duration=60_000) for i in range(n)]
+
+
+def make_batcher(metrics=None, **pipe):
+    if not native.available():
+        pytest.skip("native router unavailable")
+    eng = RateLimitEngine(capacity_per_shard=256, batch_per_shard=64,
+                          global_capacity=16, global_batch_per_shard=8,
+                          max_global_updates=8, use_native="on")
+    b = WindowBatcher(eng, BehaviorConfig(), metrics=metrics)
+    p = b.pipeline
+    assert p is not None and p.enabled
+    p.gate_enabled = False
+    p.coalesce_wait = 0.0
+    for k, v in pipe.items():
+        setattr(p, k, v)
+    return b, p
+
+
+# ------------------------------------------------------- (a) a drain's stamps
+
+
+class TickingClock:
+    """time.monotonic() that moves 1 ms on every reading: no two stamps are
+    equal, whatever thread takes them."""
+
+    def __init__(self):
+        self._t, self._lock = 1000.0, threading.Lock()
+        self.sleep = time.sleep
+
+    def monotonic(self):
+        with self._lock:
+            self._t += 0.001
+            return self._t
+
+
+def test_drain_stamps_are_monotone_and_stages_sum(monkeypatch):
+    monkeypatch.setattr(pipeline_mod, "time", TickingClock())
+    m = Metrics()
+    b, p = make_batcher(m)
+    seen = []
+    commit = p._commit
+
+    def spy(res, outs):
+        commit(res, outs)
+        seen.append(res)
+    p._commit = spy
+    try:
+        got = asyncio.run(b.submit_now(reqs("a")))
+    finally:
+        b.close()
+    assert len(got) == 8
+    (res,) = seen
+    serial = [res.pumped, res.started, res.pack_done, res.dispatch_done,
+              res.fetch_start, res.fetch_ready, res.fetch_done,
+              res.completed_cb, res.committed]
+    assert all(a < b_ for a, b_ in zip(serial, serial[1:])), serial
+    # the loop's callback after the dispatch runs beside the fetch
+    assert res.dispatch_done < res.dispatched_cb < res.committed
+
+    def ms(stage):
+        assert m.registry.get_sample_value(
+            "guber_tpu_stage_duration_ms_count", {"stage": stage}) == 1.0
+        return m.registry.get_sample_value(
+            "guber_tpu_stage_duration_ms_sum", {"stage": stage})
+    chain = ("engine_queue", "window_fill", "device_dispatch", "fetch_queue",
+             "device_wait", "decode", "complete_hop", "commit")
+    assert sum(ms(s) for s in chain) == pytest.approx(
+        (res.committed - res.pumped) * 1000.0, abs=1e-6)
+    assert ms("drain_commit") == pytest.approx(
+        ms("device_wait") + ms("decode"), abs=1e-6)
+    assert ms("dispatch_hop") == pytest.approx(
+        (res.dispatched_cb - res.dispatch_done) * 1000.0, abs=1e-6)
+
+
+# ---------------------------------------------- (b), (f) a served burst, idle
+
+
+@pytest.fixture(scope="module")
+def node():
+    """A standalone Instance behind its real gRPC server and HTTP gateway,
+    on a loop of its own."""
+    loop = asyncio.new_event_loop()
+    inst = Instance(Config(engine=EngineConfig(
+        capacity_per_shard=2048, batch_per_shard=256, global_capacity=64,
+        global_batch_per_shard=16, max_global_updates=16)))
+    inst.engine.warmup()
+
+    async def up():
+        server = GrpcServer(inst, "127.0.0.1:0")
+        await server.start()
+        http = TestClient(TestServer(build_app(inst)))
+        await http.start_server()
+        return server, http
+    server, http = loop.run_until_complete(up())
+    yield loop, inst, server, http
+
+    async def down():
+        await http.close()
+        await server.stop(0.2)
+    loop.run_until_complete(down())
+    inst.close()
+    loop.close()
+
+
+async def snapshot(http):
+    r = await http.get("/metrics")
+    prom = harness.parse_prom(await r.text())
+    r = await http.get("/v1/admin/debug")
+    return {"prom": prom, "debug": await r.json()}
+
+
+def terms(expr):
+    """The readings a layer-metric expression names."""
+    if not isinstance(expr, dict):
+        return
+    if "op" in expr:
+        for a in expr["args"]:
+            yield from terms(a)
+    else:
+        yield expr
+
+
+async def serve_burst(server, small=30, big=4):
+    client = AsyncClient(server.address)
+    try:
+        calls = [client.get_rate_limits(reqs(f"s{i}_", 1))
+                 for i in range(small)]
+        # 100 items are over server.FASTPATH_MIN_BYTES: the native RPC lane
+        calls += [client.get_rate_limits(reqs(f"b{i}_", 100))
+                  for i in range(big)]
+        for rs in await asyncio.gather(*calls):
+            assert all(r.error == "" for r in rs)
+    finally:
+        await client.close()
+    return small + big
+
+
+async def settle(inst):
+    pipe = inst.batcher.pipeline
+    for _ in range(500):
+        if pipe._in_flight == 0 and not pipe._jobs and not pipe._singles:
+            break
+        await asyncio.sleep(0.01)
+    assert pipe._in_flight == 0
+    pipe.flush_reply_wake()
+
+
+def test_idle_daemon_has_every_reading_then_every_metric_a_number(node):
+    """(f) The zero children: every prom / debug reading the new files name
+    is there before any traffic; over a served burst every file then
+    evaluates to a number."""
+    loop, inst, server, http = node
+
+    async def body():
+        bench = harness.Bench(REPO)
+        specs = {n: bench.layer_file(n) for n in NEW_LAYER_METRICS}
+        before = await snapshot(http)
+        for name, spec in specs.items():
+            for t in terms(spec["read"]):
+                if "prom" in t:
+                    key = (t["prom"], tuple(sorted(t.get("labels", {}).items())))
+                    if key[0].startswith("grpc_request_duration"):
+                        continue  # a child per method, made by its first RPC
+                    assert before["prom"].get(key) == 0.0, (name, key)
+                if "debug" in t:
+                    assert harness._dig(before["debug"], t["debug"]) is not None
+        assert set(before["debug"]["pipeline"]["pump_hold_seconds"]) == set(
+            PUMP_HOLD_REASONS)
+        assert isinstance(before["debug"]["device"]["memory"], dict)
+        t0 = time.time()
+        await serve_burst(server)
+        await settle(inst)
+        after = await snapshot(http)
+        ctx = {"before": before, "after": after,
+               "client": {"seconds": time.time() - t0, "rpc_mean_ms": 1e3}}
+        for name, spec in specs.items():
+            v = harness.evaluate(spec["read"], ctx)
+            assert isinstance(v, float) and v >= 0.0, (name, v)
+    loop.run_until_complete(body())
+
+
+def test_request_stages_sum_under_the_servers_time_and_count_every_rpc(node):
+    """(b) Σ(queue_wait + in_drain + reply_wake) ≤ Σ server duration over
+    the same RPCs, and each stage counted every RPC once."""
+    loop, inst, server, http = node
+    g = inst.metrics.registry.get_sample_value
+
+    def read():
+        sec = {s: g("guber_tpu_request_stage_seconds_total", {"stage": s})
+               for s in REQUEST_STAGES}
+        n = {s: g("guber_tpu_request_stage_requests_total", {"stage": s})
+             for s in REQUEST_STAGES}
+        return (sec, n,
+                g("grpc_request_duration_milliseconds_sum", METHOD) or 0.0,
+                g("grpc_request_duration_milliseconds_count", METHOD) or 0.0)
+
+    async def body():
+        await settle(inst)
+        sec0, n0, ms0, c0 = read()
+        served = await serve_burst(server)
+        await settle(inst)
+        sec1, n1, ms1, c1 = read()
+        assert c1 - c0 == served
+        for s in REQUEST_STAGES:
+            assert n1[s] - n0[s] == served, s
+            assert sec1[s] - sec0[s] > 0.0, s
+        parts_ms = sum(sec1[s] - sec0[s] for s in REQUEST_STAGES) * 1000.0
+        assert parts_ms <= ms1 - ms0
+        # and they are most of it: the handler's own share is parse and
+        # the batcher's wait, not a multiple of the drain
+        assert parts_ms >= 0.2 * (ms1 - ms0)
+    loop.run_until_complete(body())
+
+
+def test_debug_snapshot_and_cli_show_memory_and_holds(node, capsys,
+                                                      monkeypatch):
+    loop, inst, server, http = node
+    from gubernator_tpu.cmd import cli
+    from gubernator_tpu.observability import introspect
+
+    class Dev:
+        def memory_stats(self):
+            return {"bytes_in_use": 5_000_000, "peak_bytes_in_use": 7_000_000,
+                    "bytes_limit": 16_000_000_000, "num_allocs": 3}
+
+    class Bare:
+        def memory_stats(self):
+            return None
+    assert introspect.device_memory([Bare(), Dev()]) == {
+        "bytes_in_use": 5_000_000, "peak_bytes_in_use": 7_000_000,
+        "bytes_limit": 16_000_000_000}
+    assert introspect.device_memory([Bare()]) == {}
+    snap = loop.run_until_complete(snapshot(http))["debug"]
+    snap["device"]["memory"] = introspect.device_memory([Dev()])
+    monkeypatch.setattr(cli, "_fetch_debug", lambda *a, **k: snap)
+
+    class Args:
+        address, timeout, json = "x", 1.0, False
+    assert cli.cmd_debug(Args()) == 0
+    out = capsys.readouterr().out
+    assert "device memory: bytes_in_use=5.0MB peak_bytes_in_use=7.0MB" in out
+    assert "pump held (s): empty=" in out
+
+
+# ------------------------------------------------------ (c) the pump's holds
+
+
+@pytest.mark.parametrize("reason", PUMP_HOLD_REASONS)
+def test_pump_hold_adds_to_its_reason_and_to_no_other(reason):
+    m = Metrics()
+    b, p = make_batcher(m, depth=1 if reason == "depth" else 3)
+    if reason == "gate":
+        p.gate_enabled, p.gate_frac = True, 1.0
+    if reason == "coalesce":
+        p.coalesce_wait = 0.05
+    gate = threading.Event()
+
+    async def body():
+        p._loop = asyncio.get_running_loop()
+        if reason == "empty":
+            p._pump()                       # room, and nothing queued
+            assert p._hold_reason == "empty"
+            await asyncio.sleep(0.03)
+            await b.submit_now(reqs("e"))   # the dispatch ends the hold
+            return
+        if reason == "coalesce":
+            # one queued request, room for a drain: the batch-wait timer
+            await asyncio.gather(*[p.submit_one(r) for r in reqs("c", 2)])
+            return
+        # depth and gate: a drain in flight (the engine thread is held),
+        # and work queued behind it
+        p._engine_executor.submit(gate.wait, 5.0)
+        first = asyncio.ensure_future(b.submit_now(reqs("f")))
+        await asyncio.sleep(0.01)
+        assert p._in_flight == 1
+        second = asyncio.ensure_future(b.submit_now(reqs("g")))
+        await asyncio.sleep(0)
+        assert p._hold_reason == reason
+        empty0 = p.pump_hold_snapshot()["empty"]
+        await asyncio.sleep(0.03)
+        # work is queued all this while: `empty` does not run
+        assert p.pump_hold_snapshot()["empty"] == empty0
+        gate.set()
+        await asyncio.gather(first, second)
+
+    try:
+        asyncio.run(body())
+    finally:
+        gate.set()
+        b.close()
+    held = dict(p.pump_hold)   # the holds that have ended
+    assert held[reason] >= 0.02, held
+    for other in PUMP_HOLD_REASONS:
+        if other != reason:
+            assert held[other] == 0.0, held
+        assert m.registry.get_sample_value(
+            "guber_tpu_pump_hold_seconds_total",
+            {"reason": other}) == pytest.approx(held[other])
+
+
+# ------------------------------------------------- (d) the capture's thread
+
+
+def test_profiler_stop_does_not_stall_the_engine_thread(monkeypatch):
+    import jax
+    threads = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d: threads.append(("start", threading.current_thread().name)))
+
+    def slow_stop():
+        threads.append(("stop", threading.current_thread().name))
+        time.sleep(1.0)
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    b, p = make_batcher(Metrics())
+    prof = b.profile
+
+    async def body():
+        await b.submit_now(reqs("w"))        # warm: compiled, arena made
+        assert prof.arm(1, "/tmp/guber-test-cap")["armed"]
+        for _ in range(200):
+            if prof.tracing:
+                break
+            await asyncio.sleep(0.005)
+        assert prof.tracing
+        await b.submit_now(reqs("x"))        # the armed drain: stop begins
+        t_stop = time.monotonic()
+        slowest = 0.0
+        while time.monotonic() - t_stop < 0.8:
+            assert prof.status()["active"] is True
+            t = time.monotonic()
+            await b.submit_now(reqs("y"))
+            slowest = max(slowest, time.monotonic() - t)
+        assert slowest < 0.2, slowest
+        for _ in range(300):
+            if not prof.status()["active"]:
+                break
+            await asyncio.sleep(0.01)
+        assert prof.status() == {"active": False, "remaining": 0,
+                                 "dir": "/tmp/guber-test-cap"}
+        assert time.monotonic() - t_stop >= 1.0   # active until stop returned
+    try:
+        asyncio.run(body())
+    finally:
+        b.close()
+    assert [w for w, _ in threads] == ["start", "stop"]
+    assert all(name == "guber-profile" for _, name in threads), threads
+
+
+def test_engine_thread_code_never_calls_the_profiler():
+    """The grep of the acceptance criteria, kept as a test: the serving
+    path's start_trace and stop_trace are in observability/introspect.py
+    alone (devprof.measure_census_arms is the admin plane's offline probe:
+    its own executables on a thread of the default executor)."""
+    hits = []
+    for path in glob.glob(os.path.join(REPO, "gubernator_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        if "profiler.start_trace(" in text or "profiler.stop_trace(" in text:
+            hits.append(os.path.relpath(path, REPO))
+    assert sorted(hits) == ["gubernator_tpu/observability/devprof.py",
+                            "gubernator_tpu/observability/introspect.py"]
+
+
+# ------------------------------------- (e) the annotations in a real capture
+
+
+def test_cpu_capture_holds_the_host_stages_and_the_reducer_names_them(
+        tmp_path):
+    b, p = make_batcher(Metrics())
+    prof = b.profile
+    cap = str(tmp_path / "cap")
+
+    async def body():
+        await b.submit_now(reqs("w"))
+        assert prof.arm(3, cap)["armed"]
+        i = 0
+        while prof.status()["active"] and i < 2000:
+            await b.submit_now(reqs(f"c{i}_"))
+            i += 1
+            await asyncio.sleep(0.002)
+        assert not prof.status()["active"]
+    try:
+        asyncio.run(body())
+    finally:
+        b.close()
+    planes = []
+    for path in reduce_trace.find_traces(cap):
+        planes += reduce_trace.read_planes(path)
+    host = {}
+    for _, lines in planes:
+        for _, events in lines:
+            for name, start, dur in events:
+                if name.startswith("guber_") and dur > 0:
+                    host.setdefault(name, []).append((start, dur))
+    # three drains were dispatched under the capture; the stop begins at
+    # the last one's dispatch, so its fetch and commit may fall outside
+    for name, least in (("guber_pack", 3), ("guber_drain", 3),
+                        ("guber_fetch", 2), ("guber_decode", 2),
+                        ("guber_commit", 2)):
+        assert len(host.get(name, ())) >= least, (name, sorted(host))
+    # A CPU trace has no device plane.  Lay one beside the capture whose
+    # only idle gap is the first pack: the reducer names it.
+    start, dur = min(host["guber_pack"])
+    xplane_writer.write(os.path.join(cap, "device.xplane.pb"), [
+        ("/device:TPU:0", [("XLA Modules", [
+            ("jit_drain", start - 1_000_000, 1_000_000),
+            ("jit_drain", start + dur, 1_000_000)])])])
+    got = reduce_trace.reduce_dir(cap)
+    gaps = dict(got["idle_gaps"])
+    assert gaps["guber_pack"] == pytest.approx(dur / 1e9, rel=1e-3)
+    assert "unattributed" not in gaps
+    json.dumps(got)

@@ -465,9 +465,19 @@ def test_profile_endpoint_arms_capture(admin, loop, monkeypatch):
         payload = {"requests": [{"name": "prof", "uniqueKey": "k1",
                                  "hits": "1", "limit": "10",
                                  "duration": "60000"}]}
+        # the capture's own thread starts the profiler; drains count only
+        # once it has
+        for _ in range(200):
+            if inst.batcher.profile.tracing:
+                break
+            await asyncio.sleep(0.01)
+        assert ("start", "/tmp/cap") in calls
         r = await client.post("/v1/GetRateLimits", json=payload)
         assert r.status == 200
-        assert ("start", "/tmp/cap") in calls
+        for _ in range(200):
+            if not inst.batcher.profile.status()["active"]:
+                break
+            await asyncio.sleep(0.01)
         assert ("stop", None) in calls
         assert inst.batcher.profile.status()["active"] is False
         # invalid drains rejected
