@@ -1170,7 +1170,8 @@ class RateLimitEngine:
                k_stack: Optional[int] = None) -> None:
         """Compile and execute one empty window per serving executable —
         every lane bucket of both wire formats, plus the pipeline's
-        stacked-window buckets — so serving never pays a jit stall (a
+        stacked-window buckets and its single-window drain at each
+        narrower lane bucket — so serving never pays a jit stall (a
         cluster's 500ms peer deadline does not survive a mid-serving
         compile).  Mesh mode: pass the cluster-agreed timestamp (every
         process must warm up in lockstep), and the tick's lockstep_stack as
@@ -1219,9 +1220,13 @@ class RateLimitEngine:
                 self._buf.reset(self.global_capacity)
                 self._dispatch(now, reg_fill=lanes)
         if self.native is not None and not self.multiprocess:
-            for kb in PIPELINE_K_BUCKETS:
-                packed = np.zeros(
-                    (kb, self.num_shards, self.batch_per_shard, 2), np.int64)
+            # every K bucket at full width, then the single-window drain
+            # at each narrower lane bucket (core/pipeline.py _drain_lanes)
+            B = self.batch_per_shard
+            shapes = [(kb, B) for kb in PIPELINE_K_BUCKETS]
+            shapes += [(1, b) for b in self._lane_bucket_list if b < B]
+            for kb, lanes in shapes:
+                packed = np.zeros((kb, self.num_shards, lanes, 2), np.int64)
                 _, _, mism = self.pipeline_dispatch(
                     packed, np.full(kb, now, np.int64), n_windows=0)
             jax.device_get(mism)
@@ -1391,6 +1396,12 @@ class RateLimitEngine:
         moves 32x more bytes than it has lanes).  Buckets are powers-of-4
         steps of B so at most 3 executables exist per step family.
 
+        The buckets serve the legacy step (_dispatch) and the pipeline's
+        single-window drain (core/pipeline.py _drain_lanes): the drain
+        executable's device time is set by its lane count, not by how
+        many lanes hold a request, so a 20-decision drain runs the
+        B/16 shape.
+
         Mesh mode always uses the full width: the bucket choice is
         per-host data-dependent, and hosts picking different executables
         for the same lockstep tick would wedge the collectives."""
@@ -1509,7 +1520,8 @@ class RateLimitEngine:
         step path, serialized on the same executor thread).
 
         packed: i64[K, S_local, B, 2] compact request stack (numpy or
-        resident); nows: i64[K] per-window timestamps.  Returns un-fetched
+        resident), B any warmed lane bucket (jit keys the executable on
+        the shape); nows: i64[K] per-window timestamps.  Returns un-fetched
         device arrays (words i64[K, S, B], limits i64[K, S, B], mism
         bool[K, S]; fetch the local blocks with _fetch_local_stacked):
         the caller overlaps their fetch with the next drain's dispatch and

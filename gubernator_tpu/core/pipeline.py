@@ -440,7 +440,7 @@ class _DrainResult:
                  "fetch_start", "fetch_ready", "fetch_done", "completed_cb",
                  "committed",
                  "oldest_enq", "arena", "cols_owner", "cfut", "deferred",
-                 "arm", "chain_fetch_start", "chain_fetch_done")
+                 "arm", "lanes", "chain_fetch_start", "chain_fetch_done")
 
     def __init__(self):
         self.words = None
@@ -511,6 +511,8 @@ class _DrainResult:
         # committed through a deferred-fetch chain (satellite span +
         # chain_fetch stage; 0.0 = not chained)
         self.arm = ""
+        # lane width the drain's executable ran at (0 = nothing dispatched)
+        self.lanes = 0
         self.chain_fetch_start = 0.0
         self.chain_fetch_done = 0.0
 
@@ -647,6 +649,8 @@ class DispatchPipeline:
         self._hold_reason: Optional[str] = None
         self._hold_since = 0.0
         self.pump_hold = dict.fromkeys(PUMP_HOLD_REASONS, 0.0)
+        # drains dispatched per lane width (engine thread; see _drain_lanes)
+        self.drain_widths = dict.fromkeys(engine._lane_bucket_list, 0)
         # reply_wake of the requests that have resumed since the last
         # commit (plain floats; the next commit flushes them)
         self._wake_seconds = 0.0
@@ -1227,11 +1231,7 @@ class DispatchPipeline:
             else:
                 words = np.ascontiguousarray(next(fetched))
                 mism = next(fetched)
-                clflat = None
-                if mism.any():
-                    clflat = np.ascontiguousarray(
-                        eng._fetch_local_stacked(res.limits)).reshape(-1, B)
-                wflat = words.reshape(-1, B)
+                wflat, clflat = self._flat_outputs(res, words, mism)
             if res.stats is not None:
                 # same contract as _complete_sync: analytics must never
                 # fail a drain, so its fetch stays separately guarded
@@ -1955,6 +1955,7 @@ class DispatchPipeline:
             # lowers to (scripts/probe_census.py's arm names)
             res.arm = ("composed_analytics" if an_args is not None
                        else "composed_drain")
+            res.lanes = B
             before = eng.windows_processed
             dispatched = False
             try:
@@ -2022,6 +2023,11 @@ class DispatchPipeline:
             res.arm = ("fused_window" if self.fused_serving
                        else "compact32_xla")
             kb = next(b for b in self._k_buckets if b >= k_used)
+            res.lanes = lanes = self._drain_lanes(fills, k_used)
+            # the C router stages a shard's lanes as a prefix, so the
+            # narrow copy keeps every (row, lane) job.finish will read
+            stack = (packed[:kb] if lanes == B
+                     else np.ascontiguousarray(packed[:1, :, :lanes]))
             try:
                 # fault seam: an injected dispatch failure aborts the C
                 # router's staged allocations (no partial commit) and fails
@@ -2030,8 +2036,7 @@ class DispatchPipeline:
                 if FAULTS.enabled:
                     FAULTS.on_sync(SEAM_ENGINE_DISPATCH, "pipeline")
                 words, limits, mism = eng.pipeline_dispatch(
-                    packed[:kb], np.full(kb, now, np.int64),
-                    n_windows=k_used)
+                    stack, np.full(kb, now, np.int64), n_windows=k_used)
                 native.commit()
             except Exception as e:
                 native.abort()
@@ -2054,6 +2059,11 @@ class DispatchPipeline:
             import jax
             jax.block_until_ready(res.words)
         res.dispatch_done = time.monotonic()
+        if res.lanes:
+            self.drain_widths[res.lanes] += 1
+            if self.metrics is not None:
+                self.metrics.drains.labels(
+                    width="narrow" if res.lanes < B else "full").inc()
         # forwarded items are the OWNER's decisions, not ours — counting
         # them here would double-count cluster-wide (the owner's peer-lane
         # drain counts them)
@@ -2086,6 +2096,22 @@ class DispatchPipeline:
                                   for j in res.staged):
             res.cfut = self._fetch_executor.submit(self._complete_sync, res)
         return res
+
+    def _drain_lanes(self, fills, k_used: int) -> int:
+        """Lane width of this drain's executable: the narrowest lane
+        bucket (engine._lane_bucket) that holds the fullest shard.  The
+        window's device time follows its lane count, whatever the lanes
+        hold, so a drain of twenty decisions runs the B/16 shape.  A
+        drain that stacks windows has overflowed B or met the replay
+        bound and stays full.  So does one the standalone analytics
+        reduction follows (its executable is keyed on the same shapes
+        and warms at none).  The lockstep tick dispatches its own
+        composed executable and a mesh engine's _lane_bucket answers B:
+        one fixed executable per tick keeps the collective sequence
+        aligned across hosts."""
+        if k_used != 1 or self.lockstep or self.analytics is not None:
+            return self.engine.batch_per_shard
+        return self.engine._lane_bucket(int(fills.max()))
 
     def _analytics_stage(self, res: _DrainResult, packed, kd: int,
                          now: int):
@@ -2182,6 +2208,19 @@ class DispatchPipeline:
 
     # ------------------------------------------------------------ fetch side
 
+    def _flat_outputs(self, res: _DrainResult, words, mism) -> tuple:
+        """A fetched drain's words as rows of k * S_local + shard, at the
+        lane width the drain ran (a chain may hold drains of different
+        widths), and its limits plane the same way, fetched only when a
+        stored-limit mismatch fired."""
+        lanes = words.shape[-1]
+        clflat = None
+        if mism.any():
+            clflat = np.ascontiguousarray(
+                self.engine._fetch_local_stacked(res.limits)
+            ).reshape(-1, lanes)
+        return words.reshape(-1, lanes), clflat
+
     def _complete_sync(self, res: _DrainResult):
         res.fetch_start = time.monotonic()
         eng = self.engine
@@ -2200,12 +2239,8 @@ class DispatchPipeline:
             # moves it.  Rows index as k * S_local + shard, exactly how
             # the C router staged them.
             words, mism = eng.fetch_stacked_many([res.words, res.mism])
-            words = np.ascontiguousarray(words)
-            clflat = None
-            if mism.any():
-                clflat = np.ascontiguousarray(
-                    eng._fetch_local_stacked(res.limits)).reshape(-1, B)
-            wflat = words.reshape(-1, B)
+            wflat, clflat = self._flat_outputs(
+                res, np.ascontiguousarray(words), mism)
         gflat = None
         if res.gfused is not None:
             # this process's GLOBAL response rows [S_local, Bg, 4], indexed

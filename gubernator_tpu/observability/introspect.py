@@ -226,6 +226,7 @@ def build_debug_snapshot(instance) -> dict:
             "depth": pipe.depth,
             "overlap": pipe.overlap_snapshot(),
             "pump_hold_seconds": pipe.pump_hold_snapshot(),
+            "drain_widths": dict(pipe.drain_widths),
         }
     analytics = getattr(instance, "analytics", None)
     if analytics is not None:

@@ -60,6 +60,12 @@ REQUEST_STAGES = ("queue_wait", "in_drain", "reply_wake")
 # timer, `depth` = work queued behind a full pipeline.
 PUMP_HOLD_REASONS = ("empty", "gate", "coalesce", "depth")
 
+# Lane width of a dispatched drain's executable (guber_tpu_drains_total):
+# `full` = batch_per_shard, `narrow` = any smaller lane bucket.  Two fixed
+# values, so a reader can name them whatever the engine's B is; the count
+# per width is `pipeline.drain_widths` in /v1/admin/debug.
+DRAIN_WIDTHS = ("narrow", "full")
+
 
 class _StageRing:
     """Fixed-size ring of recent stage durations (seconds) behind one
@@ -402,6 +408,13 @@ class Metrics:
             ["reason"],
             registry=self.registry,
         )
+        self.drains = Counter(
+            "guber_tpu_drains_total",
+            "Drains dispatched, by the lane width of their executable "
+            "(narrow = a lane bucket below batch_per_shard | full).",
+            ["width"],
+            registry=self.registry,
+        )
         # a labelled child that was never touched is absent from /metrics,
         # and a reader cannot tell absent from zero: make every child now
         for stage in STAGES:
@@ -411,6 +424,8 @@ class Metrics:
             self.request_stage_requests.labels(stage=stage)
         for reason in PUMP_HOLD_REASONS:
             self.pump_hold_seconds.labels(reason=reason)
+        for width in DRAIN_WIDTHS:
+            self.drains.labels(width=width)
         # traffic analytics (ops/analytics.py device reduction +
         # observability/analytics.py host merge): hot keys, per-tenant
         # accounting, device-computed arena occupancy/churn

@@ -11,7 +11,8 @@ import time
 
 import pytest
 
-from gubernator_tpu.observability.metrics import (PUMP_HOLD_REASONS,
+from gubernator_tpu.observability.metrics import (DRAIN_WIDTHS,
+                                                  PUMP_HOLD_REASONS,
                                                   REQUEST_STAGES, STAGES,
                                                   Metrics)
 
@@ -45,6 +46,8 @@ NATIVE_NAMES = (
     "guber_tpu_request_stage_seconds_total",
     "guber_tpu_request_stage_requests_total",
     "guber_tpu_pump_hold_seconds_total",
+    # lane-bucketed serving drain (core/pipeline.py _drain_lanes)
+    "guber_tpu_drains_total",
     # deferred-fetch dispatch chain (core/pipeline.py)
     "guber_tpu_chain_fetch_stride",
     # multi-process front door (frontdoor.py, core/shm_ring.py)
@@ -206,6 +209,7 @@ def test_removed_series_stay_removed(name):
     ("guber_tpu_request_stage_seconds_total", "stage", REQUEST_STAGES),
     ("guber_tpu_request_stage_requests_total", "stage", REQUEST_STAGES),
     ("guber_tpu_pump_hold_seconds_total", "reason", PUMP_HOLD_REASONS),
+    ("guber_tpu_drains_total", "width", DRAIN_WIDTHS),
 ])
 def test_labelled_children_exist_at_zero(series, label, values):
     """A child that was never incremented is absent from /metrics, and a

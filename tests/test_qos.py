@@ -601,9 +601,11 @@ def test_http_gateway_shed_metadata_end_to_end():
             assert md["shed"] == "true"
             assert data["responses"][0]["status"] == "OVER_LIMIT"
             inst.qos.admission.pending = 0
-            # deadline header: 1ms cannot cover a drain cycle estimate
+            # deadline header: 10us cannot cover a drain cycle estimate
+            # (the estimate is what the healthy call above measured: on
+            # the CPU a 64-lane drain can come in under a millisecond)
             r = await client.post("/v1/GetRateLimits", json=payload,
-                                  headers={"X-Guber-Timeout-Ms": "1"})
+                                  headers={"X-Guber-Timeout-Ms": "0.01"})
             data = await r.json()
             assert (data["responses"][0]["metadata"]["shed_reason"]
                     == "deadline")

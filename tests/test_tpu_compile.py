@@ -149,11 +149,16 @@ def _fits(compiled, budget_bytes: int = 16 * 1024 ** 3) -> None:
 
 
 @pytest.mark.parametrize("C,B,K", [(SMOKE_C, SMOKE_B, SMOKE_K),
-                                   (DEF_C, DEF_B, SMOKE_K)],
-                         ids=["smoke-10M", "daemon-default"])
+                                   (DEF_C, DEF_B, SMOKE_K),
+                                   (SMOKE_C, SMOKE_B // 16, 1),
+                                   (SMOKE_C, SMOKE_B // 4, 1)],
+                         ids=["smoke-10M", "daemon-default",
+                              "smoke-10M-1024-lanes", "smoke-10M-4096-lanes"])
 def test_default_drain_compiles(one_chip, C, B, K):
     """The serving drain every default deployment runs: K compact windows,
-    compact32-XLA body (GUBER_* lowering flags all at their defaults)."""
+    compact32-XLA body (GUBER_* lowering flags all at their defaults); and
+    the single-window drain at the two narrower lane buckets, which the
+    benchmark's edge cells run."""
     s = _Shapes(one_chip, C, B, K)
     fn = engine_mod._compiled_pipeline_step_impl(
         one_chip, False, True, False, True)
