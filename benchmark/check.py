@@ -20,6 +20,46 @@ A key with no witness is a mismatch (`correct` false).  A key whose search ran
 out of its budget, or whose witness the replay does not confirm (the search
 treats the expiry clock loosely), is undecided: reported, and held to a share
 of its own.  All requests carry hits = 1.
+
+Keys of the keyspace's `global` family (ranks above `population`) are held to
+another guarantee, Behavior GLOBAL on a mesh: stale, then consistent.  Every
+answer of one window is the read of the row as it stood before the window,
+and the window's summed hits land once after it (`global_window` of the
+configuration's reference).  The witness for such a key is a split of its
+requests into consecutive windows with non-decreasing timestamps, each inside
+every member's [sent - TOL_MS, received + TOL_MS], under which that rule,
+replayed window by window, gives exactly the served answers.  It is a
+different guarantee, not a looser one: the answers of an exactly serial
+server (each shows its own hit) have no witness under it either.
+
+  token bucket   the answers name their window.  All answers of one window
+                 are equal, a bucket's reset time says which bucket, and
+                 within a bucket `remaining` only falls, so the windows come
+                 in the order (reset, -remaining).  A level (one bucket, one
+                 `remaining` R) of n answers followed by the level R' holds
+                 one window of exactly R - R' requests, the one whose hits
+                 landed: the psum lost no hit and counted none twice.  More
+                 answers than that can only be windows that asked for more
+                 than was left (more than R requests each), whose hits the
+                 rule refuses whole.  So R - R' < n <= 2R - R' has no witness
+                 by counting, n = R - R' needs one timestamp common to all n
+                 spans, and only a level with refused windows is searched: a
+                 partition of its requests, by receive time, into runs of
+                 more than R and a last run of R - R', each run with a common
+                 timestamp, by dynamic programming over the run's end.  The
+                 window that made a bucket names its timestamp (reset -
+                 duration).
+  leaky bucket   the answers do not fix the order: a read shows what has
+                 leaked back, so `remaining` rises and falls, and only an
+                 OVER_LIMIT answer tells its timestamp.  The order is taken
+                 from the told or guessed timestamps (as for a serial leaky
+                 key, `guide`); requests that follow each other there with
+                 equal answers are one window, split where their spans share
+                 no timestamp.  What is searched is only each window's
+                 timestamp: the leak between two windows fixes their distance
+                 to within one token's time, carried forward as an interval
+                 and fixed last to first.  The rule's replay decides; a key
+                 that it does not confirm is undecided, never a mismatch.
 """
 
 import bisect
@@ -59,6 +99,25 @@ def _token_key(ops, L, D, apply):
     return None
 
 
+def _guessed(e, lo, hi, guide):
+    """A timestamp for each request that tells none: answers of one drain
+    reach the clients together, so the told timestamp of the answer, to any
+    key, that was received nearest to it (`guide`); failing that, the receive
+    time less the run's median lag.  Held inside the request's span."""
+    lag = guide[2] if guide else 0.0
+    guess = np.clip(np.floor(e - lag).astype(np.int64), lo, hi)
+    if guide and len(guide[0]):
+        recv, now = guide[0], guide[1]
+        k = np.searchsorted(recv, e)
+        left = np.clip(k - 1, 0, len(recv) - 1)
+        right = np.clip(k, 0, len(recv) - 1)
+        pick = np.where(np.abs(recv[left] - e) <= np.abs(recv[right] - e),
+                        left, right)
+        near = now[pick]
+        guess = np.where((near >= lo) & (near <= hi), near, guess)
+    return guess
+
+
 class _Leaky:
     """Witness search for one leaky key (see the module docstring)."""
 
@@ -80,18 +139,7 @@ class _Leaky:
         # lag.  Requests of one timestamp were served as remaining falls.
         told = over | ok_hint
         h = np.where(over, n, np.where(ok_hint, hint, 0))
-        lag = guide[2] if guide else 0.0
-        guess = np.clip(np.floor(e - lag).astype(np.int64), lo, hi)
-        if guide and len(guide[0]):
-            recv, now = guide[0], guide[1]
-            k = np.searchsorted(recv, e)
-            left = np.clip(k - 1, 0, len(recv) - 1)
-            right = np.clip(k, 0, len(recv) - 1)
-            pick = np.where(np.abs(recv[left] - e) <= np.abs(recv[right] - e),
-                            left, right)
-            near = now[pick]
-            guess = np.where((near >= lo) & (near <= hi), near, guess)
-        h = np.where(told, h, guess)
+        h = np.where(told, h, _guessed(e, lo, hi, guide))
         order = np.lexsort((-rem, over, h))
         self.lo, self.hi = lo[order].tolist(), hi[order].tolist()
         self.over, self.r = over[order].tolist(), rem[order].tolist()
@@ -352,17 +400,24 @@ def _leaky_key(ops, hint, L, D, apply, budget, guide):
     return "ok", None
 
 
+def _leaky_told(ops, keyspace):
+    """Which answers are a leaky bucket's OVER_LIMIT, and the timestamp each
+    of those tells (reset - rate)."""
+    rank = ops["rank"]
+    over = ((keyspace.algos_of(rank) == LEAKY) & (ops["status"] == OVER)
+            & (ops["reset"] > 0))
+    rate = np.maximum(keyspace.durations_of(rank) // keyspace.limits_of(rank), 1)
+    return over, ops["reset"] - rate
+
+
 def _guide(ops, keyspace):
     """Every told timestamp of the run beside when its answer was received,
     by receive time, and the median of their difference: what a request whose
     timestamp is not told is guessed from.  Told are the leaky OVER_LIMIT
     answers (reset - rate) and the answers whose RPC held one (`hint`).
     Only orders the search's first try."""
-    rank = ops["rank"]
-    over = ((keyspace.algos_of(rank) == LEAKY) & (ops["status"] == OVER)
-            & (ops["reset"] > 0))
-    rate = np.maximum(keyspace.duration_ms // keyspace.limits_of(rank), 1)
-    now = np.where(over, ops["reset"] - rate, ops["hint"])
+    over, at = _leaky_told(ops, keyspace)
+    now = np.where(over, at, ops["hint"])
     told = over | (ops["hint"] > 0)
     if not told.any():
         return None
@@ -371,46 +426,351 @@ def _guide(ops, keyspace):
     return recv[by], now[by], float(np.median(recv - now))
 
 
-def check(ops, tainted, keyspace, apply, max_report=5):
+# ------------------------------------------------------- the GLOBAL family
+
+
+def _spans(s, e):
+    return (np.floor(s).astype(np.int64) - TOL_MS,
+            np.ceil(e).astype(np.int64) + TOL_MS)
+
+
+def _runs(lo, hi, t_prev, big, last, steps):
+    """Requests of one level in receive order, as consecutive runs with a
+    common timestamp each, never decreasing from `t_prev`: every run but the
+    last of more than `big` requests, the last of exactly `last` (None: of
+    any size, even none).  Returns [(start, end, timestamp)] with the
+    earliest last timestamp there is among such partitions, or None."""
+    n = len(lo)
+    best = [None] * (n + 1)       # end timestamp and cut of the prefix [0, i)
+    best[0] = (t_prev, None)
+    for i in range(1, n + 1):
+        mlo, mhi, got = -INF, INF, None
+        for j in range(i - 1, -1, -1):
+            mlo, mhi = max(mlo, lo[j]), min(mhi, hi[j])
+            if mlo > mhi:
+                break
+            steps[0] += 1
+            if best[j] is None:
+                continue
+            size = i - j
+            if i == n and last is not None:
+                if size > last:
+                    break
+                if size != last:
+                    continue
+            elif size <= big and not (i == n and last is None):
+                continue
+            t = max(best[j][0], mlo)
+            if t <= mhi and (got is None or t < got[0]):
+                got = (t, j)
+        best[i] = got
+    if best[n] is None:
+        return None
+    out, i = [], n
+    while i > 0:
+        t, j = best[i]
+        out.append((j, i, t))
+        i = j
+    return out[::-1]
+
+
+def _global_token_key(ops, L, D, rule, budget):
+    """Witness for one token key of the GLOBAL family (module docstring).
+    Returns (verdict, why)."""
+    s, e, st, rem, rs = ops
+    lo, hi = _spans(s, e)
+    if np.any((st == OVER) != (rem == 0)) and L > 1:
+        return "none", "a read says OVER_LIMIT with tokens left, or the reverse"
+    order = np.lexsort((e, st, -rem, rs))
+    lo, hi = lo[order], hi[order]
+    st, rem, rs = st[order], rem[order], rs[order]
+    n = len(order)
+    # levels: runs of equal (reset, remaining, status)
+    cut = np.flatnonzero((np.diff(rs) != 0) | (np.diff(rem) != 0)
+                         | (np.diff(st) != 0)) + 1
+    bounds = [0] + cut.tolist() + [n]
+    windows = []                  # (members' positions, timestamp)
+    t_prev, exact, steps = -INF, True, [0]
+    for a, b in zip(bounds[:-1], bounds[1:]):
+        R, reset, status = int(rem[a]), int(rs[a]), int(st[a])
+        nxt = b < n and int(rs[b]) == reset       # a lower level follows
+        drop = R - int(rem[b]) if nxt else None
+        first = a == 0 or int(rs[a - 1]) != reset
+        made = reset - D                           # the bucket's first window
+        idx = np.arange(a, b)
+        idx = idx[np.lexsort((lo[idx], hi[idx]))]  # by receive time
+        l, h = lo[idx].tolist(), np.minimum(hi[idx], reset).tolist()
+        if first:
+            if status == OVER and L > 1 or R != L - 1:
+                return "none", (f"the bucket reset={reset} starts at "
+                                f"remaining={R}, not at {L - 1}")
+            if made < t_prev:
+                return ("none" if exact else "undecided",
+                        f"bucket made at {made}, before the answers of the "
+                        f"bucket before it were given")
+            if a and made <= int(rs[a - 1]):
+                return "none", (f"bucket made at {made}, while the bucket "
+                                f"before it was still live")
+            fits = [k for k in range(len(idx)) if l[k] <= made <= h[k]]
+            size = b - a
+            whole = (len(fits) == size
+                     and (not nxt or int(rem[b]) == max(L - size, 0)))
+            if whole:
+                windows.append((idx, made))
+                t_prev = made
+                continue
+            if not fits:
+                return "none", (f"bucket made at {made}, outside the span of "
+                                f"every request that was answered from it")
+            # one request made the bucket; the others read remaining = L - 1
+            k = fits[0]
+            windows.append((idx[k:k + 1], made))
+            t_prev = made
+            idx = np.delete(idx, k)
+            del l[k], h[k]
+            if not len(idx):
+                if nxt:
+                    return "none", (f"one request made the bucket, the next "
+                                    f"answers say remaining={int(rem[b])}")
+                continue
+        size = len(idx)
+        if R == 0:
+            # an empty bucket refuses every window: each answer its own
+            for k in range(size):
+                t = max(t_prev, l[k])
+                if t > h[k]:
+                    return ("none" if exact else "undecided",
+                            f"no timestamp left for an OVER_LIMIT answer of "
+                            f"reset={reset}: needs >= {t}, answered by {h[k]}")
+                windows.append((idx[k:k + 1], t))
+                t_prev = t
+            continue
+        if drop is not None:
+            if drop <= 0:
+                return "none", f"remaining rises from {R} within one bucket"
+            refused = size - drop
+            if refused < 0 or 0 < refused <= R:
+                return "none", (
+                    f"{size} answers say remaining={R} and the next say "
+                    f"{R - drop}: the window's {drop} hits do not add up")
+        if size <= R:
+            # too few for a refused window: they are one window
+            t = max(t_prev, max(l))
+            if t > min(h):
+                return ("none" if exact else "undecided",
+                        f"the {size} requests that read remaining={R} share "
+                        f"no timestamp, and a window of them lands its hits")
+            windows.append((idx, t))
+            t_prev = t
+            continue
+        runs = _runs(l, h, t_prev, R, drop, steps)
+        if steps[0] > budget:
+            return "undecided", "search budget spent"
+        if runs is None:
+            return "undecided", (f"no split of the {size} answers at "
+                                 f"remaining={R} into refused windows found")
+        exact = False
+        for j, i, t in runs:
+            windows.append((idx[j:i], t))
+        t_prev = runs[-1][2] if runs else t_prev
+    # replay the witness through the rule, window by window
+    row = None
+    for members, t in windows:
+        row, got = rule(row.copy() if row is not None else None,
+                        [(1, L, D, TOKEN)] * len(members), int(t))
+        for p, g in zip(members.tolist(), got):
+            want = (int(st[p]), L, int(rem[p]), int(rs[p]))
+            if g != want:
+                return "undecided", (f"served {want}, the rule says {g} for a "
+                                     f"window of {len(members)} at {int(t)}")
+    return "ok", None
+
+
+def _global_leaky_key(ops, hint, L, D, rule, guide):
+    """Witness for one leaky key of the GLOBAL family (module docstring):
+    found and confirmed, or undecided."""
+    s, e, st, rem, rs = ops
+    rate = max(D // max(L, 1), 1)
+    if np.any((st == OVER) & (rem != 0)):
+        return "none", "refused (hits = 1) yet tokens left"
+    lo, hi = _spans(s, e)
+    over = st == OVER
+    told = np.where(over, rs - rate, 0)
+    if np.any(over & ((told < lo) | (told > hi))):
+        return "none", ("an OVER_LIMIT answer names a timestamp outside its "
+                        "request's span")
+    ok_hint = (hint > 0) & (hint >= lo) & (hint <= hi)
+    h = np.where(over, told,
+                 np.where(ok_hint, hint, _guessed(e, lo, hi, guide)))
+    lo = np.where(over, told, lo)
+    hi = np.where(over, told, hi)
+    order = np.lexsort((e, -rem, over, h)).tolist()
+    cols = (lo.tolist(), hi.tolist(), h.tolist(), st.tolist(), rem.tolist(),
+            rs.tolist())
+    why = None
+    # two ways to cut the order into windows: equal answers that follow each
+    # other are one window; or only where their guessed timestamp is one too
+    for same_guess in (False, True):
+        why = _leaky_windows(order, cols, same_guess, L, D, rate, rule)
+        if why is None:
+            return "ok", None
+    return "undecided", why
+
+
+def _leaky_windows(order, cols, same_guess, L, D, rate, rule):
+    """Cut `order` into windows, find each window's timestamp, replay.
+    None if the rule confirms the witness, else what stood in the way."""
+    lo, hi, h, st, rem, rs = cols
+    wins = []                      # [positions, lo, hi]
+    for p in order:
+        w = wins[-1] if wins else None
+        q = w[0][0] if w else None
+        if (w is not None and st[q] == st[p] and rem[q] == rem[p]
+                and rs[q] == rs[p] and max(w[1], lo[p]) <= min(w[2], hi[p])
+                and (not same_guess or h[q] == h[p])):
+            w[0].append(p)
+            w[1], w[2] = max(w[1], lo[p]), min(w[2], hi[p])
+        else:
+            wins.append([[p], lo[p], hi[p]])
+    # each window's timestamp: forward as an interval, then last to first
+    R, flo, fhi = None, -INF, INF
+    fwd = []
+    for members, a, b in wins:
+        q, m = members[0], len(members)
+        left = rem[q]
+        if R is None:
+            gap = (0, INF)
+            if st[q] == OVER or left != L - 1:
+                return "the first answers are not a new bucket's"
+            after = max(L - m, 0)
+        else:
+            if st[q] == OVER:
+                if R != 0:
+                    return "OVER_LIMIT with tokens left before it"
+                gap = (0, rate - 1)
+            elif left < L:
+                gap = ((left - R) * rate, (left - R) * rate + rate - 1)
+            else:
+                gap = ((L - R) * rate, INF)
+            after = left - m if m <= left else left
+            if (st[q] != OVER and left == L - 1
+                    and (left < R or max(a, flo + gap[0]) > min(b, fhi + gap[1]))):
+                # not what has leaked back: the row expired, a new bucket
+                # (the replay holds the expiry to the rule)
+                gap, after = (0, INF), max(L - m, 0)
+            elif left < R:
+                return f"remaining={left} read after a window that left {R}"
+        a, b = max(a, flo + gap[0]), min(b, fhi + gap[1])
+        if a > b:
+            return "no timestamps found for the guessed windows"
+        fwd.append((a, b, gap))
+        R, flo, fhi = after, a, b
+    nows, nxt, gap = [0] * len(wins), None, None
+    for i in range(len(wins) - 1, -1, -1):
+        a, b, mygap = fwd[i]
+        if nxt is not None:
+            if gap[1] != INF:
+                a = max(a, nxt - gap[1])
+            b = min(b, nxt - gap[0])
+        if a > b:
+            return "the witness's intervals do not close"
+        nows[i] = nxt = int(b)
+        gap = mygap
+    row = None
+    for (members, _, _), t in zip(wins, nows):
+        row, got = rule(row.copy() if row is not None else None,
+                        [(1, L, D, LEAKY)] * len(members), t)
+        for p, g in zip(members, got):
+            want = (st[p], L, rem[p], rs[p])
+            if g != want:
+                return (f"served {want}, the rule says {g} for a window of "
+                        f"{len(members)} at {t}")
+    return None
+
+
+def told_lag(ops, keyspace, window):
+    """How far the server's clock lies behind the clients': median of
+    (received - told timestamp) over the answers that tell theirs (a leaky
+    OVER_LIMIT answer: reset - rate; a token answer that made its bucket:
+    reset - duration), in the first and in the last fifth of `window`
+    (epoch seconds).  None where nothing told."""
+    rank, recv = ops["rank"], ops["recv"]
+    if not len(rank):
+        return {"first_fifth_ms": None, "last_fifth_ms": None, "told": 0}
+    over, at = _leaky_told(ops, keyspace)
+    made = ((keyspace.algos_of(rank) == TOKEN) & (ops["status"] != OVER)
+            & (ops["remaining"] == keyspace.limits_of(rank) - 1)
+            & (rank <= keyspace.population))
+    now = np.where(over, at, ops["reset"] - keyspace.durations_of(rank))
+    w0, w1 = window[0] * 1e3, window[1] * 1e3
+    fifth = (w1 - w0) / 5.0
+    out = {"told": int((over | made).sum())}
+    for name, a, b in (("first_fifth_ms", w0, w0 + fifth),
+                       ("last_fifth_ms", w1 - fifth, w1)):
+        m = (over | made) & (recv >= a) & (recv < b)
+        out[name] = float(np.median(recv[m] - now[m])) if m.any() else None
+    return out
+
+
+def check(ops, tainted, keyspace, apply, max_report=5, global_window=None):
     """ops: dict of equal-length arrays rank, sent, recv (epoch ms), status,
     remaining, reset, hint.  Returns the numbers compared and some words on
-    the first keys that failed."""
+    the first keys that failed.  Keys of the `global` family are compared
+    under `global_window` (module docstring), the others under `apply`; the
+    `global_*` numbers count that family's share of the totals."""
     rank = ops["rank"]
     out = {"followed_decisions": int(len(rank)), "checked_decisions": 0,
            "checked_keys": 0, "mismatched_keys": 0, "undecided_decisions": 0,
-           "tainted_keys": 0, "reports": []}
+           "undecided_keys": 0, "tainted_keys": 0, "reports": []}
+    if keyspace.glob is not None:
+        out.update(global_followed_decisions=int(
+            (rank > keyspace.population).sum()), global_checked_decisions=0,
+            global_checked_keys=0, global_mismatched_keys=0,
+            global_undecided_decisions=0, global_undecided_keys=0)
     if not len(rank):
         return out
     bad = set(int(x) for x in np.unique(tainted))
     order = np.argsort(rank, kind="stable")
     r_sorted = rank[order]
     cuts = np.flatnonzero(np.diff(r_sorted)) + 1
-    D = keyspace.duration_ms
     guide = _guide(ops, keyspace)
     for idx in np.split(order, cuts):
         rk = int(rank[idx[0]])
         if rk in bad:
             out["tainted_keys"] += 1
             continue
-        L, algo = keyspace.limit(rk), keyspace.algo(rk)
+        L, algo, D = keyspace.limit(rk), keyspace.algo(rk), keyspace.duration(rk)
+        glob = keyspace.is_global(rk)
         cols = (ops["sent"][idx], ops["recv"][idx],
                 ops["status"][idx].astype(np.int64),
                 ops["remaining"][idx], ops["reset"][idx])
-        if algo == TOKEN:
+        if glob and global_window is None:
+            raise ValueError("a key of the `global` family, and no "
+                             "`global_window` rule to compare it under")
+        if glob and algo == TOKEN:
+            verdict, why = _global_token_key(cols, L, D, global_window,
+                                             400 * len(idx) + 4000)
+        elif glob:
+            verdict, why = _global_leaky_key(cols, ops["hint"][idx], L, D,
+                                             global_window, guide)
+        elif algo == TOKEN:
             why = _token_key(cols, L, D, apply)
             verdict = "none" if why else "ok"
         else:
             verdict, why = _leaky_key(cols, ops["hint"][idx], L, D, apply,
                                       100 * len(idx) + 4000, guide)
-        if verdict == "ok":
-            out["checked_keys"] += 1
-            out["checked_decisions"] += len(idx)
-        elif verdict == "none":
-            out["mismatched_keys"] += 1
-        else:
-            out["undecided_decisions"] += len(idx)
+        for pre in ("", "global_") if glob else ("",):
+            if verdict == "ok":
+                out[pre + "checked_keys"] += 1
+                out[pre + "checked_decisions"] += len(idx)
+            elif verdict == "none":
+                out[pre + "mismatched_keys"] += 1
+            else:
+                out[pre + "undecided_decisions"] += len(idx)
+                out[pre + "undecided_keys"] += 1
         if why and len(out["reports"]) < max_report:
             out["reports"].append(
-                f"rank {rk} ({'leaky' if algo else 'token'}, limit {L}, "
+                f"rank {rk} ({'GLOBAL ' if glob else ''}"
+                f"{'leaky' if algo else 'token'}, limit {L}, "
                 f"{len(idx)} answers): {verdict}: {why}")
     return out

@@ -5,8 +5,10 @@
 The cell's own traffic, connections, fill and comparison, with
 benchmark/control_server.py in the daemon's place.  `stale` is the control of
 "How correct is decided": its runs have to come out `correct: false`.  `frozen`
-and `altered` are the planted faults; `sound` has to come out true.  One JSON
-line per seed, the compared numbers beside their limits.  Not part of a check.
+and `altered` are the planted faults; `lossy`, `late` and `serial` break the
+GLOBAL family's guarantee (control_server.py); `sound` has to come out true.
+One JSON line per seed, the compared numbers beside their limits.  Not part of
+a check.
 """
 
 import argparse

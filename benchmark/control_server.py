@@ -11,6 +11,17 @@ state unchanged in every fourth window; `altered` changes one answer of each
 reply in every fourth window, where it is produced.  `sound` breaks nothing (the reference
 served as it is), to show the comparison passes what it should.
 
+Items that ask for Behavior GLOBAL are held to the other guarantee, stale
+then consistent, and every mode serves them by its rule (`global_window` of
+the reference: all answers of a window read the row as it stood before it,
+the window's summed hits land once after it), so for them `stale` is the
+sound way.  Three modes break that guarantee the way a later PR would be
+tempted to: `lossy` (the summed hits of every fourth window never land: a
+dropped psum contribution), `late` (a window's hits land two windows on:
+staler than one drain, so a request sent after another's reply was received
+does not see it) and `serial` (GLOBAL items answered serially, each showing
+its own hit: exact, which is not what the configuration states).
+
 It speaks the daemon's protocol as far as the harness uses it: GetRateLimits
 over gRPC, /v1/HealthCheck, /metrics and /v1/admin/debug over HTTP, the
 device report in $BENCH_INFO_FILE, SIGTERM to stop.  No JAX, no chip.
@@ -29,6 +40,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark import wire  # noqa: E402
 from benchmark.reference import serial  # noqa: E402
 
+GLOBAL = 2          # Behavior GLOBAL on the wire
+
 
 class ControlServer:
     def __init__(self, mode, tick_ms):
@@ -37,6 +50,7 @@ class ControlServer:
         self.queue = []
         self.windows = 0
         self.altered = 0
+        self.landing = []           # late: summed hits that have yet to land
 
     async def get_rate_limits(self, data, context):
         fut = asyncio.get_running_loop().create_future()
@@ -51,6 +65,7 @@ class ControlServer:
         faulty = self.windows % 4 == 0
         stale = {} if self.mode == "stale" else None
         undo = {} if (self.mode == "frozen" and faulty) else None
+        sums = {}                   # GLOBAL keys: the window's requests
         for msg, fut in batch:
             out = wire.GetRateLimitsResp()
             for r in msg.requests:
@@ -58,19 +73,24 @@ class ControlServer:
                 if undo is not None and key not in undo:
                     old = rows.get(key)
                     undo[key] = old.copy() if old is not None else None
-                if stale is not None:
+                ask = (r.hits, r.limit, r.duration, r.algorithm)
+                if r.behavior == GLOBAL and self.mode != "serial":
+                    # the rule's read: the row as it stood before the window
+                    # (nothing of the family lands until the reads are done)
+                    old = rows.get(key)
+                    _, (resp,) = serial.global_window(
+                        old.copy() if old is not None else None, [ask], now)
+                    sums.setdefault(key, []).append(ask)
+                elif stale is not None:
                     if key not in stale:
                         old = rows.get(key)
                         stale[key] = old.copy() if old is not None else None
-                    before = stale[key]
+                    old = stale[key]
                     _, resp = serial.apply(
-                        before.copy() if before is not None else None,
-                        r.hits, r.limit, r.duration, r.algorithm, now)
-                    self.store.hit(key, r.hits, r.limit, r.duration,
-                                   r.algorithm, now)
+                        old.copy() if old is not None else None, *ask, now)
+                    self.store.hit(key, *ask, now)
                 else:
-                    resp = self.store.hit(key, r.hits, r.limit, r.duration,
-                                          r.algorithm, now)
+                    resp = self.store.hit(key, *ask, now)
                 out.responses.add(status=resp[0], limit=resp[1],
                                   remaining=resp[2], reset_time=resp[3])
             if self.mode == "altered" and faulty and len(out.responses):
@@ -79,6 +99,13 @@ class ControlServer:
                 one.remaining = one.remaining + 1 if one.remaining < one.limit - 1 \
                     else one.remaining - 1
             fut.set_result(out.SerializeToString())
+        # the windows' summed hits land: once, after the reads
+        if self.mode == "late":
+            self.landing.append(sums)
+            sums = self.landing.pop(0) if len(self.landing) > 2 else {}
+        if not (self.mode == "lossy" and faulty):
+            for key, asks in sums.items():
+                rows[key], _ = serial.global_window(rows.get(key), asks, now)
         if undo is not None:
             for key, old in undo.items():
                 if old is None:
@@ -139,7 +166,8 @@ async def amain(mode, tick_ms):
 
 if __name__ == "__main__":
     p = argparse.ArgumentParser()
-    p.add_argument("--mode", choices=("sound", "stale", "frozen", "altered"),
+    p.add_argument("--mode", choices=("sound", "stale", "frozen", "altered",
+                                      "lossy", "late", "serial"),
                    required=True)
     p.add_argument("--tick-ms", type=float, default=5.0)
     a = p.parse_args()

@@ -21,6 +21,7 @@ children pinned to cores of their own; the trace is reduced in a child too
 import importlib.util
 import json
 import os
+import re
 import shutil
 import signal
 import socket
@@ -81,16 +82,31 @@ class Bench:
         if mix["loop"] == "open" and "rate_rps" not in mix:
             raise BenchError(f"{name}: an open-loop cell needs rate_rps in "
                              f"benchmark/cells/{name}.json")
+        family = cfg["keyspace"].get("global")
+        if float(mix.get("global_item_share", 0.0)) > 0 and not family:
+            raise BenchError(
+                f"{name}: the mix has a global_item_share, and the keyspace "
+                f"of configuration {w['config']!r} has no `global` block")
+        if family and "global_window" not in self.reference_functions(w["config"]):
+            raise BenchError(
+                f"{name}: the keyspace has a `global` block, and benchmark/"
+                f"reference/{w['config']}.py has no `global_window` rule")
         return {"name": name, "chips": int(w["chips"]), "config": cfg,
                 "mix": mix, "config_name": w["config"]}
 
-    def reference(self, config_name):
+    def reference_functions(self, config_name):
+        """What a configuration's reference module states: `apply`, the
+        serial rule, and where it serves GLOBAL keys `global_window`."""
         path = os.path.join(self.data, "reference", config_name + ".py")
         spec = importlib.util.spec_from_file_location(
             "bench_reference_" + config_name.replace("-", "_"), path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.apply
+        return {k: getattr(mod, k) for k in ("apply", "global_window")
+                if callable(getattr(mod, k, None))}
+
+    def reference(self, config_name):
+        return self.reference_functions(config_name)["apply"]
 
     def metrics_for(self, cell_name, kind):
         """Names of the `end_to_end` or `per_layer` metrics this cell reports."""
@@ -180,9 +196,27 @@ class Server:
         self.proc = None
         self.log = open(os.path.join(workdir, "server.log"), "wb")
 
+    PLACEHOLDER = re.compile(r"\{([^{}]*)\}")
+
+    def fill_in(self, value):
+        """A `daemon_env` value with its placeholders filled for this run:
+        {grpc} and {http}, the run's own addresses, and {port}, a fresh free
+        port each time it stands.  A value without braces is passed as it is."""
+        def one(m):
+            if m.group(1) == "grpc":
+                return self.grpc
+            if m.group(1) == "http":
+                return self.http
+            if m.group(1) == "port":
+                return str(free_port())
+            raise BenchError(f"daemon_env: no placeholder {m.group(0)!r} "
+                             f"(there are {{grpc}}, {{http}} and {{port}})")
+        return self.PLACEHOLDER.sub(one, str(value))
+
     def start(self):
         env = dict(os.environ)
-        env.update({k: str(v) for k, v in self.cfg.get("daemon_env", {}).items()})
+        env.update({k: self.fill_in(v)
+                    for k, v in self.cfg.get("daemon_env", {}).items()})
         env.update(self.extra_env)
         env.update({"GUBER_GRPC_ADDRESS": self.grpc,
                     "GUBER_HTTP_ADDRESS": self.http,
@@ -231,6 +265,17 @@ class Server:
                 return f.read()[-n:].decode(errors="replace")
         except OSError:
             return ""
+
+    def said(self, words):
+        """The first line of the daemon's log that holds `words`, or None."""
+        try:
+            with open(os.path.join(self.workdir, "server.log"), "rb") as f:
+                for line in f:
+                    if words.encode() in line:
+                        return line.decode(errors="replace").strip()
+        except OSError:
+            pass
+        return None
 
     def debug(self):
         return json.loads(http_get(f"http://{self.http}/v1/admin/debug"))
@@ -322,6 +367,15 @@ class Generators:
             p.wait()
 
 
+def merge_errors(results):
+    """What refused or failed RPCs said, over all generators of a run."""
+    out = {}
+    for r in results:
+        for said, n in json.loads(str(r["errors"])).items():
+            out[said] = out.get(said, 0) + n
+    return out
+
+
 def merge_ops(results):
     keys = [k for k in results[0] if k.startswith("op_")]
     ops = {k[3:]: np.concatenate([r[k] for r in results]) for k in keys}
@@ -333,19 +387,28 @@ def merge_ops(results):
 
 
 def fill(cell, server, seed, workdir, cores):
-    """Load the arena: ranks 1..fill_keys once, 1000 to an RPC (set-up)."""
+    """Load the arena: ranks 1..fill_keys once, 1000 to an RPC (set-up), and
+    after them every key of the `global` family, asked for as the window
+    asks for it, so that a key's first use lies in set-up."""
     n = int(cell["config"].get("fill_keys", 0))
-    if n <= 0:
+    ks = cell["config"]["keyspace"]
+    family = int(ks["global"]["keys"]) if ks.get("global") else 0
+    if n <= 0 and family <= 0:
         return None, 0.0
     t = time.time()
     mix = cell["mix"]
     procs = int(mix["generator_procs"])
     edges = np.linspace(1, n + 1, procs + 1).astype(int)
+    per_proc = [{"fill_lo": int(edges[i]), "fill_hi": int(edges[i + 1])}
+                for i in range(procs)]
+    if family:
+        first = int(ks["population"]) + 1
+        edges = np.linspace(first, first + family, procs + 1).astype(int)
+        for i in range(procs):
+            per_proc[i]["fill_more"] = [[int(edges[i]), int(edges[i + 1])]]
     fcell = dict(cell, mix=dict(mix, connections=int(mix["fill_connections"])))
     gens = Generators(fcell, server, seed, workdir, cores, "fill", "fill",
-                      per_proc=[{"fill_lo": int(edges[i]),
-                                 "fill_hi": int(edges[i + 1])}
-                                for i in range(procs)])
+                      per_proc=per_proc)
     try:
         results = gens.wait(600.0)
     finally:
@@ -547,7 +610,9 @@ def compare(bench, cell, ops, tainted, client):
     mix = cell["mix"]
     lim = mix["check"]
     ks = traffic.KeySpace(cell["config"]["keyspace"])
-    got = checker.check(ops, tainted, ks, bench.reference(cell["config_name"]))
+    ref = bench.reference_functions(cell["config_name"])
+    got = checker.check(ops, tainted, ks, ref["apply"],
+                        global_window=ref.get("global_window"))
     followed = max(got["followed_decisions"], 1)
     numbers = {
         "mismatched_keys": (got["mismatched_keys"], 0, "max"),
@@ -558,6 +623,11 @@ def compare(bench, cell, ops, tainted, client):
         "failed_share": (client["failed"] / max(client["attempted"], 1),
                          float(lim["max_failed_share"]), "max"),
     }
+    if float(mix.get("global_item_share", 0.0)) > 0:
+        # a run in which no GLOBAL answer was verified cannot read correct
+        numbers["global_checked_decisions"] = (
+            got["global_checked_decisions"],
+            int(lim["min_global_checked_decisions"]), "min")
     ok = all(v <= l if how == "max" else v >= l
              for v, l, how in numbers.values())
     compared = {k: {"value": v, "limit": l, "holds": how}
@@ -589,6 +659,7 @@ def run_cell(bench, name, seed, seconds, trace, device_ok, server_argv=None,
         trace_dir = trace_after(cell, server, seed, workdir,
                                 cores["generators"]) if trace else None
         info = server.stop()
+        mesh_said = server.said("mesh mode:")
     except BaseException:
         server.stop()
         say(server.tail())
@@ -606,10 +677,12 @@ def run_cell(bench, name, seed, seconds, trace, device_ok, server_argv=None,
         if trace:
             try:
                 ctx["trace"] = reduce_trace(trace_dir, workdir)
-            except BenchError:
+            except BenchError as e:
                 if require_device_trace:
                     raise
+                ctx["trace_error"] = str(e)
         ops, tainted = merge_ops((fill_results or []) + m["results"])
+        ctx["ops"], ctx["tainted"] = ops, tainted
         correct, compared, detail = compare(bench, cell, ops, tainted, client)
     finally:
         if keep is None:
@@ -641,7 +714,13 @@ def run_cell(bench, name, seed, seconds, trace, device_ok, server_argv=None,
                              "idle_gaps": ctx["trace"]["idle_gaps"][:10]}
     line["run"] = {"cores": cores, "ready_s": t_ready, "fill_s": fill_s,
                    "warm_s": m["setup_s"] - t_filled,
-                   "decisions": client["decisions"], "client": client}
+                   "decisions": client["decisions"], "client": client,
+                   "mesh": mesh_said, "told_lag": checker.told_lag(
+                       ops, traffic.KeySpace(cell["config"]["keyspace"]),
+                       m["window"]),
+                   "families": {k: v for k, v in detail.items()
+                                if k != "reports"},
+                   "errors": merge_errors((fill_results or []) + m["results"])}
     line["compared"] = compared
     say(f"cores: server {cores['server']} generators {cores['generators']} "
         f"harness {cores['harness']}")

@@ -5,7 +5,8 @@ names the server, the configuration's keyspace, the traffic mix, the seed and
 this process's index.  Modes:
 
   fill    send ranks [lo, hi) once, 1000 to an RPC, as fast as the server
-          answers (set-up: loads the arena);
+          answers (set-up: loads the arena); with `fill_more`, further spans
+          of ranks after it (the `global` family's keys);
   open    Poisson arrivals at a fixed rate, several RPCs outstanding on a
           connection; latency runs from each RPC's *due* time;
   closed  one RPC outstanding per connection, the next sent on the reply.
@@ -24,6 +25,7 @@ was sent and received, and what the server said.
 import asyncio
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -95,6 +97,7 @@ class Generator:
         self.window = None
         self.stopping = False
         self.win_issued = 0
+        self.errors = {}            # what refused answers said, and how often
 
     # ------------------------------------------------------------ plumbing
 
@@ -117,7 +120,8 @@ class Generator:
         leaky positions of hot ranks whose OVER_LIMIT answer reveals the
         drain's timestamp (a hint for ordering, never a constraint)."""
         self.pool = pool
-        mask = traffic.sampled_ranks_mask(pool, self.check, self.seed)
+        mask = traffic.sampled_ranks_mask(pool, self.check, self.seed,
+                                          self.ks.population)
         self.row_pos, self.row_ranks, self.row_reveal = [], [], []
         ks = self.ks
         for i in range(pool.shape[0]):
@@ -147,14 +151,17 @@ class Generator:
                 for it in responses:
                     if it.error or it.metadata:
                         ok = False
+                        self.note(it.error
+                                  or f"metadata {sorted(it.metadata)}".replace("'", ""))
                         break
         except asyncio.CancelledError:
             # never answered within the grace period: may have been applied
             self.rec.tainted.extend(self.row_ranks[row].tolist())
             raise
-        except Exception:
+        except Exception as e:
             recv = time.time()
             ok = False
+            self.note(f"{type(e).__name__}: {e}")
         pos = self.row_pos[row]
         if pos:
             if ok:
@@ -177,14 +184,20 @@ class Generator:
         r["ok"].append(ok)
         return ok, recv
 
+    def note(self, said):
+        said = re.sub(r"'[^']*'", "'*'", said)[:200]    # without the key
+        if said in self.errors or len(self.errors) < 8:
+            self.errors[said] = self.errors.get(said, 0) + 1
+
     # --------------------------------------------------------------- modes
 
     async def run_fill(self):
-        lo, hi = int(self.job["fill_lo"]), int(self.job["fill_hi"])
+        spans = [(int(self.job["fill_lo"]), int(self.job["fill_hi"]))]
+        spans += [(int(a), int(b)) for a, b in self.job.get("fill_more", ())]
         per = 1000
-        starts = list(range(lo, hi, per))
+        starts = [(a, hi) for lo, hi in spans for a in range(lo, hi, per)]
         rows = np.zeros((len(starts), per), dtype=np.int64)
-        for i, a in enumerate(starts):
+        for i, (a, hi) in enumerate(starts):
             b = min(a + per, hi)
             rows[i, :b - a] = np.arange(a, b)
             rows[i, b - a:] = a          # pad the last row with its first rank
@@ -297,6 +310,7 @@ class Generator:
         out["items_per_rpc"] = np.asarray(self.pool.shape[1])
         out["window"] = np.asarray(self.window or (0.0, 0.0))
         out["win_issued"] = np.asarray(self.win_issued)
+        out["errors"] = np.asarray(json.dumps(self.errors))
         np.savez(self.job["out"], **out)
 
 

@@ -201,9 +201,23 @@ def reduce_dir(trace_dir):
     return reduce_planes(planes)
 
 
+def what_is_there(trace_dir):
+    """For the one who has to find out why a trace reduced to nothing: its
+    files, their planes and each plane's lines with their event counts."""
+    out = []
+    for path in find_traces(trace_dir):
+        out.append(f"{os.path.relpath(path, trace_dir)} "
+                   f"({os.path.getsize(path)} bytes):")
+        for pname, lines in read_planes(path):
+            out.append(f"  {pname}: " + ", ".join(
+                f"{lname} ({len(ev)})" for lname, ev in lines[:12]))
+    return "\n".join(out[:60]) or "no .xplane.pb file"
+
+
 if __name__ == "__main__":
     got = reduce_dir(sys.argv[1])
     if got is None:
-        sys.exit("no device events in the trace under " + sys.argv[1])
+        sys.exit(f"no device events in the trace under {sys.argv[1]}:\n"
+                 + what_is_there(sys.argv[1]))
     with open(sys.argv[2], "w") as f:
         json.dump(got, f)

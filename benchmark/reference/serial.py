@@ -9,6 +9,11 @@ a comparison of the served answers with `apply` below, request by request.
 
 A row is None on a miss; an expired row (expire < now) counts as a miss.
 The response is (status, limit, remaining, reset_time).
+
+`global_window` states the other guarantee a configuration may give, Behavior
+GLOBAL on a mesh of chips: stale, then consistent.  Every answer of one
+window is a read of the row as it stood before the window, and the window's
+hits are summed and applied once after it.
 """
 
 TOKEN_BUCKET = 0
@@ -86,6 +91,27 @@ def apply(row, hits, limit, duration, algo, now):
     if algo == LEAKY_BUCKET:
         return _leaky(row, hits, limit, duration, now)
     return _token(row, hits)
+
+
+def global_window(row, requests, now):
+    """One GLOBAL key through one window.  `requests` is the window's
+    [(hits, limit, duration, algo), ...] for that key, `now` the window's one
+    timestamp.  Every answer is the read (hits = 0) of the row as it stood
+    before the window; a missing, expired or other-algorithm row answers as
+    if made by the request's own hits.  Then the window's summed hits are
+    applied once, under the last request's limit and duration.  Returns
+    (new row, [response, ...])."""
+    out, summed, last = [], 0, None
+    for hits, limit, duration, algo in requests:
+        live = row is not None and row.expire >= now and row.algo == algo
+        _, resp = apply(row.copy() if live else None, 0 if live else hits,
+                        limit, duration, algo, now)
+        out.append(resp)
+        summed += hits
+        last = (limit, duration, algo)
+    if summed:
+        row, _ = apply(row, summed, last[0], last[1], last[2], now)
+    return row, out
 
 
 class SerialStore:

@@ -18,12 +18,32 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     info_file = os.environ["BENCH_INFO_FILE"]
     import jax
-    devs = jax.devices()
-    info = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs)}
-    with open(info_file + ".tmp", "w") as f:
-        json.dump(info, f)
-    os.replace(info_file + ".tmp", info_file)
+    devs, info = [], {}
+
+    def write():
+        with open(info_file + ".tmp", "w") as f:
+            json.dump(info, f)
+        os.replace(info_file + ".tmp", info_file)
+
+    def report():
+        devs.extend(jax.devices())
+        info.update(platform=devs[0].platform, kind=devs[0].device_kind,
+                    count=len(devs))
+        write()
+
+    if os.environ.get("GUBER_MESH_COORDINATOR"):
+        # mesh mode: jax.distributed.initialize has to come before the first
+        # look at the devices, and the daemon makes that call itself
+        from gubernator_tpu.parallel import distributed
+        join = distributed.initialize_from_env
+
+        def join_then_report():
+            on = join()
+            report()
+            return on
+        distributed.initialize_from_env = join_then_report
+    else:
+        report()
 
     from gubernator_tpu import daemon
     daemon.main([])
@@ -33,9 +53,7 @@ def main():
         stats = d.memory_stats() or {}
         peaks.append(int(stats.get("peak_bytes_in_use", 0)))
     info["memory_peak_bytes"] = max(peaks)
-    with open(info_file + ".tmp", "w") as f:
-        json.dump(info, f)
-    os.replace(info_file + ".tmp", info_file)
+    write()
 
 
 if __name__ == "__main__":

@@ -95,10 +95,10 @@ def _varint(n):
     return bytes(out)
 
 
-def item_bytes(name, unique_key, hits, limit, duration, algorithm):
+def item_bytes(name, unique_key, hits, limit, duration, algorithm, behavior=0):
     """One `requests` entry of a GetRateLimitsReq, tag and length included:
     an RPC's body is the concatenation of its items' bytes."""
     body = RateLimitReq(name=name, unique_key=unique_key, hits=hits,
-                        limit=limit, duration=duration,
-                        algorithm=algorithm).SerializeToString()
+                        limit=limit, duration=duration, algorithm=algorithm,
+                        behavior=behavior).SerializeToString()
     return b"\x0a" + _varint(len(body)) + body

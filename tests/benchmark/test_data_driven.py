@@ -5,7 +5,7 @@ import json
 import os
 
 from benchmark import control, harness
-from tests.benchmark.helpers import tiny_root
+from tests.benchmark.helpers import MESH_ENV, add_global_deployment, tiny_root
 
 
 def test_a_later_pr_adds_one_of_each_without_editing_a_file(tmp_path):
@@ -65,5 +65,72 @@ def test_a_later_pr_adds_one_of_each_without_editing_a_file(tmp_path):
     assert line["attempted"] == 100
     assert line["metrics"]["control_windows.lat"]["value"] > 0
     assert "admission_wait_ms.lat" not in line["metrics"]
+
+    # and a GLOBAL deployment on a mesh of chips: a `global` block in the
+    # keyspace, the mesh environment stated with placeholders, a reference
+    # that exports the rule, a mix with a share of GLOBAL items, a cell
+    cell = add_global_deployment(root, daemon_env=MESH_ENV)
+    bench = harness.Bench(root)
+    line, *_ = harness.run_cell(
+        bench, cell, 3_000_000_032, 3.0, False, control.accept_control,
+        server_argv=control.control_argv("sound"))
+    assert line["correct"], line["compared"]
+    floor = line["compared"]["global_checked_decisions"]
+    assert floor["value"] >= floor["limit"] == 100 and floor["holds"] == "min"
+    assert line["run"]["families"]["global_checked_keys"] == 64
+    assert list(line)[-1] == "compared"
     for p, data in before.items():
         assert open(p, "rb").read() == data, p
+
+
+def test_what_a_global_deployment_lacks_is_said_before_any_run(tmp_path):
+    root = tiny_root(tmp_path)
+    cell = add_global_deployment(root)
+
+    def rewrite(rel, change):
+        path = os.path.join(root, rel)
+        obj = json.load(open(path))
+        change(obj)
+        with open(path, "w") as f:
+            json.dump(obj, f)
+    # a reference that does not state the rule
+    with open(os.path.join(root, "benchmark/reference/global-tiny.py"), "w") as f:
+        f.write("from benchmark.reference.serial import apply\n")
+    try:
+        harness.Bench(root).cell(cell)
+        raise AssertionError("a reference without the rule was accepted")
+    except harness.BenchError as e:
+        assert "global_window" in str(e)
+    # a share of GLOBAL items, and no family to draw them from
+    with open(os.path.join(root, "benchmark/reference/global-tiny.py"), "w") as f:
+        f.write("from benchmark.reference.serial import apply, global_window\n")
+    rewrite("benchmark/configs/global-tiny.json",
+            lambda cfg: cfg["keyspace"].pop("global"))
+    try:
+        harness.Bench(root).cell(cell)
+        raise AssertionError("a share without the block was accepted")
+    except harness.BenchError as e:
+        assert "global_item_share" in str(e)
+    # a mix without the key means a share of 0: the cell loads
+    rewrite("benchmark/traffic/global-50.json",
+            lambda mix: mix.pop("global_item_share"))
+    assert harness.Bench(root).cell(cell)["mix"]["loop"] == "closed"
+
+
+def test_daemon_env_placeholders_are_filled_per_run(tmp_path):
+    cfg = {"daemon_env": dict(MESH_ENV, GUBER_TPU_BATCH_PER_SHARD="256")}
+    a = harness.Server(cfg, str(tmp_path), [])
+    b = harness.Server(cfg, str(tmp_path), [])
+    assert a.fill_in("{grpc}") == a.grpc != b.grpc
+    assert a.fill_in("x,{http}") == "x," + a.http
+    port = a.fill_in("127.0.0.1:{port}").rsplit(":", 1)[1]
+    assert port.isdigit() and port not in (a.grpc.rsplit(":", 1)[1],
+                                           a.http.rsplit(":", 1)[1])
+    assert a.fill_in("256") == "256" and a.fill_in(16384) == "16384"
+    try:
+        a.fill_in("{nope}")
+        raise AssertionError("an unknown placeholder was passed on")
+    except harness.BenchError as e:
+        assert "{nope}" in str(e)
+    a.log.close()
+    b.log.close()

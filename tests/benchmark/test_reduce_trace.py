@@ -85,6 +85,44 @@ def test_busy_is_averaged_over_devices_and_window_is_the_fullest(tmp_path):
     assert got["window_s"] == pytest.approx(3000e-9)
 
 
+def test_four_chips_of_a_mesh(tmp_path):
+    """One lockstep drain program on four chips: every plane runs the same
+    three modules and one all-reduce, chip 2 is busy longest (it waits in the
+    collective for the others).  Busy time and op self-times are means over
+    the chips; the window and the named idle gaps are the fullest chip's."""
+    def plane(i, module_ns, reduce_ns):
+        mods = [("jit_drain", 1000 + 10000 * k, module_ns) for k in range(3)]
+        ops = []
+        for k in range(3):
+            t = 1000 + 10000 * k
+            ops += [("%fusion.7 = u32[2621440]{0} fusion(u32[8]{0} %p)", t,
+                     module_ns - reduce_ns),
+                    ("%all-reduce.1 = s64[1024]{0} all-reduce(s64[1024]{0} %d)",
+                     t + module_ns - reduce_ns, reduce_ns)]
+        return (f"/device:TPU:{i}", [("XLA Modules", mods), ("XLA Ops", ops)])
+    got = lay_out(tmp_path, [
+        plane(0, 4000, 100), plane(1, 5000, 1100), plane(2, 8000, 4100),
+        plane(3, 7000, 3100),
+        ("/host:CPU", [("python", [("guber_fetch", 9500, 1000),
+                                   ("guber_pack", 19200, 600),
+                                   ("guber_commit", 5500, 2000)])])])
+    assert got["devices"] == 4 and got["modules"] == 3
+    assert got["busy_s"] == pytest.approx((4000 + 5000 + 8000 + 7000) * 3 / 4 * 1e-9)
+    # chip 2: first module at 1000, last ends at 21000 + 8000
+    assert got["window_s"] == pytest.approx(28000e-9)
+    assert got["module_s"] == pytest.approx(24000e-9)
+    ops = dict(got["device_ops"])
+    assert ops["all-reduce.1 s64[1024]"] == pytest.approx(
+        (100 + 1100 + 4100 + 3100) * 3 / 4 * 1e-9)
+    assert ops["fusion.7 u32[2621440]"] == pytest.approx(3900 * 3e-9)
+    # chip 2 idles 9000-11000 and 19000-21000; chip 0 idles from 5000 on, and
+    # `guber_commit` (5500-7500) names none of chip 2's gaps
+    assert dict(got["idle_gaps"]) == pytest.approx(
+        {"guber_fetch": 1000e-9, "guber_pack": 600e-9, "unattributed": 2400e-9})
+    assert sum(s for _, s in got["idle_gaps"]) == pytest.approx(
+        got["window_s"] - got["module_s"])
+
+
 def test_a_trace_without_device_events_reduces_to_nothing(tmp_path):
     assert lay_out(tmp_path, [("/host:CPU", [("python", [("guber_drain", 0, 10)])])]) is None
     assert rt.reduce_dir(os.path.join(str(tmp_path), "nowhere")) is None
