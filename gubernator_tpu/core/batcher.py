@@ -21,6 +21,8 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from gubernator_tpu.api.types import Behavior, RateLimitReq, RateLimitResp
 from gubernator_tpu.config import BehaviorConfig
 from gubernator_tpu.core.engine import RateLimitEngine
@@ -156,8 +158,9 @@ class WindowBatcher:
         """Serve a whole serialized GetRateLimitsReq (or, with peer_mode,
         an authoritative GetPeerRateLimitsReq) through the pipeline; None
         => caller must use the full path (always the case in lockstep
-        mode, whose pipeline keeps the raw-RPC lane gated off —
-        rpc_enabled — because mesh routes by shard, not by ring)."""
+        mode where other hosts own some of the shards: the pipeline keeps
+        the raw-RPC lane gated off there — rpc_enabled — because the C
+        parser routes by local shard)."""
         if self.pipeline is None:
             return None
         return await self.pipeline.submit_rpc(data, peer_mode=peer_mode)
@@ -183,40 +186,88 @@ class WindowBatcher:
             self._tick_task = asyncio.create_task(self._tick_loop())
 
     async def _tick_loop(self) -> None:
-        import time as _time
+        """The lockstep clock's ticks, on the wall clock: sleep to the next
+        deadline, never run ahead of it, skip whole periods when behind
+        (parallel/distributed.py LockstepClock).  A tick's sequence, the
+        same on every process: [compact drain, legacy stacked step].
 
-        period = self.behaviors.batch_wait
-        t0 = _time.monotonic()
-        n = 0
+        Where other hosts wait on this one's collectives (a multiprocess
+        engine) both are dispatched every tick, work or none, and the tick
+        ends when they have been.  A process that holds every shard
+        dispatches the drain only when something is staged and the
+        pipeline has room (lockstep_pump), the legacy step only for items
+        that had to take it, and does not wait for either: an idle tick
+        costs no device work, and the next drain is packed while the last
+        one runs."""
+        clock = self.clock
+        loop = asyncio.get_running_loop()
+        pipe = self.pipeline
+        if pipe is not None and not pipe.lockstep:
+            pipe = None
+        must = self.engine.multiprocess
+        stack = max(self.behaviors.lockstep_stack, 1)
+        m = self.metrics
+        kinds = pipe.lockstep_ticks if pipe is not None else {}
+        skipped = 0
+
+        def count(kind: str, n: int = 1) -> None:
+            if n:
+                kinds[kind] = kinds.get(kind, 0) + n
+                if m is not None:
+                    m.lockstep_ticks.labels(kind=kind).inc(n)
+
+        # the epoch is the first tick's: start-up, warm-up and a fill lie
+        # behind it.  Several hosts agree it (and each tick's index)
+        # through a collective, which belongs on the engine thread.
+        if clock.agrees:
+            await loop.run_in_executor(self._executor, clock.start)
+        else:
+            clock.start()
         while not self._closed:
             if (self.stop_at_tick is not None
-                    and self.clock.tick >= self.stop_at_tick):
+                    and clock.tick >= self.stop_at_tick):
                 return
-            n += 1
-            delay = t0 + n * period - _time.monotonic()
-            if delay > 0:
-                await asyncio.sleep(delay)
-            # per-window try: a failure taking window k must not discard
-            # windows already taken (their futures would hang forever)
-            windows = []
-            for _ in range(max(self.behaviors.lockstep_stack, 1)):
-                try:
-                    windows.append(self._take_window())
-                except Exception:  # defensive: the tick loop must never die
-                    windows.append([])
+            # sleep(0) when behind: the loop still gets to the submits
+            await asyncio.sleep(max(clock.until_next(), 0.0))
+            if self._closed:
+                return
             try:
-                now = self.clock.next_now()
-                # tick sequence, identical on every process: [compact
-                # drain, legacy stacked step].  Both land on the
-                # single-thread engine executor in submission order, so
-                # queueing the drain first fixes the collective order
-                # process-wide.
-                drain_fut = None
-                if self.pipeline is not None and self.pipeline.lockstep:
-                    drain_fut = self.pipeline.lockstep_pump(
-                        now, max(self.behaviors.lockstep_stack, 1))
-                await self._run_lockstep_window(windows, now)
+                if clock.agrees:
+                    now = await loop.run_in_executor(self._executor,
+                                                     clock.next_now)
+                else:
+                    now = clock.next_now()
+                if m is not None:
+                    m.observe_stage("tick_lag", clock.lag_s)
+                count("skipped", clock.skipped - skipped)
+                skipped = clock.skipped
+                with TraceAnnotation("guber_tick"):
+                    # per-window try: a failure taking window k must not
+                    # discard windows already taken (their futures would
+                    # hang forever)
+                    windows = []
+                    if must or self._pending:
+                        for _ in range(stack):
+                            try:
+                                windows.append(self._take_window())
+                            except Exception:  # the tick loop must never die
+                                windows.append([])
+                    # Both land on the single-thread engine executor in
+                    # submission order, so queueing the drain first fixes
+                    # the collective order process-wide.
+                    drain_fut = (pipe.lockstep_pump(now, stack, must)
+                                 if pipe is not None else None)
+                    legacy = must or any(windows)
                 if drain_fut is not None:
+                    count("drain")
+                elif pipe is not None and pipe._hold_reason in ("gate",
+                                                                "depth"):
+                    count("held")
+                elif not legacy:
+                    count("idle")
+                if legacy:
+                    await self._run_lockstep_window(windows, now)
+                if drain_fut is not None and must:
                     # surfaces only irrecoverable drain-dispatch failure
                     # (the zero-stack realign also failed): fail-stop
                     await drain_fut
@@ -245,12 +296,17 @@ class WindowBatcher:
         Invalid entries (mis-routed key, unregistered GLOBAL key — e.g. from
         a peer with a stale picker) are failed INDIVIDUALLY here: a packing
         exception later would skip this host's dispatch for the tick and
-        wedge the mesh lockstep."""
+        wedge the mesh lockstep.  Only as many entries as a window has
+        lanes are looked at: a backlog behind them waits untouched."""
         if not self._pending:
             return []
+        eng = self.engine
+        reach = eng.num_local_shards * (eng.batch_per_shard
+                                        + eng.global_batch_per_shard)
+        head, tail = self._pending[:reach], self._pending[reach:]
         ok = []
-        for item in self._pending:
-            err = self.engine.routing_error(item[0])
+        for item in head:
+            err = eng.routing_error(item[0])
             if err is None:
                 ok.append(item)
             elif not item[2].done():
@@ -260,10 +316,10 @@ class WindowBatcher:
             # lane to one hot tenant's burst (stable within tenant, so
             # per-key order is preserved — same key => same tenant)
             ok = interleave_by_tenant(ok, lambda t: tenant_of(t[0]))
-        fit = self.engine.max_window_prefix([w[0] for w in ok])
+        fit = eng.max_window_prefix([w[0] for w in ok])
         if self.qos is not None:
             fit = min(fit, self._window_limit())
-        window, self._pending = ok[:fit], ok[fit:]
+        window, self._pending = ok[:fit], ok[fit:] + tail
         return window
 
     async def _run_lockstep_window(self, windows: List[List[tuple]],
@@ -276,6 +332,11 @@ class WindowBatcher:
         loop = asyncio.get_running_loop()
         start = time.monotonic()
         n_reqs = sum(len(w) for w in windows)
+        if self.pipeline is not None and self.pipeline.lockstep and n_reqs:
+            self.pipeline.lane_decisions["legacy"] += n_reqs
+            if self.metrics is not None:
+                self.metrics.lockstep_decisions.labels(
+                    lane="legacy").inc(n_reqs)
         # Structural invariant: this tick issues EXACTLY one device dispatch,
         # no matter what step() does.  windows_processed increments once per
         # dispatch (K times for a stacked tick), so compare it instead of

@@ -65,6 +65,20 @@ single chip, and GLOBAL singles ride the drain's composed window
 GLOBAL traffic outside lockstep mode stay on the legacy step path — the
 pipeline and that path serialize on the same single-thread engine
 executor, so state mutation order is well defined.
+
+A mesh whose shards all belong to this process (one daemon over the chips
+of one host) takes whole RPCs too: the raw-RPC lane stages them as on a
+standalone node and the tick drains them.  The C parser marks an RPC's
+token and leaky GLOBAL items instead of refusing the RPC; _GlobalStage
+folds them into the drain's GLOBAL window (one lane for every distinct
+key, hits and config: the answers of a window are reads of the row as it
+stood before it, so equal requests get equal answers, and the lane's
+summed hits land once through the psum), and the fetch side appends their
+answers to the response words as rows of their own, so that the C encoder
+writes the whole RPC.  With no other host waiting on this one's
+collectives, a tick dispatches only when something is staged and the
+pipeline has room.  Several hosts keep one fixed dispatch every tick and
+route per item.
 """
 
 from __future__ import annotations
@@ -93,7 +107,9 @@ from gubernator_tpu.config import (CHAIN_LINGER_MS_DEFAULT,
 from gubernator_tpu.core.engine import PIPELINE_K_BUCKETS
 from gubernator_tpu.core.window_buffers import RequestColumns, WindowArenaRing
 from gubernator_tpu.net.faults import FAULTS, SEAM_ENGINE_DISPATCH
-from gubernator_tpu.observability.metrics import PUMP_HOLD_REASONS
+from gubernator_tpu.observability.metrics import (LOCKSTEP_LANES,
+                                                  LOCKSTEP_TICK_KINDS,
+                                                  PUMP_HOLD_REASONS)
 from gubernator_tpu.observability.tracing import current_context
 from gubernator_tpu.ops import kernel
 from gubernator_tpu.qos import interleave_by_tenant
@@ -207,7 +223,7 @@ class RpcJob:
 
     __slots__ = ("data", "fut", "n", "row", "lane", "pos", "limit", "off",
                  "mlen", "remote_idx", "forward_task", "peer_mode",
-                 "ctx", "enq", "committed")
+                 "ctx", "enq", "committed", "gdefer")
 
     def __init__(self, data: bytes, fut: asyncio.Future,
                  peer_mode: bool = False):
@@ -229,6 +245,9 @@ class RpcJob:
         self.mlen = None
         self.remote_idx = ()
         self.forward_task = None
+        # lockstep lane: the GLOBAL items this tick's window had no lane
+        # for, as (item index, request); they ride a later tick
+        self.gdefer = ()
 
     def finish(self, pipeline, wflat, clflat, now):
         # the encode target is a per-fetch-thread scratch buffer: bytes()
@@ -431,6 +450,103 @@ class _GlobalJob:
         ]
 
 
+class _GlobalStage:
+    """One drain's GLOBAL window while it is staged (engine thread).
+
+    Requests for one key with the same hits and config share a lane: under
+    the window rule every answer is a read of the row as it stood before
+    the window, so they get the same answer, and the lane accumulates their
+    summed hits for the psum.  Lanes go round-robin over the local shards
+    (the psum is shard-agnostic).  A dynamic engine (one that holds every
+    shard) configures a slot in the same dispatch, through the drain's
+    bounded update lanes, when the key is new or its config changed since
+    this pipeline last staged it; `add` answers -1 where the window has no
+    lane or no update lane left, and the caller defers the item."""
+
+    __slots__ = ("eng", "now", "gbatch", "gacc", "upd", "SL", "cap", "Kg",
+                 "lanes", "slots", "acc", "cfg_upd", "resets", "items",
+                 "cfg_seen", "dynamic")
+
+    def __init__(self, eng, now: int, cfg_seen: dict):
+        self.eng, self.now = eng, now
+        self.gbatch, self.gacc, self.upd = eng.empty_drain_control()
+        self.SL = eng.num_local_shards
+        self.cap = self.SL * eng.global_batch_per_shard
+        self.Kg = eng.max_global_updates
+        self.lanes: dict = {}    # (key, hits, limit, duration, algo) -> lane
+        self.slots: dict = {}    # key -> arena slot, looked up this drain
+        self.acc: List[int] = []  # summed hits per lane
+        self.cfg_upd: dict = {}  # slot -> config staged for update
+        self.resets: List[int] = []
+        self.items = 0
+        self.cfg_seen = cfg_seen
+        self.dynamic = eng._dynamic_global
+        eng.gtable.begin_window()
+
+    def add(self, key: str, hits: int, limit: int, duration: int,
+            algo: int) -> int:
+        lk = (key, hits, limit, duration, algo)
+        flat = self.lanes.get(lk)
+        if flat is None:
+            flat = len(self.acc)
+            if flat >= self.cap:
+                return -1
+            slot = self.slots.get(key)
+            is_init = False
+            if slot is None:
+                # a key new to the arena takes a reset and an update lane;
+                # it is looked up only once both are known to be free (a
+                # lookup allocates, and this window's commit would swallow
+                # an initialisation that was never staged)
+                if self.dynamic and max(len(self.cfg_upd),
+                                        len(self.resets)) >= self.Kg:
+                    return -1
+                slot, is_init = self.eng.gtable.lookup(key, self.now,
+                                                       duration)
+                self.slots[key] = slot
+                if is_init and self.dynamic:
+                    self.resets.append(slot)
+            if self.dynamic:
+                cfg = (limit, duration, algo)
+                if is_init or self.cfg_seen.get(slot) != cfg:
+                    if (slot not in self.cfg_upd
+                            and len(self.cfg_upd) >= self.Kg):
+                        return -1
+                    self.cfg_upd[slot] = cfg
+            s, lane = flat % self.SL, flat // self.SL
+            gb = self.gbatch
+            gb.slot[s, lane] = slot
+            gb.hits[s, lane] = hits
+            gb.limit[s, lane] = limit
+            gb.duration[s, lane] = duration
+            gb.algo[s, lane] = algo
+            gb.is_init[s, lane] = is_init
+            self.lanes[lk] = flat
+            self.acc.append(0)
+        self.acc[flat] += hits
+        self.items += 1
+        return flat
+
+    def control(self) -> tuple:
+        """(gbatch, gacc, upd) of the staged window, for the dispatch."""
+        n = len(self.acc)
+        if n:
+            flat = np.arange(n)
+            self.gacc[flat % self.SL, flat // self.SL] = self.acc
+        upd = self.upd
+        for j, (slot, cfg) in enumerate(self.cfg_upd.items()):
+            upd[0][j] = slot
+            upd[1][j], upd[2][j], upd[3][j] = cfg
+        for j, slot in enumerate(self.resets):
+            upd[4][j] = slot
+        return self.gbatch, self.gacc, upd
+
+    def committed(self) -> None:
+        """The window was dispatched: its allocations and configs hold."""
+        self.eng.gtable.commit_window()
+        self.cfg_seen.update(self.cfg_upd)
+
+
 class _DrainResult:
     __slots__ = ("words", "limits", "mism", "gfused", "stats", "stats_host",
                  "an_decay", "staged", "fallback",
@@ -440,7 +556,8 @@ class _DrainResult:
                  "fetch_start", "fetch_ready", "fetch_done", "completed_cb",
                  "committed",
                  "oldest_enq", "arena", "cols_owner", "cfut", "deferred",
-                 "arm", "lanes", "chain_fetch_start", "chain_fetch_done")
+                 "arm", "lanes", "chain_fetch_start", "chain_fetch_done",
+                 "n_raw", "n_global", "gdeferred", "glimit")
 
     def __init__(self):
         self.words = None
@@ -515,6 +632,15 @@ class _DrainResult:
         self.lanes = 0
         self.chain_fetch_start = 0.0
         self.chain_fetch_done = 0.0
+        # lockstep lane: decisions whole RPCs brought (the rest came per
+        # item), GLOBAL items the window holds, GLOBAL singles it had no
+        # lane for ((req, fut), back to the queue's front), and where whole
+        # RPCs brought GLOBAL items the request limits of the GLOBAL lanes
+        # ([S_local, Bg], for the stored-limit mismatch check)
+        self.n_raw = 0
+        self.n_global = 0
+        self.gdeferred = []
+        self.glimit = None
 
 
 class DispatchPipeline:
@@ -553,8 +679,8 @@ class DispatchPipeline:
         # drains dispatch only on the tick (lockstep_pump) with a fixed
         # stack shape, so every process issues the identical executable
         # sequence — and all serving shares the tick's cluster-agreed
-        # clock (one time base per arena).  The raw-RPC splicing lane
-        # stays off (mesh routes by shard, not by ring).
+        # clock (one time base per arena).  The raw-RPC lane stays off
+        # only where other hosts own some of the shards (rpc_enabled).
         self.lockstep = (engine.multiprocess if lockstep is None
                          else lockstep)
         if engine.multiprocess and not self.lockstep:
@@ -597,7 +723,9 @@ class DispatchPipeline:
         # it; the drain re-reads it on the ENGINE thread so a membership
         # change that races an in-flight RPC falls back instead of deciding
         # keys this node does not own.
-        self.rpc_enabled = self.enabled and not self.lockstep
+        # A mesh routes by shard, not by ring, so the lane also serves a
+        # lockstep engine whose shards are all this process's own.
+        self.rpc_enabled = self.enabled and not engine.multiprocess
         # always-on per-executable window clock (observability/devprof.py):
         # dispatch→fetch-ready wall time per drain, labelled by the arm the
         # census probe counts.  None (no metrics) keeps the commit path at
@@ -655,6 +783,17 @@ class DispatchPipeline:
         # commit (plain floats; the next commit flushes them)
         self._wake_seconds = 0.0
         self._wake_requests = 0
+        # lockstep lane (engine thread): the config this pipeline last
+        # staged for each GLOBAL slot (an unchanged one takes no update
+        # lane), and parsed GLOBAL items by their message bytes (a key's
+        # requests repeat byte for byte)
+        self._gcfg_seen: dict = {}
+        self._gparse: dict = {}
+        # ticks by what they did, GLOBAL items staged and deferred, and
+        # decisions by how they came (loop thread; /v1/admin/debug)
+        self.lockstep_ticks = dict.fromkeys(LOCKSTEP_TICK_KINDS, 0)
+        self.global_items = {"staged": 0, "deferred": 0}
+        self.lane_decisions = dict.fromkeys(LOCKSTEP_LANES, 0)
         self._singles: List[tuple] = []   # (req, fut, t_enq, ctx, col_idx)
         # GLOBAL singles (lockstep mode only): staged into the tick drain's
         # composed GLOBAL window, never mixed into regular ListJobs
@@ -1038,30 +1177,8 @@ class DispatchPipeline:
             depth = max(depth, stride + 1)
         if self._closed:
             return
-        if self._in_flight >= depth:
-            # full, with work behind it: held by depth.  Full with nothing
-            # queued is no hold at all.
-            self._note_hold("depth" if self._singles or self._jobs
-                            else None)
+        if self._held(depth):
             return
-        if self.gate_enabled and self._in_flight >= 1 and self.gate_frac > 0:
-            # occupancy gate: a drain is already hiding the device time, so
-            # hold the next dispatch until the pending work would fill
-            # ~gate_frac of one window's lanes.  Estimate lanes from queued
-            # decisions via the live duplicate-fold factor.  No timer
-            # needed: the in-flight drain's completion re-pumps, and at
-            # in_flight == 0 the gate is off — it can never strand work.
-            fold = (self.decisions_staged / self.lanes_staged
-                    if self.lanes_staged > MAX_BATCH_SIZE else 1.0)
-            pending = (len(self._singles)
-                       + sum(len(j.data) // 16 if isinstance(j, RpcJob)
-                             else j.n for j in self._jobs))
-            lanes_est = pending / max(fold, 1.0)
-            eng = self.engine
-            if lanes_est < (self.gate_frac * eng.batch_per_shard
-                            * eng.num_local_shards):
-                self._note_hold("gate" if pending else "empty")
-                return
         if not force and self.coalesce_wait > 0:
             # RpcJobs are unparsed here: estimate items from the wire size
             # (>= ~16B/item, so this overestimates — big RPCs never wait)
@@ -1095,6 +1212,36 @@ class DispatchPipeline:
                                          self._drain_sync, jobs, None, None,
                                          None, cols, time.monotonic())
         fut.add_done_callback(lambda f: self._on_dispatched(f, jobs))
+
+    def _held(self, depth: int) -> bool:
+        """Do the pipeline's depth or its occupancy gate hold the next
+        dispatch back?  Notes the reason when they do (loop thread)."""
+        if self._in_flight >= depth:
+            # full, with work behind it: held by depth.  Full with nothing
+            # queued is no hold at all.
+            self._note_hold("depth" if self._singles or self._jobs
+                            or self._gsingles else None)
+            return True
+        if self.gate_enabled and self._in_flight >= 1 and self.gate_frac > 0:
+            # occupancy gate: a drain is already hiding the device time, so
+            # hold the next dispatch until the pending work would fill
+            # ~gate_frac of one window's lanes.  Estimate lanes from queued
+            # decisions via the live duplicate-fold factor.  No timer
+            # needed: the in-flight drain's completion re-pumps (in
+            # lockstep the next tick asks again), and at in_flight == 0
+            # the gate is off — it can never strand work.
+            fold = (self.decisions_staged / self.lanes_staged
+                    if self.lanes_staged > MAX_BATCH_SIZE else 1.0)
+            pending = (len(self._singles) + len(self._gsingles)
+                       + sum(len(j.data) // 16 if isinstance(j, RpcJob)
+                             else j.n for j in self._jobs))
+            lanes_est = pending / max(fold, 1.0)
+            eng = self.engine
+            if lanes_est < (self.gate_frac * eng.batch_per_shard
+                            * eng.num_local_shards):
+                self._note_hold("gate" if pending else "empty")
+                return True
+        return False
 
     def _note_hold(self, reason: Optional[str]) -> None:
         """_pump returns without dispatching for `reason`, or dispatches
@@ -1281,22 +1428,17 @@ class DispatchPipeline:
         tick's drain (loop thread).  Invalid requests (unregistered GLOBAL
         key in non-dynamic mesh mode) fail individually here — mirroring
         the batcher's _take_window — so staging can never raise for them
-        on the engine thread.  Overflow beyond the drain's GLOBAL lane cap
-        rides the NEXT tick (pushed back to the queue front)."""
+        on the engine thread.  At most a window's GLOBAL lanes are taken
+        (equal requests share a lane, so all may well fit); what the
+        window then has no lane for comes back through res.gdeferred and
+        rides the NEXT tick, at the queue's front."""
         if not self._gsingles:
             return None
         eng = self.engine
         cap = eng.num_local_shards * eng.global_batch_per_shard
-        if eng._dynamic_global:
-            # dynamic mode stages a config-update lane per distinct key;
-            # bounding n by max_global_updates bounds distinct slots too
-            cap = min(cap, eng.max_global_updates)
-        items, self._gsingles = self._gsingles, []
+        items, self._gsingles = self._gsingles[:cap], self._gsingles[cap:]
         ok: List[tuple] = []
         for r, f in items:
-            if len(ok) >= cap:
-                self._gsingles.append((r, f))
-                continue
             err = eng.routing_error(r)
             if err is None:
                 ok.append((r, f))
@@ -1306,18 +1448,32 @@ class DispatchPipeline:
             return None
         return _GlobalJob([r for r, _ in ok], [f for _, f in ok])
 
-    def lockstep_pump(self, now: int, k_stack: int):
-        """Issue this tick's drain (mesh mode, event loop).  The dispatch
-        ALWAYS happens — the drain executable is slot 1 of the tick's
-        collective sequence on every process, staged lanes or not — and
-        runs on the single-thread engine executor, so the caller orders
-        the tick's legacy dispatch after it by submitting second.  Returns
-        the dispatch future: awaiting it surfaces an irrecoverable
-        dispatch failure (collective desync) for the batcher's fail-stop.
-        """
+    def lockstep_pump(self, now: int, k_stack: int, must: bool = True):
+        """This tick's drain (mesh mode, event loop).  `must`: other hosts
+        wait on this one's collectives, so the dispatch ALWAYS happens —
+        the drain executable is slot 1 of the tick's collective sequence
+        on every process, staged lanes or not.  A process that holds every
+        shard dispatches only what a standalone pump would: something is
+        queued, and neither the pipeline's depth nor its occupancy gate
+        holds it; otherwise the tick costs no device work and None comes
+        back.  The drain runs on the single-thread engine executor, so the
+        caller orders the tick's legacy dispatch after it by submitting
+        second.  Returns the dispatch future: awaiting it surfaces an
+        irrecoverable dispatch failure (collective desync) for the
+        batcher's fail-stop."""
         assert self.lockstep
         if self._loop is None:
             self._loop = asyncio.get_running_loop()
+        if not must:
+            if self._closed:
+                return None
+            if not (self._jobs or self._singles or self._gsingles):
+                self._note_hold("empty" if self._in_flight < self.depth
+                                else None)
+                return None
+            if self._held(self.depth):
+                return None
+            self._note_hold(None)
         jobs, cols = self._take_jobs() if not self._closed else ([], None)
         gjob = self._take_global_job() if not self._closed else None
         all_jobs = jobs + ([gjob] if gjob is not None else [])
@@ -1348,6 +1504,13 @@ class DispatchPipeline:
         # fallback jobs re-route outside the pipeline
         for job in res.fallback:
             self._route_fallback(job)
+        if res.gdeferred:
+            # GLOBAL singles this tick's window had no lane for: first in
+            # line for the next tick
+            self._gsingles[:0] = res.gdeferred
+            self.global_items["deferred"] += len(res.gdeferred)
+            if self.metrics is not None:
+                self.metrics.global_deferred.inc(len(res.gdeferred))
         # leftover jobs did not fit this stack: front of the queue.  A
         # leftover singles chunk borrows column views from THIS drain's
         # cols_owner, which is released at completion — materialize copies
@@ -1388,7 +1551,12 @@ class DispatchPipeline:
         # peers.go:143-172)
         mixed = [j for j in res.staged
                  if isinstance(j, RpcJob) and len(j.remote_idx)]
-        if mixed:
+        if mixed and self.lockstep:
+            # no ring in a mesh: these are GLOBAL items deferred to a
+            # later tick, answered through the singles' queue
+            for job in mixed:
+                self._spawn_global_deferred(job)
+        elif mixed:
             self._spawn_forwards(mixed, res.ring_peers)
         if res.deferred:
             # deferred-fetch chain: no fetch was submitted for this drain —
@@ -1488,6 +1656,40 @@ class DispatchPipeline:
                 self._spawn(
                     one_chunk(owner_idx, items[base:base + MAX_BATCH_SIZE]))
 
+    def _spawn_global_deferred(self, job: RpcJob) -> None:
+        """A whole RPC's GLOBAL items that this tick's window had no lane
+        for ride a later tick through the GLOBAL singles' queue; their
+        framed answers splice into the RPC's response like a forwarded
+        item's (_assemble_mixed), errors per item."""
+        from gubernator_tpu.api import pb
+
+        job.forward_task = self._loop.create_future()
+        n = len(job.gdefer)
+        self.global_items["deferred"] += n
+        if self.metrics is not None:
+            self.metrics.global_deferred.inc(n)
+
+        async def run():
+            frames = {}
+            try:
+                resps = await asyncio.gather(
+                    *(self.submit_one(r) for _, r in job.gdefer),
+                    return_exceptions=True)
+                for (i, _), r in zip(job.gdefer, resps):
+                    if not isinstance(r, RateLimitResp):
+                        r = RateLimitResp(error=str(r))
+                    frames[i] = _frame(pb.resp_to_pb(r).SerializeToString())
+            finally:
+                # nothing may escape without resolving the RPC's future
+                err = _frame(pb.RateLimitResp(
+                    error="pipeline closed").SerializeToString())
+                for i, _ in job.gdefer:
+                    frames.setdefault(i, err)
+                if not job.forward_task.done():
+                    job.forward_task.set_result(frames)
+
+        self._spawn(run())
+
     def _on_completed(self, fut, res: _DrainResult) -> None:
         res.completed_cb = time.monotonic()
         try:
@@ -1527,6 +1729,10 @@ class DispatchPipeline:
         # recycled for a future drain
         self._arena_ring.release(res.arena)
         res.arena = None
+        if self.lockstep:
+            self.lane_decisions["raw"] += res.n_raw
+            self.lane_decisions["item"] += res.n_decisions - res.n_raw
+            self.global_items["staged"] += res.n_global
         for job, out in zip(res.staged, outs):
             if isinstance(job, RpcJob):
                 self.rpc_served += 1
@@ -1601,6 +1807,11 @@ class DispatchPipeline:
             m.window_duration.observe(drain_wall)
             m.agg_decisions.inc(res.n_decisions)
             m.agg_lanes.inc(res.n_lanes)
+            if self.lockstep:
+                m.lockstep_decisions.labels(lane="raw").inc(res.n_raw)
+                m.lockstep_decisions.labels(lane="item").inc(
+                    res.n_decisions - res.n_raw)
+                m.global_decisions.inc(res.n_global)
             # stage-latency decomposition from the drain's boundary stamps
             # (0.0 boundary = never reached, e.g. an idle lockstep tick)
             if res.oldest_enq:
@@ -1821,6 +2032,14 @@ class DispatchPipeline:
         rpc_ok = self.rpc_enabled and eng._compact_enabled
         list_ok = (eng._compact_sound if self.lockstep
                    else eng._compact_enabled)
+        # the lockstep lane takes an RPC's GLOBAL items along: the parser
+        # marks them, and they fold into this drain's GLOBAL window (gst,
+        # made when the first GLOBAL request of the drain shows).  Their
+        # answers come back as rows after the K*S regular ones, at the
+        # regular lanes' width.
+        mark_global = (self.lockstep and eng._dynamic_global
+                       and eng.global_batch_per_shard <= B)
+        gst: Optional[_GlobalStage] = None
 
         with TraceAnnotation("guber_pack"):
             arena = self._arena_ring.acquire(K, S, B)
@@ -1845,10 +2064,19 @@ class DispatchPipeline:
                     job.limit, job.off, job.mlen = scr.limit, scr.off, scr.mlen
                     n = native.parse_stack_fast(
                         job.data, now, B, K, MAX_BATCH_SIZE, arena, scr,
-                        use_ring=not job.peer_mode)
+                        use_ring=not job.peer_mode, mark_global=mark_global)
                     if n >= 0:
                         job.n = n
                         job.remote_idx = np.flatnonzero(job.row[:n] < -1)
+                        if mark_global:
+                            gidx = np.flatnonzero(job.row[:n] == -1)
+                            if len(gidx):
+                                if gst is None:
+                                    gst = _GlobalStage(eng, now,
+                                                       self._gcfg_seen)
+                                self._stage_rpc_globals(job, gidx, gst,
+                                                        K * S)
+                                res.glimit = gst.gbatch.limit
                         res.staged.append(job)
                         if len(job.remote_idx):
                             # the forward coroutines keep reading off/mlen on
@@ -1899,49 +2127,47 @@ class DispatchPipeline:
         if self.lockstep:
             # Stage the tick's GLOBAL singles into the drain's composed
             # window (full wire format, round-robin over local shards —
-            # the psum is shard-agnostic, mirroring _stage_requests).
-            gbatch, gacc, upd = eng.empty_drain_control()
-            SL = eng.num_local_shards
+            # the psum is shard-agnostic, mirroring _stage_requests),
+            # beside the GLOBAL items whole RPCs brought.
             if gjob is not None:
-                eng.gtable.begin_window()
+                fresh = gst is None
                 try:
-                    gcfg_upd: dict = {}
-                    greset: List[int] = []
-                    gfill = np.zeros(SL, np.int32)
-                    for i, r in enumerate(gjob.reqs):
-                        slot, is_init = eng.gtable.lookup(
-                            r.hash_key(), now, r.duration)
-                        if eng._dynamic_global:
-                            gcfg_upd[slot] = (r.limit, r.duration,
-                                              r.algorithm)
-                            if is_init:
-                                greset.append(slot)
-                        s = i % SL
-                        lane = int(gfill[s])
-                        gfill[s] += 1
-                        gjob.shard[i] = s
-                        gjob.lane[i] = lane
-                        gbatch.slot[s, lane] = slot
-                        gbatch.hits[s, lane] = r.hits
-                        gbatch.limit[s, lane] = r.limit
-                        gbatch.duration[s, lane] = r.duration
-                        gbatch.algo[s, lane] = r.algorithm
-                        gbatch.is_init[s, lane] = is_init
-                        gacc[s, lane] = r.hits
-                    for j, (slot, cfg) in enumerate(gcfg_upd.items()):
-                        upd[0][j] = slot
-                        upd[1][j], upd[2][j], upd[3][j] = cfg
-                    for j, slot in enumerate(greset):
-                        upd[4][j] = slot
-                    res.staged.append(gjob)
+                    if fresh:
+                        gst = _GlobalStage(eng, now, self._gcfg_seen)
+                    reqs, futs, flats = [], [], []
+                    for r, f in zip(gjob.reqs, gjob.futs):
+                        flat = gst.add(r.hash_key(), r.hits, r.limit,
+                                       r.duration, int(r.algorithm))
+                        if flat < 0:
+                            res.gdeferred.append((r, f))
+                        else:
+                            reqs.append(r)
+                            futs.append(f)
+                            flats.append(flat)
+                    if reqs:
+                        held = _GlobalJob(reqs, futs)
+                        fl = np.asarray(flats, np.int32)
+                        held.shard[:] = fl % gst.SL
+                        held.lane[:] = fl // gst.SL
+                        res.staged.append(held)
                 except Exception:
                     # staging failed (arena full, ...): the fresh
                     # allocations stay pending (no commit) and the job
                     # re-routes through the legacy lane; the drain still
-                    # dispatches with inert GLOBAL padding
+                    # dispatches with inert GLOBAL padding.  Beside GLOBAL
+                    # items of whole RPCs, whose lanes cannot be taken
+                    # back, the drain fails as a whole instead.
+                    if not fresh:
+                        raise
                     res.fallback.append(gjob)
-                    gjob = None
-                    gbatch, gacc, upd = eng.empty_drain_control()
+                    res.gdeferred = []
+                    self._gcfg_seen.clear()  # the legacy lane configures
+                    gst = None
+            if gst is not None and gst.items:
+                gbatch, gacc, upd = gst.control()
+                res.n_global = gst.items
+            else:
+                gbatch, gacc, upd = eng.empty_drain_control()
             # the tick's drain dispatch is unconditional and fixed-shape:
             # every process issues it at the same sequence position.
             # Analytics (when wired) is COMPOSED into this same dispatch —
@@ -1968,8 +2194,8 @@ class DispatchPipeline:
                 # alone cannot distinguish 'dispatched 0 windows' from
                 # 'never dispatched' for the realign decision below
                 native.commit()
-                if gjob is not None:
-                    eng.gtable.commit_window()
+                if gst is not None:
+                    gst.committed()
             except Exception as e:
                 native.abort()
                 res.error = e  # _on_dispatched fails the staged jobs
@@ -2001,12 +2227,12 @@ class DispatchPipeline:
                 try:
                     words.copy_to_host_async()
                     mism.copy_to_host_async()
-                    if gjob is not None:
+                    if res.n_global:
                         gfused.copy_to_host_async()
                 except Exception:
                     pass  # fetch path will block instead
                 res.words, res.limits, res.mism = words, limits, mism
-                if gjob is not None:
+                if res.n_global:
                     res.gfused = gfused
             if an_args is not None:
                 # composed analytics: the stats row came out of the drain
@@ -2069,6 +2295,9 @@ class DispatchPipeline:
         # drain counts them)
         res.n_decisions = sum(
             j.n - len(getattr(j, "remote_idx", ())) for j in res.staged)
+        res.n_raw = sum(j.n - len(getattr(j, "remote_idx", ()))
+                        for j in res.staged
+                        if isinstance(j, (RpcJob, ColsJob)))
         # counted here, ON the engine thread — the legacy path's
         # engine.process increments the same attribute from this thread,
         # so updating it from the event loop would race (lost updates)
@@ -2096,6 +2325,70 @@ class DispatchPipeline:
                                   for j in res.staged):
             res.cfut = self._fetch_executor.submit(self._complete_sync, res)
         return res
+
+    def _stage_rpc_globals(self, job: RpcJob, gidx, gst: _GlobalStage,
+                           row0: int) -> None:
+        """Fold a whole RPC's marked GLOBAL items (out_row == -1) into the
+        drain's GLOBAL window (engine thread).  A staged item's (row, lane)
+        then point at the row its window's answers take behind the `row0`
+        regular ones (_with_global_rows), so the C encoder writes it like
+        any other; an item the window has no lane for keeps its mark and
+        is answered by a later tick (job.gdefer, _spawn_global_deferred)."""
+        from gubernator_tpu.api import pb
+
+        data, memo = job.data, self._gparse
+        ok_idx, ok_flat, defer = [], [], []
+        for i, o, ln in zip(gidx.tolist(), job.off[gidx].tolist(),
+                            job.mlen[gidx].tolist()):
+            raw = data[o:o + ln]
+            item = memo.get(raw)
+            if item is None:
+                m = pb.RateLimitReq.FromString(raw)
+                item = (m.name + "_" + m.unique_key, m.hits, m.limit,
+                        m.duration, int(m.algorithm))
+                if len(memo) >= 65536:
+                    memo.clear()
+                memo[raw] = item
+            flat = gst.add(*item)
+            if flat < 0:
+                defer.append((i, pb.req_from_pb(
+                    pb.RateLimitReq.FromString(raw))))
+            else:
+                ok_idx.append(i)
+                ok_flat.append(flat)
+        if ok_idx:
+            fl = np.asarray(ok_flat, np.int32)
+            job.row[ok_idx] = row0 + fl % gst.SL
+            job.lane[ok_idx] = fl // gst.SL
+        if defer:
+            job.gdefer = defer
+            job.remote_idx = np.asarray([i for i, _ in defer])
+
+    def _with_global_rows(self, res: _DrainResult, wflat, clflat,
+                          gflat) -> tuple:
+        """The fetched response words with the GLOBAL window's answers
+        ([S_local, Bg, 4] = status / limit / remaining / reset_time)
+        appended as S_local rows in the words' own format (ops/kernel.py
+        encode_output_word), so that a whole RPC's GLOBAL items encode
+        through the same C call as its regular ones.  The limits plane
+        follows only where a stored limit differs from the request's."""
+        SL, Bg = gflat.shape[:2]
+        lanes = wflat.shape[1]
+        st, lim, rem, rst = (gflat[..., c].astype(np.int64)
+                             for c in range(4))
+        enc = np.where(rst > 0, np.maximum(rst - res.now + 1, 1), 0)
+        ext = np.zeros((SL, lanes), np.int64)
+        ext[:, :Bg] = (enc << 32) | ((st & 1) << 31) | (rem & 0x7FFFFFFF)
+        used = res.glimit != 0
+        if clflat is None and bool((used & (lim != res.glimit)).any()):
+            clflat = np.ascontiguousarray(
+                self.engine._fetch_local_stacked(res.limits)
+            ).reshape(-1, lanes)
+        if clflat is not None:
+            ext_l = np.zeros((SL, lanes), np.int64)
+            ext_l[:, :Bg] = lim
+            clflat = np.concatenate([clflat, ext_l])
+        return np.concatenate([wflat, ext]), clflat
 
     def _drain_lanes(self, fills, k_used: int) -> int:
         """Lane width of this drain's executable: the narrowest lane
@@ -2246,6 +2539,9 @@ class DispatchPipeline:
             # this process's GLOBAL response rows [S_local, Bg, 4], indexed
             # exactly as the round-robin staging wrote (shard, lane)
             gflat = eng._fetch_local(res.gfused)
+            if res.glimit is not None:
+                wflat, clflat = self._with_global_rows(res, wflat, clflat,
+                                                       gflat)
         if res.stats is not None:
             # analytics stats ride the same fetch stage as the drain's own
             # outputs (their async copy started at dispatch)

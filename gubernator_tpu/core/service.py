@@ -140,11 +140,16 @@ class Instance:
         if self.mesh_mode:
             from gubernator_tpu.parallel.distributed import (
                 LockstepClock,
-                agree_epoch_ms,
+                agree_max,
             )
 
-            clock = LockstepClock(agree_epoch_ms(self.engine.mesh),
-                                  self.conf.behaviors.batch_wait)
+            # the epoch is taken at the first tick; several hosts agree it
+            # and every tick's index through one tiny collective
+            mesh_ = self.engine.mesh
+            clock = LockstepClock(
+                None, self.conf.behaviors.batch_wait,
+                agree=((lambda v: agree_max(mesh_, v))
+                       if self.engine.multiprocess else None))
         self.batcher = WindowBatcher(self.engine, self.conf.behaviors,
                                      self.metrics, lockstep_clock=clock,
                                      qos=self.qos, tracer=self.tracer,
@@ -263,9 +268,32 @@ class Instance:
         if len(requests) > MAX_BATCH_SIZE:
             raise BatchTooLargeError(
                 f"Requests.RateLimits list too large; max size is '{MAX_BATCH_SIZE}'")
+        if self.mesh_mode:
+            await self._register_first_seen(requests)
         return list(await asyncio.gather(
             *(self._route(r, deadline, client_id=client_id)
               for r in requests)))
+
+    async def _register_first_seen(self, requests) -> None:
+        """Register an RPC's first-seen GLOBAL keys mesh-wide in ONE
+        registration, before its items are routed: a fill of a thousand new
+        keys is then a handful of registrar round trips, which the default
+        GUBER_GLOBAL_TIMEOUT holds, instead of one a key behind one lock.
+        A failure is left to the items: each retries its own key in
+        _route_inner and reports the error in-band."""
+        specs = {}
+        for r in requests:
+            if (r.behavior == Behavior.GLOBAL and r.name and r.unique_key
+                    and r.algorithm in (Algorithm.TOKEN_BUCKET,
+                                        Algorithm.LEAKY_BUCKET)):
+                key = r.hash_key()
+                if key not in specs and not self.engine.global_ready(key):
+                    specs[key] = (key, r.limit, r.duration, int(r.algorithm))
+        if specs:
+            try:
+                await self._ensure_globals_registered(list(specs.values()))
+            except Exception as e:  # noqa: BLE001 — per-item error contract
+                log.debug("batched GLOBAL registration failed: %s", e)
 
     async def _route(self, r: RateLimitReq,
                      deadline: Optional[float] = None,
@@ -395,7 +423,8 @@ class Instance:
                     # through the registrar before serving (reference
                     # analog: GLOBAL keys accepted on first use,
                     # global.go:62-68)
-                    await self._ensure_global_registered(r)
+                    await self._ensure_globals_registered(
+                        [(key, r.limit, r.duration, int(r.algorithm))])
                 return await self.batcher.submit(r, deadline=deadline)
             except Exception as e:
                 # per-item failure (e.g. unregistered GLOBAL key failed
@@ -490,31 +519,41 @@ class Instance:
 
     # --------------------------------------------- dynamic mesh GLOBAL keys
 
-    async def _ensure_global_registered(self, r: RateLimitReq) -> None:
-        """Route a first-seen GLOBAL key's registration through the mesh
-        registrar (process 0) and wait until it is servable HERE.  In-flight
-        registrations for the same key coalesce into one RPC."""
-        key = r.hash_key()
-        fut = self._greg_inflight.get(key)
-        if fut is None:
-            fut = asyncio.get_running_loop().create_future()
-            self._greg_inflight[key] = fut
+    async def _ensure_globals_registered(self, specs) -> None:
+        """Route first-seen GLOBAL keys' registration — `specs` of (key,
+        limit, duration, algorithm), one registrar RPC for all of them —
+        through the mesh registrar (process 0) and wait until they are
+        servable HERE.  A key another caller is already registering is
+        waited for, not sent again."""
+        loop = asyncio.get_running_loop()
+        mine, waits = [], []
+        for spec in specs:
+            fut = self._greg_inflight.get(spec[0])
+            if fut is None:
+                self._greg_inflight[spec[0]] = loop.create_future()
+                mine.append(spec)
+            else:
+                waits.append(fut)
+        if mine:
+            err = None
             try:
                 registrar = self._picker.get_by_host(self.mesh_peers[0])
                 if registrar is None:
                     raise RuntimeError("mesh registrar peer is not connected")
-                await registrar.register_globals(
-                    [(key, r.limit, r.duration, int(r.algorithm))])
-                if not fut.done():
-                    fut.set_result(None)
+                await registrar.register_globals(mine)
             except Exception as e:
-                if not fut.done():
-                    fut.set_exception(e)
-                raise
-            finally:
-                self._greg_inflight.pop(key, None)
-            return
-        await fut
+                err = e
+            for spec in mine:
+                fut = self._greg_inflight.pop(spec[0])
+                if err is None:
+                    fut.set_result(None)
+                else:
+                    fut.set_exception(err)
+                    fut.exception()  # consumed: a key nobody waited for
+            if err is not None:
+                raise err
+        for fut in waits:
+            await fut
 
     async def register_globals(self, specs) -> None:
         """Registrar endpoint (runs on mesh process 0): totally order
@@ -530,7 +569,6 @@ class Instance:
                          if s[0] not in self._greg_done}.values())
             if not todo:
                 return
-            from gubernator_tpu.api.types import millisecond_now
             now = millisecond_now()
             peers = [self._picker.get_by_host(h) for h in self.mesh_peers]
             if any(p is None for p in peers):
@@ -543,6 +581,7 @@ class Instance:
             await asyncio.gather(*(
                 p.apply_global_registration(todo, now, True) for p in peers))
             self._greg_done.update(s[0] for s in todo)
+            self.metrics.global_register_batch.observe(len(todo))
 
     async def apply_global_registration(self, specs, now: int,
                                         activate: bool) -> None:
@@ -704,7 +743,10 @@ class Instance:
         import numpy as np
         loop = asyncio.get_running_loop()
         if self.mesh_mode:
-            pipe.rpc_enabled = False
+            # the raw-RPC lane routes by shard: it serves a mesh whose
+            # shards are all this process's own; with several hosts the
+            # full path forwards each item to the host that owns its shard
+            pipe.rpc_enabled = not self.engine.multiprocess
             return
         if self._picker.size() == 0:
             await loop.run_in_executor(

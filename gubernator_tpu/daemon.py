@@ -126,8 +126,10 @@ class Daemon:
         # cluster-agreed timestamp (all processes warm up in lockstep)
         if mesh_peers is not None:
             eng = self.instance.engine
-            eng.warmup(now=self.instance.batcher.clock.epoch_ms,
-                       k_stack=c.behaviors.lockstep_stack)
+            # one agreed reading of the clock for the warm-up and the
+            # preload (the epoch itself is taken at the first tick)
+            boot_ms = self.instance.batcher.clock.now_ms()
+            eng.warmup(now=boot_ms, k_stack=c.behaviors.lockstep_stack)
             gk_file = os.environ.get("GUBER_GLOBAL_KEYS_FILE", "")
             if gk_file:
                 import json
@@ -136,8 +138,7 @@ class Daemon:
                               d.get("algorithm", 0))
                              for d in (json.loads(ln) for ln in f
                                        if ln.strip())]
-                eng.register_global_keys(
-                    specs, now=self.instance.batcher.clock.epoch_ms)
+                eng.register_global_keys(specs, now=boot_ms)
                 log.info("registered %d GLOBAL keys", len(specs))
         else:
             self.instance.engine.warmup()

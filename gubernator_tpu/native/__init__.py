@@ -330,17 +330,20 @@ class NativeRouter:
 
     def parse_stack_fast(self, data: bytes, now: int, lanes: int,
                          K: int, max_items: int, arena, scr,
-                         use_ring: bool = True) -> int:
+                         use_ring: bool = True,
+                         mark_global: bool = False) -> int:
         """fastpath_parse_stack against a WindowArena + JobScratch
         (core/window_buffers.py): identical semantics, but every output
         pointer was derived once at buffer allocation instead of per call
         — the per-call ctypes pointer derivation is a measured fixed cost
-        on the drain's host-encode stage."""
+        on the drain's host-encode stage.  mark_global: token and leaky
+        GLOBAL items take no lane and come back as out_row == -1 with
+        their byte ranges, for the caller to stage (the lockstep lane)."""
         buf = ctypes.cast(ctypes.c_char_p(data),
                           ctypes.POINTER(ctypes.c_uint8))
         return self._lib.fastpath_parse_stack(
             self._handle, buf, len(data), now, lanes, K, max_items,
-            1 if use_ring else 0,
+            (1 if use_ring else 0) | (2 if mark_global else 0),
             arena.p_packed, arena.p_kcur, arena.p_fills,
             scr.p_row, scr.p_lane, scr.p_pos,
             scr.p_limit, scr.p_off, scr.p_mlen,

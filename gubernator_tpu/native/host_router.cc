@@ -783,6 +783,9 @@ namespace {
 constexpr int32_t MAX_STACK_ITEMS = 1024;  // > MAX_BATCH_SIZE (1000)
 constexpr int32_t MAX_STACK_SHARDS = 256;
 
+// ParsedItem.owner of a Behavior=GLOBAL item the host stages itself
+constexpr int32_t GLOBAL_ITEM = -2;
+
 struct ParsedItem {
   const uint8_t* name;
   int64_t name_len;
@@ -793,7 +796,8 @@ struct ParsedItem {
   int32_t shard;  // local shard index
   uint64_t fp;
   int64_t scratch_off;  // assembled hash_key offset (exact mode)
-  int32_t owner;        // ring peer index (-1 == local / no ring)
+  int32_t owner;        // ring peer index (-1 == local / no ring), or
+                        // GLOBAL_ITEM
   int64_t msg_off;      // serialized RateLimitReq body within the RPC bytes
   int32_t msg_len;
 };
@@ -1103,9 +1107,14 @@ inline int32_t ring_owner(const Router* r, uint32_t h) {
 //   -6  the RPC does not fit in this stack's remaining lanes (caller
 //       dispatches the stack and retries on a fresh one; -6 on a FRESH
 //       stack means the RPC can never fit and must take the full path)
-// use_ring == 0 treats every item as local even when a ring is installed:
-// the peer-plane lane (GetPeerRateLimits) is authoritative for whatever it
-// receives, like the reference owner (gubernator.go:210-227).
+// use_ring bit 0 clear treats every item as local even when a ring is
+// installed: the peer-plane lane (GetPeerRateLimits) is authoritative for
+// whatever it receives, like the reference owner (gubernator.go:210-227).
+// use_ring bit 1 (the lockstep lane of a process that holds every shard,
+// core/pipeline.py): a Behavior=GLOBAL item of a token or leaky limit in the
+// compact ranges does not send the RPC to the full path.  It takes no lane
+// here and comes back marked out_row[i] = -1 with its message's byte range
+// in out_off/out_mlen; the host stages it into the drain's GLOBAL window.
 int64_t fastpath_parse_stack(Router* r, const uint8_t* buf, int64_t len,
                              int64_t now, int32_t lanes, int32_t K,
                              int64_t max_items, int32_t use_ring,
@@ -1159,12 +1168,20 @@ int64_t fastpath_parse_stack(Router* r, const uint8_t* buf, int64_t len,
     p += mlen;
 
     if (it->name_len == 0 || it->key_len == 0) return -2;
-    if (behavior != 0) return -2;  // BATCHING only
+    bool global = behavior == 2 && (use_ring & 2) && it->algo <= 1;
+    if (behavior != 0 && !global) return -2;  // BATCHING, or marked GLOBAL
     // concurrency rides the python path: the host lease book needs
     // per-item visibility the bytes lane does not surface
     if (it->algo == 4) return -2;
     if (!compact_ranges_ok(it->hits, it->limit, it->duration, it->algo))
       return -2;
+    if (global) {
+      it->owner = GLOBAL_ITEM;  // parsed, staged by the host
+      bump[n] = 0;
+      item_shard[n] = -1;
+      n++;
+      continue;
+    }
 
     // hash key = name + "_" + unique_key (client.go:33-35), streamed
     uint8_t sep = '_';
@@ -1175,7 +1192,7 @@ int64_t fastpath_parse_stack(Router* r, const uint8_t* buf, int64_t len,
     uint32_t crc = c ^ 0xFFFFFFFFu;
 
     it->owner = -1;  // local
-    if (use_ring && r->ring_len > 0) {
+    if ((use_ring & 1) && r->ring_len > 0) {
       int32_t owner = ring_owner(r, crc);
       if (owner != r->ring_self) {
         it->owner = owner;  // forwarded: parsed but never staged
@@ -1216,8 +1233,9 @@ int64_t fastpath_parse_stack(Router* r, const uint8_t* buf, int64_t len,
   uint8_t* scratch = r->exact ? scratch_reserve(r, scratch_need) : nullptr;
   for (int64_t i = 0; i < n; i++) {
     ParsedItem* it = &items[i];
-    if (it->owner >= 0) {  // forwarded item: marker + message byte range
-      out_row[i] = -2 - it->owner;
+    if (it->owner >= 0 || it->owner == GLOBAL_ITEM) {
+      // forwarded or GLOBAL item: marker + message byte range
+      out_row[i] = it->owner == GLOBAL_ITEM ? -1 : -2 - it->owner;
       out_lane[i] = -1;
       out_pos[i] = -1;
       out_limit[i] = it->limit;

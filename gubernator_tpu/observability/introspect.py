@@ -228,6 +228,16 @@ def build_debug_snapshot(instance) -> dict:
             "pump_hold_seconds": pipe.pump_hold_snapshot(),
             "drain_widths": dict(pipe.drain_widths),
         }
+        if pipe.lockstep:
+            clock = instance.batcher.clock
+            out["pipeline"]["lockstep_state"] = {
+                "ticks": dict(pipe.lockstep_ticks),
+                "decisions_by_lane": dict(pipe.lane_decisions),
+                "global_items": dict(pipe.global_items),
+                "tick_index": clock.tick, "epoch_ms": clock.epoch_ms,
+                "interval_ms": clock.interval_ms,
+                "last_tick_lag_ms": clock.lag_s * 1000.0,
+            }
     analytics = getattr(instance, "analytics", None)
     if analytics is not None:
         snap = analytics.snapshot()

@@ -31,6 +31,7 @@ from prometheus_client import CONTENT_TYPE_LATEST
 # snapshot all use exactly these labels so dashboards, traces, and the
 # `cli debug` table line up column-for-column.
 STAGES = (
+    "tick_lag",         # lockstep: a tick's deadline -> the tick runs
     "enqueue",          # submit -> appended to the pending window
     "admission_wait",   # oldest request of a drain: queued -> drain started
     "engine_queue",     # loop hands the drain over -> engine thread starts it
@@ -59,6 +60,17 @@ REQUEST_STAGES = ("queue_wait", "in_drain", "reply_wake")
 # nothing queued, `gate` = the occupancy gate, `coalesce` = the batch-wait
 # timer, `depth` = work queued behind a full pipeline.
 PUMP_HOLD_REASONS = ("empty", "gate", "coalesce", "depth")
+
+# What a lockstep tick did (guber_tpu_lockstep_ticks_total): `drain` = it
+# dispatched staged work, `idle` = nothing was queued, `held` = work was
+# queued behind the pipeline's depth or its occupancy gate, `skipped` =
+# whole periods passed over because the host was behind its deadlines
+# (they are no ticks: nothing ran).  How a lockstep decision came
+# (guber_tpu_lockstep_decisions_total): `raw` = in a whole RPC staged by
+# the raw-RPC lane, `item` = per item through the pipeline's drain,
+# `legacy` = per item through the tick's legacy step.
+LOCKSTEP_TICK_KINDS = ("drain", "idle", "held", "skipped")
+LOCKSTEP_LANES = ("raw", "item", "legacy")
 
 # Lane width of a dispatched drain's executable (guber_tpu_drains_total):
 # `full` = batch_per_shard, `narrow` = any smaller lane bucket.  Two fixed
@@ -415,6 +427,39 @@ class Metrics:
             ["width"],
             registry=self.registry,
         )
+        self.lockstep_ticks = Counter(
+            "guber_tpu_lockstep_ticks_total",
+            "Lockstep ticks by what they did (drain | idle | held), and "
+            "the whole periods skipped when behind (skipped).",
+            ["kind"],
+            registry=self.registry,
+        )
+        self.lockstep_decisions = Counter(
+            "guber_tpu_lockstep_decisions_total",
+            "Decisions answered in lockstep, by how they came (raw = a "
+            "whole RPC through the raw-RPC lane | item | legacy).",
+            ["lane"],
+            registry=self.registry,
+        )
+        self.global_decisions = Counter(
+            "guber_tpu_global_decisions_total",
+            "GLOBAL decisions answered from a lockstep drain's composed "
+            "GLOBAL window.",
+            registry=self.registry,
+        )
+        self.global_deferred = Counter(
+            "guber_tpu_global_deferred_total",
+            "GLOBAL items a tick's window had no lane for; each rode a "
+            "later tick.",
+            registry=self.registry,
+        )
+        self.global_register_batch = Histogram(
+            "guber_tpu_global_register_batch_keys",
+            "First-seen GLOBAL keys per mesh registration (the registrar "
+            "side: one two-phase round for the whole batch).",
+            buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
+            registry=self.registry,
+        )
         # a labelled child that was never touched is absent from /metrics,
         # and a reader cannot tell absent from zero: make every child now
         for stage in STAGES:
@@ -426,6 +471,10 @@ class Metrics:
             self.pump_hold_seconds.labels(reason=reason)
         for width in DRAIN_WIDTHS:
             self.drains.labels(width=width)
+        for kind in LOCKSTEP_TICK_KINDS:
+            self.lockstep_ticks.labels(kind=kind)
+        for lane in LOCKSTEP_LANES:
+            self.lockstep_decisions.labels(lane=lane)
         # traffic analytics (ops/analytics.py device reduction +
         # observability/analytics.py host merge): hot keys, per-tenant
         # accounting, device-computed arena occupancy/churn

@@ -154,15 +154,17 @@ async def serve_get_rate_limits_inner(inst: Instance, data: bytes, context):
     # so the response carries shed_reason metadata in-band
     qos_saturated = (inst.qos is not None
                      and inst.qos.admission.saturated)
-    if (not inst.mesh_mode and not qos_saturated
-            and len(data) >= FASTPATH_MIN_BYTES):
+    if not qos_saturated and len(data) >= FASTPATH_MIN_BYTES:
         # native RPC lane: C parse -> stacked compact dispatch -> C
         # encode (core/pipeline.py).  In cluster mode the C parser
         # classifies items per key against the installed ring and
         # forwards non-owned items to their peers; the drain re-checks
         # the gate on the engine thread, so a membership change that
         # races this RPC falls back to the full path below instead of
-        # deciding keys this node does not own
+        # deciding keys this node does not own.  A mesh served by one
+        # process takes the same lane in its lockstep form: staged now,
+        # drained on the tick (submit_rpc answers None on a mesh of
+        # several hosts, which routes per item)
         out = await inst.batcher.submit_rpc(data)
         if out is not None:
             m.observe_rpc("/pb.gubernator.V1/GetRateLimits", start,

@@ -79,7 +79,7 @@ def _child(pid, coord_port, grpc0, grpc1, ctrl_port, stack=1):
             mesh=mesh,
             mesh_peers=addrs,
         )
-        epoch = inst.batcher.clock.epoch_ms
+        epoch = inst.batcher.clock.now_ms()
         inst.engine.warmup(now=epoch, k_stack=stack)
         inst.engine.register_global_keys(
             [("msrv_gbl_g", 100, 60_000, Algorithm.TOKEN_BUCKET)], now=epoch)

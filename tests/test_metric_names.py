@@ -12,6 +12,8 @@ import time
 import pytest
 
 from gubernator_tpu.observability.metrics import (DRAIN_WIDTHS,
+                                                  LOCKSTEP_LANES,
+                                                  LOCKSTEP_TICK_KINDS,
                                                   PUMP_HOLD_REASONS,
                                                   REQUEST_STAGES, STAGES,
                                                   Metrics)
@@ -177,7 +179,7 @@ def test_stage_labels_are_canonical():
         assert m.registry.get_sample_value(
             "guber_tpu_stage_duration_ms_count", {"stage": stage}) == 1.0
     assert STAGES == (
-        "enqueue", "admission_wait", "engine_queue", "window_fill",
+        "tick_lag", "enqueue", "admission_wait", "engine_queue", "window_fill",
         "device_dispatch", "dispatch_hop", "fetch_queue", "drain_commit",
         "device_wait", "decode", "complete_hop", "commit", "peer_forward",
         "global_broadcast")
@@ -210,6 +212,8 @@ def test_removed_series_stay_removed(name):
     ("guber_tpu_request_stage_requests_total", "stage", REQUEST_STAGES),
     ("guber_tpu_pump_hold_seconds_total", "reason", PUMP_HOLD_REASONS),
     ("guber_tpu_drains_total", "width", DRAIN_WIDTHS),
+    ("guber_tpu_lockstep_ticks_total", "kind", LOCKSTEP_TICK_KINDS),
+    ("guber_tpu_lockstep_decisions_total", "lane", LOCKSTEP_LANES),
 ])
 def test_labelled_children_exist_at_zero(series, label, values):
     """A child that was never incremented is absent from /metrics, and a

@@ -13,6 +13,7 @@ Python (EngineConfig use_native=False) because migration needs key strings.
 """
 
 import asyncio
+import socket
 
 import pytest
 
@@ -91,9 +92,55 @@ def _slot_of(cluster, address, full_key):
     return eng.tables[shard_of(full_key, eng.num_shards)].peek(full_key)
 
 
+def _ring_of(cluster, addresses):
+    """A scratch ring over `addresses`: address that owns a hash key."""
+    ring = cluster.nodes[0].instance._picker.new()
+    for a in addresses:
+        ring.add(a, a)
+    return ring.get
+
+
+def _joining_address(cluster):
+    """An address for the node that joins, bound to nothing yet, whose arc
+    of the ring is neither a sliver nor most of it.  The ring has one point
+    a host (crc32 of its address), so an arbitrary port may leave the
+    joining node next to nothing; which keys re-home must not hang on
+    that."""
+    for _ in range(200):
+        with socket.socket() as sock:
+            sock.bind(("127.0.0.1", 0))
+            address = f"127.0.0.1:{sock.getsockname()[1]}"
+        owner = _ring_of(cluster, cluster.addresses + [address])
+        share = sum(owner(f"mig_probe:{i}") == address
+                    for i in range(400)) / 400
+        if 0.1 <= share <= 0.6:
+            return address
+    raise AssertionError("no port gave the joining node a fair arc")
+
+
+def _pick_keys(prefix, n, n_moving, owner_after, joining):
+    """`n` keys of which exactly `n_moving` re-home to `joining`."""
+    moving, staying, i = [], [], 0
+    while len(moving) < n_moving or len(staying) < n - n_moving:
+        key = f"{prefix}:{i}"
+        i += 1
+        if owner_after(f"mig_{key}") == joining:
+            if len(moving) < n_moving:
+                moving.append(key)
+        elif len(staying) < n - n_moving:
+            staying.append(key)
+    return sorted(moving + staying)
+
+
 def test_ring_grow_migrates_only_rehomed_keys(cluster, loop):
-    keys = [f"acct:{i}" for i in range(N_KEYS)]
-    gkeys = [f"gacct:{i}" for i in range(N_GLOBAL)]
+    # the membership after the join is fixed before a key is chosen, so
+    # that a quarter of the keys of either kind re-homes whatever ports
+    # the run drew
+    joining = _joining_address(cluster)
+    owner_after = _ring_of(cluster, cluster.addresses + [joining])
+    keys = _pick_keys("acct", N_KEYS, N_KEYS // 4, owner_after, joining)
+    gkeys = _pick_keys("gacct", N_GLOBAL, N_GLOBAL // 4, owner_after,
+                       joining)
     full = {k: f"mig_{k}" for k in keys}
     gfull = {k: f"mig_{k}" for k in gkeys}
 
@@ -141,14 +188,14 @@ def test_ring_grow_migrates_only_rehomed_keys(cluster, loop):
             elif row[1] == best:
                 cands.add(row)
 
-    added = run(loop, cluster.add_instance())
-    assert len(cluster.addresses) == 4
+    added = run(loop, cluster.add_instance(joining))
+    assert len(cluster.addresses) == 4 and added.address == joining
 
     owners_after = _owners(cluster, list(full.values()))
     moved = [k for k in keys if owners_after[full[k]] != owners_before[full[k]]]
     kept = [k for k in keys if k not in moved]
     # consistent hashing re-homes ~1/4 of the space: some but never all
-    assert 0 < len(moved) < N_KEYS
+    assert len(moved) == N_KEYS // 4
     # a joining node only GAINS keys: everything that moved, moved to it
     assert all(owners_after[full[k]] == added.address for k in moved)
 
@@ -185,7 +232,7 @@ def test_ring_grow_migrates_only_rehomed_keys(cluster, loop):
     # move what exists, not to finish the sync protocol.
     gmoved = [k for k in gkeys
               if _owners(cluster, [gfull[k]])[gfull[k]] == added.address]
-    assert gmoved, "no GLOBAL key re-homed; widen N_GLOBAL"
+    assert len(gmoved) == N_GLOBAL // 4, gmoved
     new_gkeys = set(added.instance.engine.global_keys())
     for k in gmoved:
         assert gfull[k] in new_gkeys, \
