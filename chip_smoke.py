@@ -442,12 +442,14 @@ def phase_mesh(jax, sz, seed, n_chips):
                           batch_per_shard=sz.B, use_native="on")
     eng.warmup(now=T0, k_stack=K)
     compile_s = time.perf_counter() - t
-    homes = {s.device for s in eng.state.limit.addressable_shards}
-    shapes = {s.data.shape for s in eng.state.limit.addressable_shards}
+    # eleven resident planes (ten uint32 halves and algo), each sharded alike
+    blocks = [s for plane in eng.state for s in plane.addressable_shards]
+    homes = {s.device for s in blocks}
+    shapes = {s.data.shape for s in blocks}
     require(len(homes) == n_chips and homes == set(devs), homes)
     require(shapes == {(1, sz.C)}, shapes)
     say(f"[mesh] arena {n_chips} x {sz.C:,} slots: "
-        f"{len(eng.state.limit.addressable_shards)} blocks of {shapes} on "
+        f"{len(blocks)} blocks of {shapes} on "
         f"{sorted(str(d) for d in homes)}")
 
     # the compiled lockstep drain holds the reconciliation all-reduce

@@ -15,9 +15,11 @@ to the owner (peers.go:176-207): the host packs per-shard request lanes into
 dense arrays, the device applies them in a single jitted shard_map step, and
 the responses demux back by lane index.
 
-State layout: regular (sharded) keys live in BucketState arrays of shape
-[S, C] partitioned over the "shard" mesh axis; GLOBAL keys live in a
-replicated [G] arena whose updates flow only through the psum so replicas stay
+State layout: regular (sharded) keys live in ArenaPlanes arrays of shape
+[S, C] partitioned over the "shard" mesh axis (each int64 column as a
+(lo, hi) pair of uint32 planes: the executables' parameters hold no int64
+of the arena's size); GLOBAL keys live in a replicated [G] int64
+BucketState whose updates flow only through the psum so replicas stay
 bit-exact.  Host-side key→slot tables (state/arena.py) are per shard.
 """
 
@@ -43,6 +45,7 @@ from gubernator_tpu.api.types import (
 )
 from gubernator_tpu.ops import kernel
 from gubernator_tpu.ops.kernel import (
+    ArenaPlanes,
     BucketState,
     GlobalConfig,
     WindowBatch,
@@ -209,14 +212,10 @@ class RateLimitEngine:
             return jax.jit(lambda: jnp.zeros(shape, dtype),
                            out_shardings=sharding)()
 
-        self.state = BucketState(
-            limit=sharded_zeros((S, C), jnp.int64, shard_sharding),
-            duration=sharded_zeros((S, C), jnp.int64, shard_sharding),
-            remaining=sharded_zeros((S, C), jnp.int64, shard_sharding),
-            tstamp=sharded_zeros((S, C), jnp.int64, shard_sharding),
-            expire=sharded_zeros((S, C), jnp.int64, shard_sharding),
-            algo=sharded_zeros((S, C), jnp.int32, shard_sharding),
-        )
+        self.state = ArenaPlanes(
+            *[sharded_zeros((S, C), jnp.uint32, shard_sharding)
+              for _ in ArenaPlanes._fields[:-1]],
+            algo=sharded_zeros((S, C), jnp.int32, shard_sharding))
         self.gstate = BucketState(
             limit=sharded_zeros((G,), jnp.int64, repl_sharding),
             duration=sharded_zeros((G,), jnp.int64, repl_sharding),
@@ -1688,9 +1687,9 @@ class RateLimitEngine:
         # guber_analytics: devprof classification anchor — the standalone
         # reduction's kernels attribute to the analytics arm, not the drain
         with jax.profiler.TraceAnnotation("guber_analytics"):
-            self._an_sketch, stats = fn(self._an_sketch, self.state.expire,
-                                        packed, words, tenants, now_in,
-                                        decay_in)
+            self._an_sketch, stats = fn(
+                self._an_sketch, self.state.expire_lo, self.state.expire_hi,
+                packed, words, tenants, now_in, decay_in)
         return stats
 
     def process(
@@ -1882,8 +1881,8 @@ class RateLimitEngine:
         represent the data exactly, so the choice is never lossy."""
         from gubernator_tpu.state.snapshot import ArenaSnapshot, SnapshotError
         now = self._resolve_now(now)
-        planes = {n: np.asarray(self._fetch_local(getattr(self.state, n)))
-                  for n in BucketState._fields}
+        planes = kernel.arena_to_rows(ArenaPlanes(
+            *[np.asarray(self._fetch_local(p)) for p in self.state]))._asdict()
         gplanes = {n: np.asarray(jax.device_get(getattr(self.gstate, n)))
                    for n in BucketState._fields}
         gcfg = {n: np.asarray(jax.device_get(getattr(self.gcfg, n)))
@@ -1974,14 +1973,11 @@ class RateLimitEngine:
             return out
 
         rp, gp = shifted(snap.planes), shifted(snap.gplanes)
-        self.state = BucketState(
-            limit=self._put_sharded(rp["limit"], np.int64),
-            duration=self._put_sharded(rp["duration"], np.int64),
-            remaining=self._put_sharded(rp["remaining"], np.int64),
-            tstamp=self._put_sharded(rp["tstamp"], np.int64),
-            expire=self._put_sharded(rp["expire"], np.int64),
-            algo=self._put_sharded(rp["algo"], np.int32),
-        )
+        planes = kernel.arena_from_rows(
+            BucketState(**{f: rp[f] for f in BucketState._fields}))
+        self.state = ArenaPlanes(
+            *[self._put_sharded(p, np.uint32) for p in planes[:-1]],
+            algo=self._put_sharded(planes.algo, np.int32))
         self.gstate = BucketState(
             limit=self._put_repl(gp["limit"], np.int64),
             duration=self._put_repl(gp["duration"], np.int64),
@@ -2483,17 +2479,20 @@ def _fnv1a64(data: bytes) -> int:
 
 
 @jax.jit
-def _gather_rows_jit(state: BucketState, si, li) -> BucketState:
-    # OOB padded indices read as 0 (mode="fill"); callers slice them off
-    return jax.tree.map(
-        lambda a: a.at[si, li].get(mode="fill", fill_value=0), state)
+def _gather_rows_jit(state: ArenaPlanes, si, li) -> BucketState:
+    # the resident planes' rows as int64 rows; OOB padded indices read as 0
+    # (mode="fill"); callers slice them off
+    return kernel.arena_to_rows(jax.tree.map(
+        lambda a: a.at[si, li].get(mode="fill", fill_value=0), state))
 
 
 @jax.jit
-def _scatter_rows_jit(state: BucketState, si, li, vals) -> BucketState:
+def _scatter_rows_jit(state: ArenaPlanes, si, li,
+                      vals: BucketState) -> ArenaPlanes:
+    vals = kernel.arena_from_rows(vals._replace(
+        algo=vals.algo.astype(jnp.int32)))
     return jax.tree.map(
-        lambda a, v: a.at[si, li].set(v.astype(a.dtype), mode="drop"),
-        state, vals)
+        lambda a, v: a.at[si, li].set(v, mode="drop"), state, vals)
 
 
 @jax.jit
@@ -2671,6 +2670,12 @@ def _mesh_on_cpu(mesh: Mesh) -> bool:
     return mesh.devices.flat[0].platform == "cpu"
 
 
+# shard_map specs of the two resident states: the sharded arena's planes,
+# and the replicated GLOBAL table's int64 rows
+_ARENA_SHARDED = ArenaPlanes(*[P(SHARD_AXIS)] * len(ArenaPlanes._fields))
+_GSTATE_REPL = BucketState(*[P()] * len(BucketState._fields))
+
+
 def _apply_control(gstate: BucketState, gcfg: GlobalConfig, upd, ups):
     """Apply host control-plane writes to the GLOBAL arena (once per dispatch).
 
@@ -2778,7 +2783,7 @@ def _compiled_step_impl(mesh: Mesh, pallas: bool):
     def shard_fn(state, gstate, gcfg, batch, gbatch, gacc, upd, ups, now):
             # Block shapes inside shard_map: state [1, C]; batch/gbatch [1, B*];
             # gstate/gcfg [G] (replicated); upd/ups [K*] (replicated).
-            st = BucketState(*jax.tree.map(lambda a: a[0], state))
+            st = jax.tree.map(lambda a: a[0], state)
             bt = WindowBatch(*jax.tree.map(lambda a: a[0], batch))
             new_st, out = _window_step_fn(mesh, compact32=False, pallas=pallas,
                                       c32xla=False)(st, bt, now)
@@ -2789,14 +2794,12 @@ def _compiled_step_impl(mesh: Mesh, pallas: bool):
 
             expand = lambda a: a[None]
             return (
-                BucketState(*jax.tree.map(expand, new_st)),
+                jax.tree.map(expand, new_st),
                 kernel.pack_outputs(out, gout)[None],
                 new_g,
                 gcfg,
             )
 
-    state_sharded = BucketState(*[P(SHARD_AXIS)] * 6)
-    state_repl = BucketState(*[P()] * 6)
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
@@ -2805,8 +2808,8 @@ def _compiled_step_impl(mesh: Mesh, pallas: bool):
         # an XLA-path-only invariant here
         check_vma=not pallas,
         in_specs=(
-            state_sharded,
-            state_repl,
+            _ARENA_SHARDED,
+            _GSTATE_REPL,
             GlobalConfig(*[P()] * 3),
             WindowBatch(*[P(SHARD_AXIS)] * 6),
             WindowBatch(*[P(SHARD_AXIS)] * 6),
@@ -2816,9 +2819,9 @@ def _compiled_step_impl(mesh: Mesh, pallas: bool):
             P(),
         ),
         out_specs=(
-            state_sharded,
+            _ARENA_SHARDED,
             P(SHARD_AXIS),
-            state_repl,
+            _GSTATE_REPL,
             GlobalConfig(*[P()] * 3),
         ),
     )
@@ -2845,7 +2848,7 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
     host's range checks, so they are exempt from compact saturation rules.
     """
     def shard_fn(state, gstate, gcfg, packed, gbatch, gacc, upd, ups, now):
-        st = BucketState(*jax.tree.map(lambda a: a[0], state))
+        st = jax.tree.map(lambda a: a[0], state)
         # The fused megakernel's in-kernel bitonic sort needs a power-of-two
         # lane count; other widths fall back to the compact32-XLA drain at
         # trace time (B is static).
@@ -2871,15 +2874,13 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
             [gout.status.astype(jnp.int64), gout.limit, gout.remaining,
              gout.reset_time], axis=-1)
         return (
-            BucketState(*jax.tree.map(expand, new_st)),
+            jax.tree.map(expand, new_st),
             enc[None],
             gfused[None],
             new_g,
             gcfg,
         )
 
-    state_sharded = BucketState(*[P(SHARD_AXIS)] * 6)
-    state_repl = BucketState(*[P()] * 6)
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
@@ -2888,8 +2889,8 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
         # an XLA-path-only invariant here
         check_vma=not (pallas or fused),
         in_specs=(
-            state_sharded,
-            state_repl,
+            _ARENA_SHARDED,
+            _GSTATE_REPL,
             GlobalConfig(*[P()] * 3),
             P(SHARD_AXIS),
             WindowBatch(*[P(SHARD_AXIS)] * 6),
@@ -2899,10 +2900,10 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
             P(),
         ),
         out_specs=(
-            state_sharded,
+            _ARENA_SHARDED,
             P(SHARD_AXIS),
             P(SHARD_AXIS),
-            state_repl,
+            _GSTATE_REPL,
             GlobalConfig(*[P()] * 3),
         ),
     )
@@ -2972,19 +2973,17 @@ def _compiled_pipeline_step_impl(mesh: Mesh, pallas: bool,
     """
     def shard_fn(state, packed, nows):
         # Block shapes: state [1, C]; packed [K, 1, B, 2]; nows [K].
-        st = BucketState(*jax.tree.map(lambda a: lax.squeeze(a, (0,)),
-                                       state))
+        st = jax.tree.map(lambda a: lax.squeeze(a, (0,)), state)
         st, words, limits, mism, _ = _drain_scan(mesh, pallas, c32xla, fused,
                                                  staged, st, packed, nows)
         expand = lambda a: a[None]
         return (
-            BucketState(*jax.tree.map(expand, st)),
+            jax.tree.map(expand, st),
             words[:, None],
             limits[:, None],
             mism[:, None],
         )
 
-    state_sharded = BucketState(*[P(SHARD_AXIS)] * 6)
     stackedP = stacked_spec()
     sharded = jax.shard_map(
         shard_fn,
@@ -2993,8 +2992,8 @@ def _compiled_pipeline_step_impl(mesh: Mesh, pallas: bool,
         # interpret-mode while_loop (jnp.take drops them); vma checking is
         # an XLA-path-only invariant here
         check_vma=not (pallas or fused),
-        in_specs=(state_sharded, stackedP, P()),
-        out_specs=(state_sharded, stackedP, stackedP, stackedP),
+        in_specs=(_ARENA_SHARDED, stackedP, P()),
+        out_specs=(_ARENA_SHARDED, stackedP, stackedP, stackedP),
     )
     fn = jax.jit(sharded, donate_argnums=(0,))
     return _recursion_guarded(fn) if (pallas or fused) else fn
@@ -3008,7 +3007,7 @@ def _staged_active(fused: bool, staged: bool, B: int) -> bool:
 
 
 def _drain_scan(mesh: Mesh, pallas: bool, c32xla: bool, fused: bool,
-                staged: bool, st: BucketState, packed, nows,
+                staged: bool, st: ArenaPlanes, packed, nows,
                 tenants=None, tenant_slots: int = 0):
     """The drain's regular-key K windows (shared by the regular and the
     GLOBAL-composed drain executables): K compact windows applied
@@ -3038,7 +3037,8 @@ def _drain_scan(mesh: Mesh, pallas: bool, c32xla: bool, fused: bool,
             fused_state_to_planes(st), lax.squeeze(packed, (1,)), nows,
             interpret=_mesh_on_cpu(mesh),
             tenants=tenants, tenant_slots=tenant_slots)
-        return fused_state_from_planes(st32), words, limits, mism, dstats
+        return (fused_state_from_planes(st32, st), words, limits, mism,
+                dstats)
 
     def body(st, xs):
         pk, now = xs
@@ -3071,7 +3071,7 @@ def _drain_scan(mesh: Mesh, pallas: bool, c32xla: bool, fused: bool,
 
         st32, (words, limits, mism) = lax.scan(
             body32, fused_state_to_planes(st), (packed, nows))
-        st = fused_state_from_planes(st32)
+        st = fused_state_from_planes(st32, st)
     else:
         st, (words, limits, mism) = lax.scan(body, st, (packed, nows))
     return st, words, limits, mism, None
@@ -3090,11 +3090,13 @@ def _compiled_analytics_reduce(mesh: Mesh, depth: int, width: int,
     mesh) and leaves their jaxprs byte-identical when analytics is off."""
     from gubernator_tpu.ops import analytics as ops_analytics
 
-    def shard_fn(sketch, expire, packed, words, tenants, now, decay):
-        # Block shapes: sketch [1, D, W]; expire [1, C]; packed
+    def shard_fn(sketch, exp_lo, exp_hi, packed, words, tenants, now, decay):
+        # Block shapes: sketch [1, D, W]; exp_lo/exp_hi [1, C] (the arena's
+        # expiry planes, joined here: this reduction reads all C); packed
         # [K, 1, B, 2]; words [K, 1, B]; tenants [K, 1, B]; now/decay [].
         sk, stats = ops_analytics.shard_stats(
-            sketch[0], packed[:, 0], words[:, 0], tenants[:, 0], expire[0],
+            sketch[0], packed[:, 0], words[:, 0], tenants[:, 0],
+            kernel.join64(exp_lo[0], exp_hi[0]),
             now, decay, tenant_slots=tenant_slots, topk=topk,
             over_weight=over_weight)
         return sk[None], stats[None]
@@ -3102,8 +3104,8 @@ def _compiled_analytics_reduce(mesh: Mesh, depth: int, width: int,
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), stacked_spec(),
-                  stacked_spec(), stacked_spec(), P(), P()),
+        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS),
+                  stacked_spec(), stacked_spec(), stacked_spec(), P(), P()),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
     )
     return jax.jit(sharded, donate_argnums=(0,))
@@ -3164,7 +3166,7 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
         # block-unpack glue is most of what remains around the kernels.
         sq = lambda a: lax.squeeze(a, (0,))
         sq1 = lambda a: lax.squeeze(a, (1,))
-        st = BucketState(*jax.tree.map(sq, state))
+        st = jax.tree.map(sq, state)
         # With staged analytics the drain kernel itself accumulates the
         # dense/tenant/header sums (dstats) while it drains — the stats
         # tail below then only runs the one-kernel sketch/top-k finish.
@@ -3187,7 +3189,7 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
 
         expand = lambda a: a[None]
         outs = (
-            BucketState(*jax.tree.map(expand, st)),
+            jax.tree.map(expand, st),
             words[:, None],
             limits[:, None],
             mism[:, None],
@@ -3198,30 +3200,30 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
         if analytics is not None:
             _, _, tenant_slots, topk, over_weight = analytics
             sketch, tenants, decay = an
+            # the occupancy counts read all C expiries: joined here only
+            expire = kernel.join64(st.expire_lo, st.expire_hi)
             if dstats is not None:
                 from gubernator_tpu.ops.pallas_kernel import (
                     staged_stats_finish,
                 )
                 sk, stats = staged_stats_finish(
-                    sq(sketch), dstats, st.expire, nows[0], decay,
+                    sq(sketch), dstats, expire, nows[0], decay,
                     tenant_slots=tenant_slots, topk=topk,
                     over_weight=over_weight,
                     interpret=_mesh_on_cpu(mesh))
             else:
                 from gubernator_tpu.ops import analytics as ops_analytics
                 sk, stats = ops_analytics.shard_stats(
-                    sq(sketch), sq1(packed), words, sq1(tenants), st.expire,
+                    sq(sketch), sq1(packed), words, sq1(tenants), expire,
                     nows[0], decay, tenant_slots=tenant_slots, topk=topk,
                     over_weight=over_weight)
             outs = outs + (sk[None], stats[None])
         return outs
 
-    state_sharded = BucketState(*[P(SHARD_AXIS)] * 6)
-    state_repl = BucketState(*[P()] * 6)
     stackedP = stacked_spec()
     in_specs = (
-        state_sharded,
-        state_repl,
+        _ARENA_SHARDED,
+        _GSTATE_REPL,
         GlobalConfig(*[P()] * 3),
         stackedP,
         WindowBatch(*[shard_spec()] * 6),
@@ -3230,12 +3232,12 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
         P(),
     )
     out_specs = (
-        state_sharded,
+        _ARENA_SHARDED,
         stackedP,
         stackedP,
         stackedP,
         shard_spec(),
-        state_repl,
+        _GSTATE_REPL,
         GlobalConfig(*[P()] * 3),
     )
     donate = (0, 1, 2)
@@ -3293,7 +3295,7 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
     def shard_fn(state, gstate, gcfg, batches, gbatches, gaccs, upd, ups, nows):
         # Block shapes: state [1, C]; batches [K, 1, B]; gbatches [K, 1, Bg];
         # gaccs [K, 1, Bg]; gstate/gcfg [G] replicated; nows [K].
-        st = BucketState(*jax.tree.map(lambda a: a[0], state))
+        st = jax.tree.map(lambda a: a[0], state)
         if with_global:
             gstate, gcfg = _apply_control(gstate, gcfg, upd, ups)
 
@@ -3320,14 +3322,12 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
         expand = lambda a: a[None]
         # fused: [K, B+Bg, 4] -> [K, 1, B+Bg, 4] so the shard axis is explicit
         return (
-            BucketState(*jax.tree.map(expand, st)),
+            jax.tree.map(expand, st),
             fused[:, None],
             gst,
             gcfg,
         )
 
-    state_sharded = BucketState(*[P(SHARD_AXIS)] * 6)
-    state_repl = BucketState(*[P()] * 6)
     stackedP = P(None, SHARD_AXIS)
     sharded = jax.shard_map(
         shard_fn,
@@ -3337,8 +3337,8 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
         # an XLA-path-only invariant here
         check_vma=not pallas,
         in_specs=(
-            state_sharded,
-            state_repl,
+            _ARENA_SHARDED,
+            _GSTATE_REPL,
             GlobalConfig(*[P()] * 3),
             WindowBatch(*[stackedP] * 6),
             WindowBatch(*[stackedP] * 6),
@@ -3348,9 +3348,9 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
             P(),
         ),
         out_specs=(
-            state_sharded,
+            _ARENA_SHARDED,
             stackedP,
-            state_repl,
+            _GSTATE_REPL,
             GlobalConfig(*[P()] * 3),
         ),
     )

@@ -526,7 +526,10 @@ def build_census_arms(k: int = 8):
                           global_batch_per_shard=8, max_global_updates=8)
     s, b = eng.num_shards, eng.batch_per_shard
 
+    # the oracle steps int64 rows; the serving arms step the arena's
+    # resident uint32 planes, as the engine's executables do
     st1 = kernel.BucketState.zeros(eng.capacity_per_shard)
+    arena1 = kernel.ArenaPlanes.zeros(eng.capacity_per_shard)
     packed1 = jnp.zeros((b, 2), jnp.int64)
 
     def xla64(state, packed, now):
@@ -573,6 +576,7 @@ def build_census_arms(k: int = 8):
     packed_mix = np.broadcast_to(mix1, (k, s, b, 2)).copy()
 
     one = (st1, packed1, jnp.int64(t0))
+    one_arena = (arena1, packed1, jnp.int64(t0))
     drain_args = (eng.state, eng.gstate, eng.gcfg, packed, gb, ga, upd, nows)
     mix_args = (eng.state, eng.gstate, eng.gcfg, packed_mix, gb, ga, upd,
                 nows)
@@ -580,10 +584,10 @@ def build_census_arms(k: int = 8):
     return [
         {"name": "int64_xla", "fn": xla64, "args": one, "windows": 1,
          "measure_fn": xla64},
-        {"name": "compact32_xla", "fn": c32, "args": one, "windows": 1,
+        {"name": "compact32_xla", "fn": c32, "args": one_arena, "windows": 1,
          "measure_fn": c32},
-        {"name": "fused_window", "fn": fusedw, "args": one, "windows": 1,
-         "measure_fn": fusedw_measure},
+        {"name": "fused_window", "fn": fusedw, "args": one_arena,
+         "windows": 1, "measure_fn": fusedw_measure},
         {"name": "composed_drain", "fn": fdrain, "args": drain_args,
          "windows": k, "measure_fn": fdrain},
         {"name": "composed_mixed_algos", "fn": fdrain, "args": mix_args,

@@ -6,10 +6,13 @@ cache mutex (reference algorithms.go:24-186, gubernator.go:236-251).  Here one
 *window* of requests (the reference's 500µs BATCHING window, peers.go:143-172)
 is evaluated as a single fused XLA computation over a batch:
 
-  * State is a structure-of-arrays arena in device memory (`BucketState`),
-    replacing the map+linked-list LRU (reference cache/lru.go:30-96).  A slot
-    index replaces the string key; the host keeps the key→slot table
-    (state/arena.py).
+  * State is a structure-of-arrays arena in device memory, replacing the
+    map+linked-list LRU (reference cache/lru.go:30-96): `BucketState` is
+    its int64 row form (the oracle's, and the replicated GLOBAL table's),
+    `ArenaPlanes` the form the sharded arena is resident in — the same
+    columns as (lo, hi) uint32 planes, so no executable converts anything
+    of the arena's size.  A slot index replaces the string key; the host
+    keeps the key→slot table (state/arena.py).
   * Every request in the window is routed to a slot.  Requests to *different*
     slots are data-parallel.  Requests to the *same* slot must observe
     sequential semantics (request N+1 sees N's decrement — the reference gets
@@ -151,7 +154,11 @@ def _floordiv_rolled(a, b):
 
 
 class BucketState(NamedTuple):
-    """Dense SoA arena state, one row per key slot.
+    """Dense SoA bucket state as int64 rows, one row per key slot: what
+    `window_step` (the int64 oracle) steps, what the replicated GLOBAL
+    table is held as, and the form every reader outside a drain sees
+    (snapshot, migration, tiers).  The engine's sharded arena holds the
+    same columns as uint32 planes between drains: ArenaPlanes below.
 
     Replaces the reference's cacheRecord {value, expireAt} where value is
     either a *RateLimitResp (token) or a LeakyBucket (leaky)
@@ -186,6 +193,72 @@ class BucketState(NamedTuple):
             expire=z64,
             algo=jnp.zeros((capacity,), dtype=I32),
         )
+
+
+class ArenaPlanes(NamedTuple):
+    """The RESIDENT arena: every int64 column of BucketState held as a
+    (lo, hi) pair of uint32 planes, plus `algo` — the one layout
+    `engine.state` has in device memory between drains (44 B a slot, as the
+    int64 columns were).
+
+    The TPU compiler keeps an `s64[C]` executable parameter as a (lo, hi)
+    pair inside the program and converted every int64 plane of the arena on
+    the way in (X64SplitLow/High) and on the way out (X64Combine), every
+    drain, whatever the drain held: O(arena) work that did no rate limiting.
+    With uint32 parameters nothing of size C is converted: a drain gathers
+    `lo[g]`, `hi[g]` at its B lanes, joins them at B width
+    (gather_registers), and commits with single-operand 32-bit scatters
+    (commit_registers).  `hi` carries the sign, so all 64 bits survive:
+    values >= 2^32, negative `remaining`, expire == 0 for a dead slot.
+
+    BucketState stays the ROW form: the int64 oracle's state
+    (window_step), the replicated GLOBAL tables, and what everything that
+    is not a drain reads and writes through arena_to_rows /
+    arena_from_rows (snapshot, migration, tiers: their formats are
+    int64 rows, unchanged).
+    """
+
+    limit_lo: jax.Array  # u32[C]
+    limit_hi: jax.Array
+    duration_lo: jax.Array
+    duration_hi: jax.Array
+    remaining_lo: jax.Array
+    remaining_hi: jax.Array
+    tstamp_lo: jax.Array
+    tstamp_hi: jax.Array
+    expire_lo: jax.Array
+    expire_hi: jax.Array
+    algo: jax.Array  # i32[C]
+
+    @classmethod
+    def zeros(cls, capacity: int) -> "ArenaPlanes":
+        return arena_from_rows(BucketState.zeros(capacity))
+
+
+def split64(x):
+    """int64 values -> (lo, hi) uint32 halves.  Elementwise on any shape,
+    numpy or jax arrays alike."""
+    return ((x & 0xFFFFFFFF).astype(np.uint32),
+            ((x >> 32) & 0xFFFFFFFF).astype(np.uint32))
+
+
+def join64(lo, hi):
+    """(lo, hi) uint32 halves -> the int64 they hold (hi's top bit lands on
+    bit 63: two's complement, so negatives round-trip).  Inverse of
+    split64."""
+    return (hi.astype(np.int64) << 32) | lo.astype(np.int64)
+
+
+def arena_from_rows(rows: BucketState) -> ArenaPlanes:
+    """int64 rows (any shape, numpy or jax) -> the resident plane form."""
+    halves = [h for col in rows[:5] for h in split64(col)]
+    return ArenaPlanes(*halves, rows.algo)
+
+
+def arena_to_rows(planes: ArenaPlanes) -> BucketState:
+    """The resident plane form (any shape, numpy or jax) -> int64 rows."""
+    cols = [join64(planes[2 * i], planes[2 * i + 1]) for i in range(5)]
+    return BucketState(*cols, planes.algo)
 
 
 class WindowBatch(NamedTuple):
@@ -886,7 +959,27 @@ class WindowPrep(NamedTuple):
     s_agg: jax.Array   # aggregated-run lanes (AGG_SLOT_BIT), sorted order
 
 
-def window_prep(state: BucketState, batch: WindowBatch, now) -> WindowPrep:
+def gather_registers(state, g) -> _Reg:
+    """The int64 registers of slots `g`, from whichever form holds the
+    columns: int64 rows (BucketState: the oracle, the GLOBAL tables) or the
+    resident uint32 planes (ArenaPlanes), whose halves are gathered at the
+    B lanes and joined at B width — nothing of the arena's size is
+    converted."""
+    at = type(state)(*[col[g] for col in state])
+    return _Reg(*(arena_to_rows(at) if isinstance(at, ArenaPlanes) else at))
+
+
+def commit_registers(state, wslot, fin: _Reg):
+    """Write registers `fin` to slots `wslot` (out-of-range lanes dropped),
+    in `state`'s own form: the resident planes take each int64 register
+    split at B width, as one single-operand 32-bit scatter a plane."""
+    rows = BucketState(*fin)
+    vals = arena_from_rows(rows) if isinstance(state, ArenaPlanes) else rows
+    return type(state)(*[col.at[wslot].set(v, mode="drop")
+                         for col, v in zip(state, vals)])
+
+
+def window_prep(state, batch: WindowBatch, now) -> WindowPrep:
     """Sort by slot, find segments, gather registers, classify uniform
     segments (see window_step for the semantics each piece serves).
 
@@ -901,7 +994,7 @@ def window_prep(state: BucketState, batch: WindowBatch, now) -> WindowPrep:
     slot commits to the arena (earlier tenants' counters die with the
     eviction, exactly like the reference's cache Remove)."""
     B = batch.slot.shape[0]
-    C = state.limit.shape[0]
+    C = state.algo.shape[0]
 
     valid = batch.slot >= 0
     # Strip the aggregated-run flag off the slot BEFORE anything keys on
@@ -943,15 +1036,7 @@ def window_prep(state: BucketState, batch: WindowBatch, now) -> WindowPrep:
 
     # Registers: the live state of each segment's bucket.  Every lane of a
     # segment gathers the SAME slot, so these are replicated per segment.
-    g = jnp.clip(s_slot, 0, C - 1)
-    cur = _Reg(
-        limit=state.limit[g],
-        duration=state.duration[g],
-        remaining=state.remaining[g],
-        tstamp=state.tstamp[g],
-        expire=state.expire[g],
-        algo=state.algo[g],
-    )
+    cur = gather_registers(state, jnp.clip(s_slot, 0, C - 1))
     # Miss conditions known before replay: fresh host allocation or lazy TTL
     # expiry (lru.go:110: expireAt < now).  Algorithm switches are detected
     # per-round against the live register.
@@ -990,9 +1075,8 @@ def window_prep(state: BucketState, batch: WindowBatch, now) -> WindowPrep:
                       hstar, seg_fold, max_pos, commit_mask, s_agg)
 
 
-def window_commit(state: BucketState, prep: WindowPrep, fin: _Reg,
-                  outs_sorted: WindowOutput
-                  ) -> tuple[BucketState, WindowOutput]:
+def window_commit(state, prep: WindowPrep, fin: _Reg,
+                  outs_sorted: WindowOutput):
     """Scatter the final segment registers back to the arena (one write per
     touched slot — the window's net effect) and un-sort the responses to
     arrival order.  Shared by the XLA and Pallas paths.
@@ -1001,16 +1085,9 @@ def window_commit(state: BucketState, prep: WindowPrep, fin: _Reg,
     a slot mid-window the slot has several virtual segments, and only the
     last tenant's final register may land in the arena (duplicate scatter
     indices have undefined order in XLA)."""
-    C = state.limit.shape[0]
+    C = state.algo.shape[0]
     wslot = jnp.where(prep.commit_mask, prep.s_slot, jnp.int32(C))
-    new_state = BucketState(
-        limit=state.limit.at[wslot].set(fin.limit, mode="drop"),
-        duration=state.duration.at[wslot].set(fin.duration, mode="drop"),
-        remaining=state.remaining.at[wslot].set(fin.remaining, mode="drop"),
-        tstamp=state.tstamp.at[wslot].set(fin.tstamp, mode="drop"),
-        expire=state.expire.at[wslot].set(fin.expire, mode="drop"),
-        algo=state.algo.at[wslot].set(fin.algo, mode="drop"),
-    )
+    new_state = commit_registers(state, wslot, fin)
     # Un-sort via ONE packed row scatter instead of four per-field scatters
     # (per-op launch cost, see window_prep note); unpack is fused slices.
     B = prep.order.shape[0]
@@ -1294,17 +1371,9 @@ def global_read(state: BucketState, batch: WindowBatch, now) -> WindowOutput:
     reads never decrement, recomputing limit-hits each time is
     response-identical while keeping replicas bit-exact across shards).
     """
-    C = state.limit.shape[0]
+    C = state.algo.shape[0]
     now = jnp.asarray(now, dtype=I64)
-    g = jnp.clip(batch.slot, 0, C - 1)
-    reg = _Reg(
-        limit=state.limit[g],
-        duration=state.duration[g],
-        remaining=state.remaining[g],
-        tstamp=state.tstamp[g],
-        expire=state.expire[g],
-        algo=state.algo[g],
-    )
+    reg = gather_registers(state, jnp.clip(batch.slot, 0, C - 1))
     fresh = batch.is_init | (reg.expire < now) | (batch.algo != reg.algo)
     # A cached read is the hit path with hits=0 (the cached status the owner
     # would broadcast, global.go:199-203 → getRateLimit with Hits cleared);

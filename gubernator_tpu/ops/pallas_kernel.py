@@ -254,10 +254,9 @@ def _window_math_kernel(now_ref, maxpos_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "compact32", "use_pallas"))
-def window_step_pallas(state: BucketState, batch: WindowBatch, now, *,
+def window_step_pallas(state, batch: WindowBatch, now, *,
                        interpret: bool = False, compact32: bool = False,
-                       use_pallas: bool = True
-                       ) -> tuple[BucketState, WindowOutput]:
+                       use_pallas: bool = True):
     """Drop-in replacement for kernel.window_step with the window math in
     one Pallas kernel.  Sort, segment indexing, the arena gather, and the
     final scatter/unsort stay in XLA (see the module docstring for why).
@@ -374,8 +373,7 @@ def window_step_pallas(state: BucketState, batch: WindowBatch, now, *,
     return kernel.window_commit(state, prep, fin, out_sorted)
 
 
-def window_step_compact32_xla(state: BucketState, batch: WindowBatch, now
-                              ) -> tuple[BucketState, WindowOutput]:
+def window_step_compact32_xla(state, batch: WindowBatch, now):
     """The serving drain's default window step: the rebased-int32 math as
     plain traced XLA (no Mosaic dependency).  Exact under the compact
     wire-format range caps — the only context the engine calls it in
@@ -589,28 +587,38 @@ class FusedState32(NamedTuple):
     algo: jax.Array       # i32[C]
 
 
-def fused_state_to_planes(state: BucketState) -> FusedState32:
-    tp = lax.bitcast_convert_type(state.tstamp, I32)
-    ep = lax.bitcast_convert_type(state.expire, I32)
+def fused_state_to_planes(state) -> FusedState32:
+    """The resident planes (kernel.ArenaPlanes; int64 rows are split first)
+    as the megakernel's i32 planes: bitcasts of the halves it reads, the
+    high halves of limit/duration/remaining left behind (zero under the
+    compact caps)."""
+    a = (kernel.arena_from_rows(state) if isinstance(state, BucketState)
+         else state)
+    i32 = lambda p: lax.bitcast_convert_type(p, I32)
     return FusedState32(
-        limit=state.limit.astype(I32),
-        duration=state.duration.astype(I32),
-        remaining=state.remaining.astype(I32),
-        t_lo=tp[:, 0], t_hi=tp[:, 1],
-        e_lo=ep[:, 0], e_hi=ep[:, 1],
-        algo=state.algo)
+        limit=i32(a.limit_lo), duration=i32(a.duration_lo),
+        remaining=i32(a.remaining_lo),
+        t_lo=i32(a.tstamp_lo), t_hi=i32(a.tstamp_hi),
+        e_lo=i32(a.expire_lo), e_hi=i32(a.expire_hi),
+        algo=a.algo)
 
 
-def fused_state_from_planes(st32: FusedState32) -> BucketState:
-    pair64 = lambda lo, hi: lax.bitcast_convert_type(
-        jnp.stack([lo, hi], axis=-1), I64)
-    return BucketState(
-        limit=st32.limit.astype(I64),
-        duration=st32.duration.astype(I64),
-        remaining=st32.remaining.astype(I64),
-        tstamp=pair64(st32.t_lo, st32.t_hi),
-        expire=pair64(st32.e_lo, st32.e_hi),
-        algo=st32.algo)
+def fused_state_from_planes(st32: FusedState32, like=None):
+    """Inverse of fused_state_to_planes, into the form the state `like`
+    has: the resident planes, or (the default) int64 rows.  The counters'
+    high halves are their sign extension, as an i32 -> i64 widening
+    gives."""
+    u32 = lambda p: lax.bitcast_convert_type(p, jnp.uint32)
+    sext = lambda p: u32(p >> 31)
+    arena = kernel.ArenaPlanes(
+        u32(st32.limit), sext(st32.limit),
+        u32(st32.duration), sext(st32.duration),
+        u32(st32.remaining), sext(st32.remaining),
+        u32(st32.t_lo), u32(st32.t_hi), u32(st32.e_lo), u32(st32.e_hi),
+        st32.algo)
+    if isinstance(like, kernel.ArenaPlanes):
+        return arena
+    return kernel.arena_to_rows(arena)
 
 
 class _FusedAux(NamedTuple):
@@ -853,15 +861,16 @@ def window_step_fused_planes(st32: FusedState32, packed, now, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def window_step_fused(state: BucketState, packed, now, *,
+def window_step_fused(state, packed, now, *,
                       interpret: bool = False):
-    """BucketState-in/BucketState-out wrapper around the fused megakernel
-    (single-window call sites).  The pipeline drain avoids the per-window
+    """State-in/state-out wrapper around the fused megakernel (single-window
+    call sites; the state comes back in the form it came in, resident
+    planes or int64 rows).  The pipeline drain avoids the per-window
     O(C) plane conversion by carrying FusedState32 through its scan and
     calling window_step_fused_planes directly."""
     st32, words, limits, mism = window_step_fused_planes(
         fused_state_to_planes(state), packed, now, interpret=interpret)
-    return fused_state_from_planes(st32), words, limits, mism
+    return fused_state_from_planes(st32, state), words, limits, mism
 
 
 # ---- the K-grid staged drain: all K windows in ONE pallas_call ------------

@@ -99,11 +99,11 @@ def main():
             # the already-jitted step is not composable; rebuild with scan over
             # kernel.window_step on shard 0 only (single-chip scan probe)
             def step_inner(st, gst, gc, b, t):
-                s0 = kernel.BucketState(*jax.tree.map(lambda a: a[0], st))
+                s0 = jax.tree.map(lambda a: a[0], st)  # the arena's planes
                 b0 = kernel.WindowBatch(*jax.tree.map(lambda a: a[0], b))
                 ns, out = kernel.window_step(s0, b0, t)
                 expand = lambda a: a[None]
-                return (kernel.BucketState(*jax.tree.map(expand, ns)), gst, gc,
+                return (jax.tree.map(expand, ns), gst, gc,
                         kernel.WindowOutput(*jax.tree.map(expand, out)), None)
 
             (st, gst, gc, _), outs = lax.scan(body, (state, gstate, gcfg, t0), (stk,))

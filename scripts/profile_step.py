@@ -45,7 +45,8 @@ def main():
     x = jnp.zeros((8,), jnp.int32)
     print(f"dispatch floor (tiny jit): {timeit(nop, x)*1e3:.3f} ms")
 
-    state = kernel.BucketState.zeros(CAPACITY)
+    # the resident layout the engine serves from: (lo, hi) uint32 planes
+    state = kernel.ArenaPlanes.zeros(CAPACITY)
     state = jax.block_until_ready(state)
 
     for LANES in (4096, 8192, 16384, 32768, 65536):
@@ -84,12 +85,8 @@ def main():
         # --- (c) transition math alone (no sort, no scatter)
         @jax.jit
         def trans_only(st, b, now):
-            g = jnp.clip(b.slot, 0, CAPACITY - 1)
-            reg = kernel._Reg(
-                limit=st.limit[g], duration=st.duration[g],
-                remaining=st.remaining[g], tstamp=st.tstamp[g],
-                expire=st.expire[g], algo=st.algo[g],
-            )
+            reg = kernel.gather_registers(
+                st, jnp.clip(b.slot, 0, CAPACITY - 1))
             fresh = b.is_init | (reg.expire < now)
             return kernel.transition(reg, b.hits, b.limit, b.duration, b.algo, now, fresh)
 
@@ -100,11 +97,11 @@ def main():
         @jax.jit
         def scatter_only(st, b, vals):
             wslot = jnp.where(b.slot >= 0, b.slot, jnp.int32(CAPACITY))
-            return st.remaining.at[wslot].set(vals, mode="drop")
+            return st.remaining_lo.at[wslot].set(vals, mode="drop")
 
-        vals = jnp.ones((LANES,), jnp.int64)
+        vals = jnp.ones((LANES,), jnp.uint32)
         t = timeit(scatter_only, state, batch, vals)
-        print(f"  scatter (1 field)     : {t*1e3:7.3f} ms")
+        print(f"  scatter (1 plane)     : {t*1e3:7.3f} ms")
 
     # --- (d) int32 variant of full sorted pipeline (sort + seg + math int32)
     LANES = 8192

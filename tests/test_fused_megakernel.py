@@ -327,7 +327,7 @@ def test_pipeline_drain_fused_parity(monkeypatch):
     np.testing.assert_array_equal(np.asarray(wf), np.asarray(wx))
     np.testing.assert_array_equal(np.asarray(lf), np.asarray(lx))
     np.testing.assert_array_equal(np.asarray(mf), np.asarray(mx))
-    for n, a, b in zip(kernel.BucketState._fields, ef.state, ex.state):
+    for n, a, b in zip(kernel.ArenaPlanes._fields, ef.state, ex.state):
         np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]),
                                       err_msg=f"state.{n}")
 

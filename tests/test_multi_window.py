@@ -112,10 +112,11 @@ def test_stacked_equals_sequential(seed):
                 err_msg=f"window {i} GLOBAL field {f}")
 
     # final arena state identical
-    for f in kernel.BucketState._fields:
+    for f in kernel.ArenaPlanes._fields:
         np.testing.assert_array_equal(
             jax.device_get(getattr(ea.state, f)),
             jax.device_get(getattr(eb.state, f)), err_msg=f"state.{f}")
+    for f in kernel.BucketState._fields:
         np.testing.assert_array_equal(
             jax.device_get(getattr(ea.gstate, f)),
             jax.device_get(getattr(eb.gstate, f)), err_msg=f"gstate.{f}")

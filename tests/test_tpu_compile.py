@@ -16,6 +16,7 @@ around them (a described-device executable cannot be read back).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gubernator_tpu.config import AnalyticsConfig, EngineConfig
 from gubernator_tpu.core import engine as engine_mod
-from gubernator_tpu.ops.kernel import BucketState, GlobalConfig, WindowBatch
+from gubernator_tpu.ops.kernel import (ArenaPlanes, BucketState,
+                                       GlobalConfig, WindowBatch)
 from gubernator_tpu.parallel.mesh import SHARD_AXIS
 
 # chip_smoke.py's one-chip widths (BASELINE.json config 3) and K
@@ -91,8 +93,9 @@ class _Shapes:
                 algo=sds(shape, i32, sharding=s),
                 is_init=sds(shape, jnp.bool_, sharding=s))
 
-        self.state = BucketState(*[sds((S, C), i64, sharding=sh)] * 5,
-                                 sds((S, C), i32, sharding=sh))
+        self.state = ArenaPlanes(
+            *[sds((S, C), jnp.uint32, sharding=sh)] * 10,
+            sds((S, C), i32, sharding=sh))
         self.gstate = BucketState(*[sds((G,), i64, sharding=rep)] * 5,
                                   sds((G,), i32, sharding=rep))
         self.gcfg = GlobalConfig(sds((G,), i64, sharding=rep),
@@ -117,7 +120,7 @@ class _Shapes:
     def sketch(self, conf):
         return jax.ShapeDtypeStruct(
             (self.S, conf.sketch_depth, conf.sketch_width), jnp.int64,
-            sharding=self.state.limit.sharding)
+            sharding=self.state.algo.sharding)
 
 
 def _compile(fn, *args):
@@ -145,6 +148,56 @@ def _fits(compiled, budget_bytes: int = 16 * 1024 ** 3) -> None:
     assert total < budget_bytes, m
 
 
+def _arena_stays_in_place(compiled, C: int, staged_ok: bool) -> None:
+    """The optimised program converts and copies nothing of the arena's
+    size: the resident planes are uint32 (ops/kernel.py ArenaPlanes), so
+    no X64SplitLow / X64SplitHigh / X64Combine custom-call has an operand
+    of C elements (with int64 planes there were fifteen, 4.7 ms of every
+    drain at C = 10,485,760: ledger, PR 32), every plane parameter is
+    aliased to its output (a plane that is not donated is copied, 42 MB a
+    drain), and the only instructions that produce an array of C elements
+    are the commit scatters.
+
+    `staged_ok`: from 4,096 lanes up, and for small arenas, the compiler's
+    memory-space assignment prefetches some planes into S(1) around their
+    scatter (slice-start / ConcatBitcast in, copy-start out: asynchronous
+    moves the compiler chooses, and a 16,384-lane drain compiled without
+    them measured 1 ms SLOWER: PERF.md section 6, PR 33).  They are its
+    to choose; a conversion or a synchronous copy is not."""
+    text = compiled.as_text()
+    head = text.split("\n", 1)[0]
+    pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", head)
+    assert (sum(o == i for o, i in pairs)
+            >= len(ArenaPlanes._fields)), head[:400]
+
+    sized = re.compile(r"\[(?:1,)?%d\]" % C)
+    instr = re.compile(r"^(?:ROOT )?%[\w.\-]+ = (.*?) ([\w\-]+)\(")
+    no_data = {"parameter", "bitcast", "get-tuple-element", "tuple", "while"}
+    prefetch = {"slice-start", "slice-done", "copy-start", "copy-done"}
+    fused = False       # inside a fusion's own computation: judged by its
+    for line in text.splitlines():      # caller's `fusion(` line instead
+        line = line.strip()
+        if line.endswith("{") and " = " not in line:
+            fused = line.startswith("%fused_computation")
+            continue
+        m = instr.match(line)
+        if fused or m is None:
+            continue
+        shape, op = m.groups()
+        if "X64" in line:
+            assert not sized.search(line), (
+                f"arena-sized x64 conversion: {line[:240]}")
+        if not sized.search(shape) or op in no_data:
+            continue    # gathers read a plane and produce B lanes
+        if op == "fusion":
+            assert "/scatter" in line, (
+                f"arena-sized fusion that is no commit scatter: {line[:300]}")
+            continue
+        assert staged_ok and (op in prefetch or '"ConcatBitcast"' in line), (
+            f"arena-sized instruction that is neither a commit scatter nor "
+            f"a prefetch: {line[:300]}")
+
+
 # ----------------------------------------------------- default path: compiles
 
 
@@ -165,6 +218,9 @@ def test_default_drain_compiles(one_chip, C, B, K):
     c = _compile(fn, s.state, s.packed, s.nows)
     _fits(c)
     assert "tpu_custom_call" not in c.as_text()  # the XLA body, no Mosaic
+    # at 1,024 lanes over 10M slots (the edge cells' drain) the compiler
+    # stages nothing: gathers and commit scatters alone touch the planes
+    _arena_stays_in_place(c, C, staged_ok=(C, B) != (SMOKE_C, SMOKE_B // 16))
 
 
 def test_global_drain_compiles_one_chip(one_chip):
@@ -186,6 +242,7 @@ def test_global_drain_compiles_four_chips(four_chips):
     _fits(c)
     text = c.as_text()
     assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 1
+    _arena_stays_in_place(c, DEF_C, staged_ok=True)
 
 
 def test_global_drain_with_analytics_compiles(one_chip):
@@ -228,8 +285,8 @@ def test_analytics_reduce_compiles(one_chip):
     fn = engine_mod._compiled_analytics_reduce(
         one_chip, conf.sketch_depth, conf.sketch_width, conf.tenant_slots,
         conf.topk, conf.over_weight)
-    _fits(_compile(fn, s.sketch(conf), s.state.expire, s.packed, s.words,
-                   s.tenants, s.now, s.now))
+    _fits(_compile(fn, s.sketch(conf), s.state.expire_lo, s.state.expire_hi,
+                   s.packed, s.words, s.tenants, s.now, s.now))
 
 
 # ------------------------------------------- opt-in Pallas lowerings: refused

@@ -104,7 +104,7 @@ def test_narrow_dispatch_equals_full_dispatch(width, seed):
         np.testing.assert_array_equal(np.asarray(mn), np.asarray(mf))
         if i == 1:
             assert np.asarray(mn).any()   # the limits plane mattered
-        for name, a, b in zip(kernel.BucketState._fields, narrow.state,
+        for name, a, b in zip(kernel.ArenaPlanes._fields, narrow.state,
                               full.state):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=f"state.{name} drain {i}")
