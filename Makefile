@@ -1,7 +1,7 @@
 # Dev targets (the reference Makefile:1-15 has only release/docker; we add
 # the working set).
 
-.PHONY: test test-core test-pallas test-mesh-fused test-fused-staging test-snapshot test-qos test-obs test-chaos test-analytics test-overlap test-chain test-frontdoor test-tiers test-devprof test-algorithms proto bench chip-smoke bench-smoke docker lint cluster
+.PHONY: test test-core test-mesh-drain test-snapshot test-qos test-obs test-chaos test-analytics test-overlap test-chain test-frontdoor test-tiers test-devprof test-algorithms proto chip-smoke docker lint cluster
 
 test:
 	python -m pytest tests/ -x -q
@@ -10,23 +10,11 @@ test:
 test-core:
 	python -m pytest tests/ -x -q -m "not slow"
 
-# the Pallas lowerings' differential suites (interpret mode on CPU):
-# per-op kernels + the fused serving-window megakernel vs the int64 oracle
-test-pallas:
-	python -m pytest tests/test_pallas.py tests/test_fused_megakernel.py -x -q
-
-# the sharded fused-serving differential suite (forced 8-device CPU mesh):
-# composed GLOBAL drain, fused-vs-legacy parity, jaxpr kernel census.
+# the sharded serving differential suite (forced 8-device CPU mesh): the
+# GLOBAL-composed drain vs the int64 oracle, analytics composed in or not.
 # Part of tier-1 (`test-core` picks it up too); this target runs just the slice.
-test-mesh-fused:
-	python -m pytest tests/ -x -q -m "mesh_fused and not slow"
-
-# the fused-staging differential seeds: packed-wire windows through the
-# K-grid drain + staged GLOBAL/analytics kernels vs the host
-# decode→oracle→encode path, replay-fallback shapes included.  Part of
-# tier-1 (`test-core` picks it up too); this target runs just the slice.
-test-fused-staging:
-	python -m pytest tests/ -x -q -m "fused_staging and not slow"
+test-mesh-drain:
+	python -m pytest tests/ -x -q -m "mesh_drain and not slow"
 
 # the state-lifecycle slice: snapshot/restore restart equivalence + live
 # key migration on ring change.  Part of tier-1 (`test-core` picks it up
@@ -55,7 +43,7 @@ test-chaos:
 
 # the traffic-analytics slice: device stats reduction vs the numpy oracle,
 # Zipf hot-key top-K precision, SLO burn-rate alerting, analytics-off
-# zero-overhead census.  Part of tier-1 (`test-core` picks it up too).
+# zero overhead.  Part of tier-1 (`test-core` picks it up too).
 test-analytics:
 	python -m pytest tests/ -x -q -m "analytics and not slow"
 
@@ -91,7 +79,7 @@ test-tiers:
 	python -m pytest tests/ -x -q -m "tiers and not slow"
 
 # the device-time flight-recorder slice: jax.profiler trace parsing +
-# kernel attribution (every census arm gets nonzero measured ms/window
+# kernel attribution (every probe arm gets nonzero measured ms/window
 # from a REAL parsed trace), window-clock EWMA + slow-window exemplars,
 # shm traceparent region roundtrip, the /v1/admin/kernels plane, and
 # malformed-trace degradation.  Part of tier-1 (`test-core` picks it up
@@ -100,8 +88,8 @@ test-devprof:
 	python -m pytest tests/ -x -q -m "devprof and not slow"
 
 # the algorithm-plane slice: GCRA / sliding-window / concurrency ladders
-# bit-exact vs the plain-python serial oracles on every lowering (int64,
-# compact32-XLA, Pallas per-window, fused K-grid), the all-algorithm fold
+# bit-exact vs the plain-python serial oracles on both window bodies
+# (int64, compact32) and through the packed wire, the all-algorithm fold
 # fuzz seeds, lease-book lifecycle, out-of-range→token fallback, and
 # snapshot forward-compat row dropping.  Part of tier-1 (`test-core`
 # picks it up too); this target runs just the slice.
@@ -111,35 +99,12 @@ test-algorithms:
 proto:
 	cd gubernator_tpu/api/proto && protoc --python_out=. gubernator.proto peers.proto
 
-# On the machine with the chip: one process, fails if the device is not a
-# TPU, exits non-zero when a tier raises.
-bench:
-	python bench.py
-
 # The quickest proof the system still starts on the chip (run it through
 # the chip tool; `python chip_smoke.py --chips 4` is the cross-chip path).
 # Here, without a chip, it must fail: `JAX_PLATFORMS=cpu python
 # chip_smoke.py --tiny` rehearses the control flow and still ends non-zero.
 chip-smoke:
 	python chip_smoke.py
-
-# bench-regression gate: fresh CPU smoke run of bench.py (JAX_PLATFORMS=cpu,
-# --platform cpu) diffed against this host's best prior run (10% noise
-# floor); fails loudly when e2e/device/host decisions-per-sec regress.
-# Then the census, the open-loop overlap probe (the pipeline's stage split
-# + realized overlap), a short front-door sweep (in-process baseline vs 2
-# acceptor workers: e2e decisions/s + shm ring stall %), the tier probe
-# (arena fraction under Zipf traffic), and the trace-overhead probe (the
-# continuous device profiler, GUBER_DEVPROF=periodic, must cost <2% of the
-# untraced serving rate).  All of it is CPU; none of it is a device number.
-bench-smoke:
-	python scripts/bench_compare.py
-	JAX_PLATFORMS=cpu python scripts/probe_census.py
-	JAX_PLATFORMS=cpu python scripts/probe_trace_overhead.py
-	JAX_PLATFORMS=cpu python scripts/probe_overlap.py
-	JAX_PLATFORMS=cpu GUBER_PROBE_FD_WORKERS=0,2 GUBER_PROBE_SECONDS=2 python scripts/probe_frontdoor.py
-	JAX_PLATFORMS=cpu GUBER_PROBE_TIER_NS=8192 GUBER_PROBE_TIER_WINDOWS=120 GUBER_PROBE_B=128 python scripts/probe_tiers.py
-	JAX_PLATFORMS=cpu GUBER_CLUSTER_NODES=1 GUBER_CLUSTER_SECONDS=2 GUBER_CLUSTER_RATE=20 GUBER_CLUSTER_BATCH=32 GUBER_CLUSTER_FRONTDOOR=2 python scripts/load_cluster.py
 
 docker:
 	docker build -t gubernator-tpu:latest .

@@ -37,7 +37,6 @@ import gc
 import json
 import socket
 import sys
-import threading
 import time
 
 import numpy as np
@@ -405,12 +404,6 @@ async def serve_and_check(sz, seed, workers):
     finally:
         await cl.close()
         await d.stop()
-    # the daemon's boot-time census thread traces jaxprs in the background;
-    # let it finish so the interpreter never exits under a live JAX trace
-    for th in threading.enumerate():
-        if th.name == "guber-census":
-            th.join(timeout=300)
-            require(not th.is_alive(), "the census thread never finished")
     require(d.shutdown_phases == SHUTDOWN_PHASES, d.shutdown_phases)
     say(f"[{tag}] PASS: clean stop(), phases {' > '.join(d.shutdown_phases)}")
     del d

@@ -5,7 +5,7 @@ v0.5.0) for TPU hardware.  Where the reference keeps each rate-limit counter in 
 per-node LRU map mutated under a mutex (reference cache/lru.go:30,
 algorithms.go:24-186), this framework keeps the whole keyspace as dense
 structure-of-arrays state resident in TPU HBM, evaluates every batching window
-with one fused XLA/Pallas kernel (ops/kernel.py), partitions keys over a
+with one fused XLA computation (ops/kernel.py), partitions keys over a
 `jax.sharding.Mesh` axis instead of a consistent-hash ring of Go processes
 (reference hash.go:28-96), and replaces the GLOBAL behavior's async gRPC hit
 broadcast (reference global.go:72-232) with a `lax.psum` over the mesh axis.

@@ -1,14 +1,14 @@
 """Algorithm plane: the per-algorithm ladders layered over the fused drain.
 
 The wire `algorithm` enum (api/types.py) carries five values; all of them
-lower to the ONE shared transition ladder in ops/kernel.py, which every
-lowering (int64 oracle, compact32-XLA, per-window Pallas, fused megakernel)
-vmaps over.  This package holds what lives ABOVE the kernels:
+lower to the ONE shared transition ladder in ops/kernel.py, which both
+window bodies (the int64 oracle, the compact32 serving body) run.  This
+package holds what lives ABOVE the kernels:
 
   * oracles.py — pure-python serial references for all five algorithms,
     mirroring the device ladders branch for branch.  The differential test
-    suites (tests/test_fold_fuzz.py, tests/test_algorithms.py) hold every
-    lowering bit-exact against these.
+    suites (tests/test_fold_fuzz.py, tests/test_algorithms.py) hold both
+    bodies bit-exact against these.
   * leases.py — the host-side concurrency-lease book: who holds how many
     slots of which key, so stream-close and peer-death can release held
     slots and ring migration can re-register them.

@@ -176,32 +176,16 @@ def build_app(instance: Instance) -> web.Application:
                                  status=200 if out.get("armed") else 409)
 
     async def admin_kernels(request: web.Request) -> web.Response:
-        """Census count × measured ms/window per serving arm, the rolling
-        kernel table, and the window clock (observability/devprof.py).
-        `?measure=1` runs the arm-scoped measured probe inline (seconds of
-        compile on a cold process; 409 while a capture is armed);
-        `?census=1` adds the per-arm census kernels/window (traced once,
-        then cached)."""
+        """Measured ms/window per serving arm, the rolling kernel table,
+        and the window clock (observability/devprof.py).  `?measure=1`
+        runs the arm-scoped measured probe inline (seconds of compile on a
+        cold process; 409 while a capture is armed)."""
         import asyncio as _aio
         devprof = getattr(instance, "devprof", None)
         if devprof is None:
             return web.json_response(
                 {"error": "devprof unavailable", "code": 12}, status=501)
         q = request.query
-        census = None
-        if q.get("census", "1") not in ("0", "false"):
-            from gubernator_tpu.observability.devprof import census_table
-            census = await _aio.get_running_loop().run_in_executor(
-                None, census_table)
-            # keep the scoreboard gauge current with the freshly traced
-            # table (startup publishes the same number; see
-            # Instance._publish_census)
-            metrics = getattr(instance, "metrics", None)
-            if metrics is not None and census:
-                arm = census.get("composed_analytics") \
-                    or census.get("composed_drain")
-                if arm:
-                    metrics.kernels_per_window.set(arm)
         measured = None
         if q.get("measure") in ("1", "true"):
             if instance.batcher.profile.armed:
@@ -214,19 +198,17 @@ def build_app(instance: Instance) -> web.Application:
                 return web.json_response(
                     {"error": "invalid iters", "code": 3}, status=400)
             from gubernator_tpu.observability.devprof import (
-                measure_census_arms,
+                measure_probe_arms,
             )
             measured = await _aio.get_running_loop().run_in_executor(
-                None, lambda: measure_census_arms(iters=iters,
-                                                  table=devprof.table))
-        out = devprof.kernels_snapshot(census=census)
+                None, lambda: measure_probe_arms(iters=iters,
+                                                 table=devprof.table))
+        out = devprof.kernels_snapshot()
         if measured is not None:
             out["measured"] = measured["arms"]
             for arm, row in measured["arms"].items():
-                slot = out["arms"].setdefault(
-                    arm, {"census_kernels_per_window": None,
-                          "measured_ms_per_window": None})
-                slot["measured_ms_per_window"] = row["measured_ms_per_window"]
+                out["arms"][arm] = {
+                    "measured_ms_per_window": row["measured_ms_per_window"]}
         return web.json_response(out)
 
     # a full-arena snapshot blob is tens of MB at default capacity — far

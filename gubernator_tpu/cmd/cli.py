@@ -356,13 +356,12 @@ def cmd_slo(args) -> int:
 
 
 def cmd_kernels(args) -> int:
-    """Census count × measured ms/window reconciliation table from
+    """Measured ms/window per serving arm and the kernel table from
     /v1/admin/kernels (observability/devprof.py)."""
     def once() -> int:
-        url = (f"{_http_base(args.address)}/v1/admin/kernels"
-               f"?census={0 if args.no_census else 1}")
+        url = f"{_http_base(args.address)}/v1/admin/kernels"
         if args.measure:
-            url += f"&measure=1&iters={args.iters}"
+            url += f"?measure=1&iters={args.iters}"
         try:
             with urllib.request.urlopen(url, timeout=args.timeout) as resp:
                 snap = json.loads(resp.read().decode("utf-8"))
@@ -374,12 +373,10 @@ def cmd_kernels(args) -> int:
             print(f"kernels fetch failed: {e}", file=sys.stderr)
             return 1
         arms = snap.get("arms", {})
-        print(f"{'arm':<22}{'census k/win':>14}{'measured ms/win':>18}")
+        print(f"{'arm':<22}{'measured ms/win':>18}")
         for arm, row in sorted(arms.items()):
-            cen = row.get("census_kernels_per_window")
             ms = row.get("measured_ms_per_window")
             print(f"{arm:<22}"
-                  f"{cen if cen is not None else '-':>14}"
                   f"{f'{ms:.4f}' if ms is not None else '-':>18}")
         clock = snap.get("clock")
         if clock:
@@ -464,8 +461,8 @@ def main(argv=None) -> None:
                     help="refresh every SECONDS until ^C (0 = one shot)")
     po.add_argument("--timeout", type=float, default=5.0)
 
-    pk = sub.add_parser("kernels", help="census × measured device-time "
-                        "kernel table (devprof)")
+    pk = sub.add_parser("kernels", help="measured device-time kernel "
+                        "table (devprof)")
     pk.add_argument("address", help="daemon HTTP address (host:port)")
     pk.add_argument("-n", type=int, default=20,
                     help="kernel-table rows to show")
@@ -474,8 +471,6 @@ def main(argv=None) -> None:
                     "(seconds of compile on a cold daemon)")
     pk.add_argument("--iters", type=int, default=2,
                     help="measured-probe iterations per arm")
-    pk.add_argument("--no-census", action="store_true",
-                    help="skip the census column (faster on a cold daemon)")
     pk.add_argument("--watch", type=float, default=0.0, metavar="SECONDS",
                     help="refresh every SECONDS until ^C (0 = one shot)")
     pk.add_argument("--timeout", type=float, default=300.0)

@@ -34,19 +34,6 @@ FETCH_STRIDE_DEFAULT = 1
 FETCH_STRIDE_MAX_DEFAULT = 8
 CHAIN_LINGER_MS_DEFAULT = 2.0
 
-# Serving-lowering ladder (core/engine.py) — env-only perf knobs, all read
-# through env_bool at BUILD time (they key the compiled-builder cache, so
-# flipping one mid-process only affects executables built afterwards):
-#   GUBER_PALLAS=1          per-op Pallas lowerings (default: XLA)
-#   GUBER_PALLAS_FUSED=1    the fused serving-window megakernel
-#   GUBER_PALLAS_STAGED=0   opt OUT of the staged drain (default ON when
-#                           fused): K-grid drain kernel + pair-GLOBAL
-#                           kernel + analytics finisher — the folded
-#                           single-digit kernels/window ladder.  0 reverts
-#                           to the lax.scan drain skeleton for bisection.
-#   GUBER_COMPACT32_XLA=0   opt out of the compact32 XLA window body
-
-
 @dataclass
 class BehaviorConfig:
     """Batching/global windows (reference config.go:43-57, defaults :59-66).
@@ -556,7 +543,7 @@ def place_compile_cache() -> str:
     reads it and nothing is set here.  Otherwise the cache is
     `<checkout>/.jax_cache`, derived from this file: the path is part of
     the cache key, so it must not move between runs of one checkout.  The
-    daemon, chip_smoke.py, bench.py and the probe scripts all call this —
+    daemon, chip_smoke.py and the probe scripts all call this —
     the one place the cache is placed."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
@@ -575,14 +562,10 @@ _warned_env: set = set()
 def env_bool(name: str, default: bool = False) -> bool:
     """Boolean GUBER_* knob: accepts 0/1/true/false/yes/no/on/off
     (case-insensitive); unset means `default`.  An unrecognized value
-    warns once per (name, value) and falls back to the default — the old
-    `== "1"` readers silently disabled features on `GUBER_PALLAS_FUSED=true`,
-    which is exactly the misconfiguration a perf flag must surface.
-
-    One shared reader for every on/off flag (engine executables,
-    pallas_kernel, probes): these flags are compiled-builder cache keys
-    read at build time, so every reader normalizing identically is part
-    of the executable-consistency contract."""
+    warns once per (name, value) and falls back to the default: a reader
+    that only accepted the literal "1" would silently disable a feature on
+    `=true`, which is exactly the misconfiguration a flag must surface.
+    One shared reader for every on/off flag."""
     v = os.environ.get(name)
     if v is None:
         return default

@@ -164,7 +164,6 @@ class RateLimitEngine:
         skip_global: bool = False,
     ):
         self.mesh = mesh if mesh is not None else make_mesh()
-        _check_lowering_flags(self.mesh)
         self.num_shards = int(np.prod(list(self.mesh.shape.values())))
         self.capacity_per_shard = capacity_per_shard
         self.batch_per_shard = batch_per_shard
@@ -1466,8 +1465,7 @@ class RateLimitEngine:
         now_in = self._repl_in(np.int64(now)) if self.multiprocess \
             else jnp.int64(now)
         # SURVEY §5 tracing analog: window dispatches show up as named steps
-        # in a jax.profiler trace (GUBER_PROFILE in bench.py, or any
-        # profiler session); no-op otherwise
+        # in a jax.profiler trace (any profiler session); no-op otherwise
         with jax.profiler.StepTraceAnnotation(
                 "guber_window", step_num=self.windows_processed):
             return self._dispatch_inner(buf, compact, lanes, gbatch, gacc,
@@ -1637,7 +1635,7 @@ class RateLimitEngine:
     #     executable over the drain's inputs/outputs (analytics_dispatch
     #     below), so the drain builders stay byte-identical whether
     #     analytics is on or off — the disabled serving path is provably
-    #     unchanged (tests/test_analytics.py census);
+    #     unchanged (tests/test_analytics.py);
     #   * the lockstep tick composes it INTO the GLOBAL-composed drain
     #     (pipeline_dispatch_global's analytics_args): one dispatch, one
     #     collective-sequence slot, and the reduction reads the drain's
@@ -2521,155 +2519,6 @@ def _scatter_gcfg_jit(gcfg: GlobalConfig, gi, vals) -> GlobalConfig:
         gcfg, vals)
 
 
-def _use_pallas() -> bool:
-    """Opt-in Pallas lowering (GUBER_PALLAS=1) for the window kernel and
-    the GLOBAL apply pass (ops/pallas_kernel.py).  Read at trace time —
-    i.e. once per mesh, when each executable family builds."""
-    from gubernator_tpu.config import env_bool
-    return env_bool("GUBER_PALLAS", False)
-
-
-def _use_compact32_xla() -> bool:
-    """Default-on rebased-int32 XLA math for compact call sites
-    (GUBER_COMPACT32_XLA=0 reverts to the int64 kernel).  Same read-at-
-    build-time discipline as _use_pallas: the flag is part of each
-    compiled builder's cache key, never read mid-trace."""
-    from gubernator_tpu.config import env_bool
-    return env_bool("GUBER_COMPACT32_XLA", True)
-
-
-def _use_pallas_fused() -> bool:
-    """Opt-in FUSED Pallas serving window (GUBER_PALLAS_FUSED=1): the whole
-    compact window — decode, sort, segment prep, transitions, commit,
-    response encode — as ONE pallas_call (ops/pallas_kernel.py
-    window_step_fused) instead of the ~hundreds of executed kernels the
-    compact32-XLA drain lowers to.  Default off; adopted by bench.py's
-    parity-gated A/B.  Same read-at-build-time discipline as _use_pallas.
-    Takes precedence over GUBER_PALLAS at compact call sites; full-format
-    call sites are unaffected (their lanes may exceed the rebase range)."""
-    from gubernator_tpu.ops.pallas_kernel import fused_enabled
-    return fused_enabled(False)
-
-
-def _use_pallas_staged() -> bool:
-    """Default-on STAGED drain lowering (GUBER_PALLAS_STAGED=0 reverts to
-    the K-scan of single-window megakernels): with the fused megakernel
-    enabled, the pipeline drain's K windows run as ONE pallas_call with a
-    K-major grid dimension (the arena carried across grid steps through
-    the aliased planes) and the GLOBAL sub-window's transition ladder runs
-    as one pair-arithmetic kernel — the composed drain traces to O(1)
-    kernels total instead of K pallas_calls plus the scan/staging/GLOBAL
-    shoulders.  No effect unless GUBER_PALLAS_FUSED is on.  Same
-    read-at-build-time discipline as _use_pallas: part of each compiled
-    builder's cache key, never read mid-trace."""
-    from gubernator_tpu.config import env_bool
-    return env_bool("GUBER_PALLAS_STAGED", True)
-
-
-# Pallas lowerings the chip's compiler refuses, with its own words (AOT
-# compiles for a described v5e, PR 24; tests/test_tpu_compile.py holds each
-# as a strict xfail).  A flag listed here is an error at engine
-# construction on a TPU mesh — the engine never serves a different body
-# than the flag names.  The PR that makes one lower deletes its entry here
-# together with the test's xfail mark.
-_MOSAIC_REFUSED = {
-    "GUBER_PALLAS": (
-        "RecursionError: maximum recursion depth exceeded — Mosaic's "
-        "lowering of the window-math kernel recurses without end on a "
-        "64-bit to 32-bit convert_element_type (python-int operands trace "
-        "as weak int64 under x64)"),
-    "GUBER_PALLAS_FUSED": (
-        "GUBER_PALLAS_STAGED=1 (default): ValueError: The Pallas TPU "
-        "lowering currently requires that the last two dimensions of your "
-        "block shape are divisible by 8 and 128 respectively, or be equal "
-        "to the respective dimensions of the overall array (drain_kernel "
-        "args[0]: block (1, 2) of array (K, 2)); GUBER_PALLAS_STAGED=0: "
-        "NotImplementedError: Only 2D gather is supported (the fused "
-        "body's 1-D lane gathers)"),
-}
-
-
-def _check_lowering_flags(mesh: Mesh) -> None:
-    if _mesh_on_cpu(mesh):
-        return  # interpret mode: every lowering runs
-    for flag, on in (("GUBER_PALLAS", _use_pallas()),
-                     ("GUBER_PALLAS_FUSED", _use_pallas_fused())):
-        if on and flag in _MOSAIC_REFUSED:
-            raise RuntimeError(
-                f"{flag}=1 on a {mesh.devices.flat[0].platform} mesh: the "
-                f"chip's compiler refuses this lowering "
-                f"({_MOSAIC_REFUSED[flag]}).  Unset the flag: the default "
-                f"compact32-XLA body is the one that compiles.")
-
-
-def _recursion_guarded(fn):
-    """Wrap a compiled executable so every call runs under the Mosaic
-    recursion-limit guard (ops/pallas_kernel.py mosaic_recursion_guard).
-
-    Real-Mosaic lowering of the big fused window jaxpr recurses deeper than
-    CPython's default 1000 frames, and jax lowers lazily — at the FIRST CALL
-    of the jitted object, not at jit() time — so the guard must wrap the
-    call site.  Scoping it here (instead of the old module-import
-    setrecursionlimit side effect) keeps the process global untouched for
-    every embedder that never runs the Pallas path."""
-    from functools import wraps
-
-    from gubernator_tpu.ops.pallas_kernel import mosaic_recursion_guard
-
-    @wraps(fn)
-    def guarded(*args, **kwargs):
-        with mosaic_recursion_guard():
-            return fn(*args, **kwargs)
-
-    return guarded
-
-
-def _window_step_fn(mesh: Mesh, compact32: bool, pallas: bool,
-                    c32xla: bool):
-    """kernel.window_step, or its Pallas lowering under GUBER_PALLAS=1
-    (interpret mode when the MESH's devices are CPU — Mosaic is TPU-only,
-    and the process default backend may differ from the mesh platform).
-
-    compact32 marks call sites whose lanes are guaranteed inside the
-    compact wire-format ranges (the pipeline drain): there the Pallas
-    kernel runs in rebased int32, which is the ONLY form Mosaic accepts
-    on real TPU (no 64-bit vector types).  Without Pallas those call
-    sites run the SAME rebased-int32 math as plain XLA by default
-    (window_step_compact32_xla, c32xla): TPU XLA emulates int64
-    arithmetic as i32-pair ops, so the int64 ladder pays roughly double
-    the math op count for no benefit inside the compact ranges.
-    Full-format call sites keep the int64 kernel — their lanes can
-    exceed the rebase range.
-
-    `pallas`/`c32xla` are REQUIRED and threaded from the compiled-builder
-    cache keys so a jit object built under one env setting cannot trace
-    under another; an env-reading default here would reintroduce the
-    trace-time read the cache keys exist to eliminate."""
-    if pallas:
-        from functools import partial
-
-        from gubernator_tpu.ops.pallas_kernel import window_step_pallas
-        on_cpu = _mesh_on_cpu(mesh)
-        if compact32:
-            return partial(window_step_pallas, interpret=on_cpu,
-                           compact32=True)
-        if on_cpu:
-            return partial(window_step_pallas, interpret=True)
-        raise NotImplementedError(
-            "GUBER_PALLAS has no full-format (int64) window kernel for a "
-            "TPU mesh: Mosaic has no 64-bit vector types")
-    if compact32 and c32xla:
-        from gubernator_tpu.ops.pallas_kernel import (
-            window_step_compact32_xla,
-        )
-        return window_step_compact32_xla
-    return kernel.window_step
-
-
-def _mesh_on_cpu(mesh: Mesh) -> bool:
-    return mesh.devices.flat[0].platform == "cpu"
-
-
 # shard_map specs of the two resident states: the sharded arena's planes,
 # and the replicated GLOBAL table's int64 rows
 _ARENA_SHARDED = ArenaPlanes(*[P(SHARD_AXIS)] * len(ArenaPlanes._fields))
@@ -2725,8 +2574,7 @@ def _apply_config(gstate: BucketState, gcfg: GlobalConfig, upd):
 
 
 def _global_window(gstate: BucketState, gcfg: GlobalConfig, gb: WindowBatch,
-                   gacc_row, now, mesh: Mesh, pallas: bool,
-                   staged: bool = False):
+                   gacc_row, now):
     """One window of GLOBAL traffic: replica reads + the reconciliation psum.
 
     The whole GLOBAL dance — the reference's async hit send plus owner
@@ -2737,60 +2585,27 @@ def _global_window(gstate: BucketState, gcfg: GlobalConfig, gb: WindowBatch,
         jnp.zeros_like(gstate.remaining), gb._replace(hits=gacc_row)
     )
     summed = lax.psum(delta, SHARD_AXIS)
-    if staged:
-        # The whole read+apply transition ladder as ONE pallas_call: the
-        # i64 arena crosses as bitcast (lo, hi) i32 pairs (Mosaic has no
-        # 64-bit vectors) and the ladder runs in exact pair arithmetic;
-        # only the leaky path's two integer divisions stay in XLA
-        # (kernel.transition_precompute) — they depend solely on pre-psum
-        # data, so hoisting them is bit-free.  fused_out: the read half
-        # comes back as the wire's gfused block i64[Bg, 4] directly.
-        from gubernator_tpu.ops.pallas_kernel import global_combined_staged
-        return global_combined_staged(gstate, gcfg, gb, summed, now,
-                                      interpret=_mesh_on_cpu(mesh),
-                                      fused_out=True)
-    # The Pallas GLOBAL apply is int64 and Mosaic has no 64-bit vectors,
-    # and unlike the serving window the GLOBAL arena is EXEMPT from the
-    # compact range caps (core/engine.py _compiled_step_compact note), so
-    # a rebased-i32 form would not be exact: interpret mode (CPU meshes)
-    # only.  GUBER_PALLAS on a TPU mesh is refused at engine construction
-    # (_check_lowering_flags), so no TPU program reaches this with pallas.
-    if pallas:
-        if not _mesh_on_cpu(mesh):
-            raise NotImplementedError(
-                "GUBER_PALLAS has no GLOBAL apply kernel for a TPU mesh: "
-                "Mosaic has no 64-bit vector types")
-        from gubernator_tpu.ops.pallas_kernel import global_apply_pallas
-        gout = kernel.global_read(gstate, gb, now)
-        new_g = global_apply_pallas(
-            gstate, gcfg, summed, now, interpret=True)
-        return new_g, gout
-    # XLA path: the replica reads (shard-varying lanes) and the post-psum
-    # apply (replicated lanes) run as two ladders.  One concatenated ladder
-    # would be bit-identical, but it taints the apply half as shard-varying
-    # and shard_map's replication check can then no longer prove the GLOBAL
+    # The replica reads (shard-varying lanes) and the post-psum apply
+    # (replicated lanes) run as two ladders.  One concatenated ladder would
+    # be bit-identical, but it taints the apply half as shard-varying and
+    # shard_map's replication check can then no longer prove the GLOBAL
     # arena's P() out_specs.
     gout = kernel.global_read(gstate, gb, now)
     return kernel.global_apply(gstate, gcfg, summed, now), gout
 
 
-def _compiled_step(mesh: Mesh):
-    return _compiled_step_impl(mesh, _use_pallas())
-
-
 @lru_cache(maxsize=None)
-def _compiled_step_impl(mesh: Mesh, pallas: bool):
+def _compiled_step(mesh: Mesh):
     def shard_fn(state, gstate, gcfg, batch, gbatch, gacc, upd, ups, now):
             # Block shapes inside shard_map: state [1, C]; batch/gbatch [1, B*];
             # gstate/gcfg [G] (replicated); upd/ups [K*] (replicated).
             st = jax.tree.map(lambda a: a[0], state)
             bt = WindowBatch(*jax.tree.map(lambda a: a[0], batch))
-            new_st, out = _window_step_fn(mesh, compact32=False, pallas=pallas,
-                                      c32xla=False)(st, bt, now)
+            new_st, out = kernel.window_step(st, bt, now)
 
             gstate, gcfg = _apply_control(gstate, gcfg, upd, ups)
             gb = WindowBatch(*jax.tree.map(lambda a: a[0], gbatch))
-            new_g, gout = _global_window(gstate, gcfg, gb, gacc[0], now, mesh, pallas)
+            new_g, gout = _global_window(gstate, gcfg, gb, gacc[0], now)
 
             expand = lambda a: a[None]
             return (
@@ -2803,10 +2618,6 @@ def _compiled_step_impl(mesh: Mesh, pallas: bool):
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        # the Pallas window kernel cannot carry vma tags through its
-        # interpret-mode while_loop (jnp.take drops them); vma checking is
-        # an XLA-path-only invariant here
-        check_vma=not pallas,
         in_specs=(
             _ARENA_SHARDED,
             _GSTATE_REPL,
@@ -2825,19 +2636,11 @@ def _compiled_step_impl(mesh: Mesh, pallas: bool):
             GlobalConfig(*[P()] * 3),
         ),
     )
-    fn = jax.jit(sharded, donate_argnums=(0, 1, 2))
-    return _recursion_guarded(fn) if pallas else fn
-
-
-def _compiled_step_compact(mesh: Mesh):
-    return _compiled_step_compact_impl(mesh, _use_pallas(),
-                                       _use_compact32_xla(),
-                                       _use_pallas_fused())
+    return jax.jit(sharded, donate_argnums=(0, 1, 2))
 
 
 @lru_cache(maxsize=None)
-def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
-                                c32xla: bool, fused: bool = False):
+def _compiled_step_compact(mesh: Mesh):
     """The serving fast path: compact request/response wire format.
 
     Same computation as _compiled_step, but the regular-key window crosses
@@ -2849,25 +2652,13 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
     """
     def shard_fn(state, gstate, gcfg, packed, gbatch, gacc, upd, ups, now):
         st = jax.tree.map(lambda a: a[0], state)
-        # The fused megakernel's in-kernel bitonic sort needs a power-of-two
-        # lane count; other widths fall back to the compact32-XLA drain at
-        # trace time (B is static).
-        B = packed.shape[-2]
-        if fused and (B & (B - 1)) == 0:
-            from gubernator_tpu.ops.pallas_kernel import window_step_fused
-            new_st, words, limits, _ = window_step_fused(
-                st, packed[0], now, interpret=_mesh_on_cpu(mesh))
-            enc = jnp.stack([words, limits], axis=-1)
-        else:
-            bt = kernel.decode_batch(packed[0])
-            new_st, out = _window_step_fn(mesh, compact32=True,
-                                          pallas=pallas,
-                                          c32xla=c32xla)(st, bt, now)
-            enc = kernel.encode_output_compact(out, now)
+        bt = kernel.decode_batch(packed[0])
+        new_st, out = kernel.window_step_compact32(st, bt, now)
+        enc = kernel.encode_output_compact(out, now)
 
         gstate, gcfg = _apply_control(gstate, gcfg, upd, ups)
         gb = WindowBatch(*jax.tree.map(lambda a: a[0], gbatch))
-        new_g, gout = _global_window(gstate, gcfg, gb, gacc[0], now, mesh, pallas)
+        new_g, gout = _global_window(gstate, gcfg, gb, gacc[0], now)
 
         expand = lambda a: a[None]
         gfused = jnp.stack(
@@ -2884,10 +2675,6 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        # the Pallas window kernel cannot carry vma tags through its
-        # interpret-mode while_loop (jnp.take drops them); vma checking is
-        # an XLA-path-only invariant here
-        check_vma=not (pallas or fused),
         in_specs=(
             _ARENA_SHARDED,
             _GSTATE_REPL,
@@ -2907,8 +2694,7 @@ def _compiled_step_compact_impl(mesh: Mesh, pallas: bool,
             GlobalConfig(*[P()] * 3),
         ),
     )
-    fn = jax.jit(sharded, donate_argnums=(0, 1, 2))
-    return _recursion_guarded(fn) if (pallas or fused) else fn
+    return jax.jit(sharded, donate_argnums=(0, 1, 2))
 
 
 @lru_cache(maxsize=None)
@@ -2939,17 +2725,8 @@ def _compiled_global_register(mesh: Mesh):
                    out_shardings=(repl6, repl3))
 
 
-def _compiled_pipeline_step(mesh: Mesh):
-    return _compiled_pipeline_step_impl(mesh, _use_pallas(),
-                                        _use_compact32_xla(),
-                                        _use_pallas_fused(),
-                                        _use_pallas_staged())
-
-
 @lru_cache(maxsize=None)
-def _compiled_pipeline_step_impl(mesh: Mesh, pallas: bool,
-                                 c32xla: bool, fused: bool = False,
-                                 staged: bool = False):
+def _compiled_pipeline_step(mesh: Mesh):
     """K compact serving windows in ONE device dispatch — the drain
     executable of the serving pipeline (core/pipeline.py).
 
@@ -2974,8 +2751,7 @@ def _compiled_pipeline_step_impl(mesh: Mesh, pallas: bool,
     def shard_fn(state, packed, nows):
         # Block shapes: state [1, C]; packed [K, 1, B, 2]; nows [K].
         st = jax.tree.map(lambda a: lax.squeeze(a, (0,)), state)
-        st, words, limits, mism, _ = _drain_scan(mesh, pallas, c32xla, fused,
-                                                 staged, st, packed, nows)
+        st, words, limits, mism = _drain_scan(st, packed, nows)
         expand = lambda a: a[None]
         return (
             jax.tree.map(expand, st),
@@ -2988,93 +2764,27 @@ def _compiled_pipeline_step_impl(mesh: Mesh, pallas: bool,
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        # the Pallas window kernel cannot carry vma tags through its
-        # interpret-mode while_loop (jnp.take drops them); vma checking is
-        # an XLA-path-only invariant here
-        check_vma=not (pallas or fused),
         in_specs=(_ARENA_SHARDED, stackedP, P()),
         out_specs=(_ARENA_SHARDED, stackedP, stackedP, stackedP),
     )
-    fn = jax.jit(sharded, donate_argnums=(0,))
-    return _recursion_guarded(fn) if (pallas or fused) else fn
+    return jax.jit(sharded, donate_argnums=(0,))
 
 
-def _staged_active(fused: bool, staged: bool, B: int) -> bool:
-    """GUBER_PALLAS_STAGED acts only where the fused megakernel does: the
-    flag is on, GUBER_PALLAS_FUSED is on, and the lane count is a power of
-    two (the in-kernel bitonic sort's requirement)."""
-    return staged and fused and (B & (B - 1)) == 0
-
-
-def _drain_scan(mesh: Mesh, pallas: bool, c32xla: bool, fused: bool,
-                staged: bool, st: ArenaPlanes, packed, nows,
-                tenants=None, tenant_slots: int = 0):
+def _drain_scan(st: ArenaPlanes, packed, nows):
     """The drain's regular-key K windows (shared by the regular and the
     GLOBAL-composed drain executables): K compact windows applied
-    sequentially to one shard's block, each window's decode→transition→
-    word-encode either fused into ONE pallas_call or lowered per-op by
-    compact32-XLA.  With `staged` the K windows collapse further: the
-    lax.scan of single-window megakernels becomes ONE pallas_call with a
-    K-major grid dimension whose aliased plane outputs carry the arena
-    across grid steps — the drain traces to a single kernel.  When
-    `tenants` is given (staged only), the per-drain analytics reductions
-    (dense/tenant/header sums) accumulate inside that same kernel and
-    come back as `dstats` (see ops/analytics.py staged_stats_tail).
-    Returns (state, words[K,B], limits[K,B], mism[K], dstats-or-None)."""
-    # Fused megakernel needs a power-of-two lane count for its in-kernel
-    # bitonic sort; other widths fall back to compact32-XLA (B static).
-    B = packed.shape[-2]
-    use_fused = fused and (B & (B - 1)) == 0
-    use_staged = _staged_active(fused, staged, B)
-
-    if use_staged:
-        from gubernator_tpu.ops.pallas_kernel import (
-            fused_state_from_planes,
-            fused_state_to_planes,
-            window_drain_fused_planes,
-        )
-        st32, words, limits, mism, dstats = window_drain_fused_planes(
-            fused_state_to_planes(st), lax.squeeze(packed, (1,)), nows,
-            interpret=_mesh_on_cpu(mesh),
-            tenants=tenants, tenant_slots=tenant_slots)
-        return (fused_state_from_planes(st32, st), words, limits, mism,
-                dstats)
-
+    sequentially to one shard's block, each decode → window_step_compact32
+    → word-encode.  Returns (state, words[K,B], limits[K,B], mism[K])."""
     def body(st, xs):
         pk, now = xs
         bt = kernel.decode_batch(pk[0])
-        st, out = _window_step_fn(mesh, compact32=True, pallas=pallas,
-                                  c32xla=c32xla)(st, bt, now)
+        st, out = kernel.window_step_compact32(st, bt, now)
         word = kernel.encode_output_word(out, now)
         mism = jnp.any((out.limit != bt.limit) & (bt.slot >= 0))
         return st, (word, out.limit, mism)
 
-    if use_fused:
-        # decode, sort, prep, transitions, commit AND the word encode
-        # all happen inside ONE pallas_call per window — O(1) executed
-        # kernels instead of the XLA drain's per-op launches.  The
-        # arena converts to its i32 plane form ONCE per drain and the
-        # scan carries that form, so the O(C) conversion amortizes
-        # over all K windows.
-        from gubernator_tpu.ops.pallas_kernel import (
-            fused_state_from_planes,
-            fused_state_to_planes,
-            window_step_fused_planes,
-        )
-        on_cpu = _mesh_on_cpu(mesh)
-
-        def body32(st32, xs):
-            pk, now = xs
-            st32, word, limit, mism = window_step_fused_planes(
-                st32, pk[0], now, interpret=on_cpu)
-            return st32, (word, limit, mism)
-
-        st32, (words, limits, mism) = lax.scan(
-            body32, fused_state_to_planes(st), (packed, nows))
-        st = fused_state_from_planes(st32, st)
-    else:
-        st, (words, limits, mism) = lax.scan(body, st, (packed, nows))
-    return st, words, limits, mism, None
+    st, (words, limits, mism) = lax.scan(body, st, (packed, nows))
+    return st, words, limits, mism
 
 
 @lru_cache(maxsize=None)
@@ -3086,8 +2796,8 @@ def _compiled_analytics_reduce(mesh: Mesh, depth: int, width: int,
     (packed, words, tenants) into the resident count-min sketch (donated
     carry) and emit one flat stats row.  Deliberately NOT part of the
     drain builders: keyed only on geometry, it composes unchanged with
-    every drain lowering (compact32-XLA, fused Pallas, GLOBAL-composed
-    mesh) and leaves their jaxprs byte-identical when analytics is off."""
+    the standalone drain and leaves its jaxpr byte-identical when
+    analytics is off."""
     from gubernator_tpu.ops import analytics as ops_analytics
 
     def shard_fn(sketch, exp_lo, exp_hi, packed, words, tenants, now, decay):
@@ -3111,32 +2821,18 @@ def _compiled_analytics_reduce(mesh: Mesh, depth: int, width: int,
     return jax.jit(sharded, donate_argnums=(0,))
 
 
-def _compiled_pipeline_step_global(mesh: Mesh, analytics=None):
-    return _compiled_pipeline_step_global_impl(mesh, _use_pallas(),
-                                               _use_compact32_xla(),
-                                               _use_pallas_fused(),
-                                               _use_pallas_staged(),
-                                               analytics)
-
-
 @lru_cache(maxsize=None)
-def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
-                                        c32xla: bool, fused: bool = False,
-                                        staged: bool = False,
-                                        analytics=None):
+def _compiled_pipeline_step_global(mesh: Mesh, analytics=None):
     """The mesh serving drain: _compiled_pipeline_step's K-scan PLUS one
     GLOBAL reconciliation window composed around it — the lockstep tick's
     single executable.
 
-    Every chip runs the fused (or compact32-XLA) kernel per window over
-    its own plane-arena shard, and the whole drain pays exactly ONE
-    collective: the GLOBAL hit-delta psum of `_global_window`, applied
-    once at the drain's timestamp (nows[0]; the lockstep tick stages all
-    K windows at the tick time, so there is nothing later to order
-    against).  This replaces the legacy mesh path's per-stage kernels and
-    per-window psum — the drain's cost model becomes
-    (K pallas_calls + one GLOBAL window) / K windows, against the legacy
-    step's ~hundreds of launches per window.
+    Every chip runs window_step_compact32 per window over its own
+    plane-arena shard, and the whole drain pays exactly ONE collective:
+    the GLOBAL hit-delta psum of `_global_window`, applied once at the
+    drain's timestamp (nows[0]; the lockstep tick stages all K windows at
+    the tick time, so there is nothing later to order against), where the
+    legacy mesh step pays a psum per window.
 
     GLOBAL lanes keep the FULL wire format (they are few — Bg per shard —
     and exempt from the compact saturation rules); the control plane is
@@ -3160,30 +2856,15 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
         # [1, Bg]; gstate/gcfg [G] (replicated); upd [Kg] (replicated);
         # nows [K]; analytics extras: sketch [1, D, W]; tenants [K, 1, B];
         # decay [].
-        # Squeezes, not [0]-indexing: each a[0] traces as slice+squeeze (2
-        # census equations per leaf) where squeeze alone is 1 — the staged
-        # ladder's budget counts every surviving op, and the shard_map
-        # block-unpack glue is most of what remains around the kernels.
         sq = lambda a: lax.squeeze(a, (0,))
         sq1 = lambda a: lax.squeeze(a, (1,))
         st = jax.tree.map(sq, state)
-        # With staged analytics the drain kernel itself accumulates the
-        # dense/tenant/header sums (dstats) while it drains — the stats
-        # tail below then only runs the one-kernel sketch/top-k finish.
-        use_staged = _staged_active(fused, staged, packed.shape[-2])
-        drain_tenants, drain_slots = None, 0
-        if analytics is not None and use_staged:
-            drain_tenants, drain_slots = sq1(an[1]), analytics[2]
-        st, words, limits, mism, dstats = _drain_scan(
-            mesh, pallas, c32xla, fused, staged, st, packed, nows,
-            tenants=drain_tenants, tenant_slots=drain_slots)
+        st, words, limits, mism = _drain_scan(st, packed, nows)
 
         gstate, gcfg = _apply_config(gstate, gcfg, upd)
         gb = WindowBatch(*jax.tree.map(sq, gbatch))
-        new_g, gout = _global_window(gstate, gcfg, gb, sq(gacc), nows[0],
-                                     mesh, pallas, staged=use_staged)
-        # staged hands back the gfused wire block straight from the kernel
-        gfused = gout if use_staged else jnp.stack(
+        new_g, gout = _global_window(gstate, gcfg, gb, sq(gacc), nows[0])
+        gfused = jnp.stack(
             [gout.status.astype(jnp.int64), gout.limit, gout.remaining,
              gout.reset_time], axis=-1)
 
@@ -3202,21 +2883,11 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
             sketch, tenants, decay = an
             # the occupancy counts read all C expiries: joined here only
             expire = kernel.join64(st.expire_lo, st.expire_hi)
-            if dstats is not None:
-                from gubernator_tpu.ops.pallas_kernel import (
-                    staged_stats_finish,
-                )
-                sk, stats = staged_stats_finish(
-                    sq(sketch), dstats, expire, nows[0], decay,
-                    tenant_slots=tenant_slots, topk=topk,
-                    over_weight=over_weight,
-                    interpret=_mesh_on_cpu(mesh))
-            else:
-                from gubernator_tpu.ops import analytics as ops_analytics
-                sk, stats = ops_analytics.shard_stats(
-                    sq(sketch), sq1(packed), words, sq1(tenants), expire,
-                    nows[0], decay, tenant_slots=tenant_slots, topk=topk,
-                    over_weight=over_weight)
+            from gubernator_tpu.ops import analytics as ops_analytics
+            sk, stats = ops_analytics.shard_stats(
+                sq(sketch), sq1(packed), words, sq1(tenants), expire,
+                nows[0], decay, tenant_slots=tenant_slots, topk=topk,
+                over_weight=over_weight)
             outs = outs + (sk[None], stats[None])
         return outs
 
@@ -3248,24 +2919,14 @@ def _compiled_pipeline_step_global_impl(mesh: Mesh, pallas: bool,
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        # the Pallas window kernel cannot carry vma tags through its
-        # interpret-mode while_loop (jnp.take drops them); vma checking is
-        # an XLA-path-only invariant here
-        check_vma=not (pallas or fused),
         in_specs=in_specs,
         out_specs=out_specs,
     )
-    fn = jax.jit(sharded, donate_argnums=donate)
-    return _recursion_guarded(fn) if (pallas or fused) else fn
-
-
-def _compiled_multi_step(mesh: Mesh, with_global: bool = True):
-    return _compiled_multi_step_impl(mesh, _use_pallas(), with_global)
+    return jax.jit(sharded, donate_argnums=donate)
 
 
 @lru_cache(maxsize=None)
-def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
-                              with_global: bool = True):
+def _compiled_multi_step(mesh: Mesh, with_global: bool = True):
     """K batching windows applied in ONE device dispatch via lax.scan.
 
     Each scanned iteration is a full serving window — its own timestamp, its
@@ -3285,8 +2946,7 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
     one past the arena), yet the composed executable still ran the whole
     GLOBAL sub-window — gathers, scatters and a psum per scanned iteration
     — just to produce an all-dropped output block.  Statically skipping it
-    removes those kernels per window (the round-5 calibration showed the
-    window cost is per-executed-kernel overhead); the fused output keeps
+    removes those ops from every window; the fused output keeps
     its [K, B+Bg, 4] shape (GLOBAL rows zero-filled) so every decode path
     is unchanged.  step_windows picks the variant from host-staged
     inertness, single-process only — a per-process data-dependent
@@ -3303,8 +2963,7 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
             st, gst = carry
             b, gb, gacc, now = xs
             bt = WindowBatch(*jax.tree.map(lambda a: a[0], b))
-            st, out = _window_step_fn(mesh, compact32=False, pallas=pallas,
-                                      c32xla=False)(st, bt, now)
+            st, out = kernel.window_step(st, bt, now)
             if not with_global:
                 o = jnp.stack([out.status.astype(jnp.int64), out.limit,
                                out.remaining, out.reset_time], axis=-1)
@@ -3313,7 +2972,7 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
                     [o, jnp.zeros((Bg, 4), jnp.int64)], axis=0)
                 return (st, gst), fused
             gbt = WindowBatch(*jax.tree.map(lambda a: a[0], gb))
-            gst, gout = _global_window(gst, gcfg, gbt, gacc[0], now, mesh, pallas)
+            gst, gout = _global_window(gst, gcfg, gbt, gacc[0], now)
             return (st, gst), kernel.pack_outputs(out, gout)
 
         (st, gst), fused = lax.scan(
@@ -3332,10 +2991,6 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
     sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
-        # the Pallas window kernel cannot carry vma tags through its
-        # interpret-mode while_loop (jnp.take drops them); vma checking is
-        # an XLA-path-only invariant here
-        check_vma=not pallas,
         in_specs=(
             _ARENA_SHARDED,
             _GSTATE_REPL,
@@ -3354,5 +3009,4 @@ def _compiled_multi_step_impl(mesh: Mesh, pallas: bool,
             GlobalConfig(*[P()] * 3),
         ),
     )
-    fn = jax.jit(sharded, donate_argnums=(0, 1, 2))
-    return _recursion_guarded(fn) if pallas else fn
+    return jax.jit(sharded, donate_argnums=(0, 1, 2))

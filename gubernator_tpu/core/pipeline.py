@@ -57,7 +57,7 @@ natural accumulation that happens while the pipeline is at depth.
 
 Mesh (lockstep) serving runs the SAME drain: the tick's drain executable is
 the GLOBAL-composed variant (engine.pipeline_dispatch_global) — every chip
-runs the fused kernel per window over its own plane-arena shard, with ONE
+runs the compact32 window body over its own plane-arena shard, with ONE
 GLOBAL reconciliation psum composed around the K-scan per drain — so mesh
 mode gets the same one-dispatch-per-drain, overlapped-fetch structure as a
 single chip, and GLOBAL singles ride the drain's composed window
@@ -622,9 +622,8 @@ class _DrainResult:
         self.committed = 0.0
         self.oldest_enq = 0.0
         # devprof attribution: which executable family served this drain
-        # (composed_analytics / composed_drain / fused_window /
-        # compact32_xla — the same arm names scripts/probe_census.py
-        # counts), and the shared stacked-fetch window when the drain
+        # (composed_analytics / composed_drain / compact32_xla: devprof's
+        # arm names), and the shared stacked-fetch window when the drain
         # committed through a deferred-fetch chain (satellite span +
         # chain_fetch stage; 0.0 = not chained)
         self.arm = ""
@@ -663,7 +662,7 @@ class DispatchPipeline:
         # None: the disabled serving path pays exactly ONE attribute check
         # per DRAIN (not per request) and dispatches nothing extra — the
         # drain executables are byte-identical either way
-        # (tests/test_analytics.py census).
+        # (tests/test_analytics.py).
         self.analytics = analytics
         self.slo = slo
         # observability: span recorder (None = tracing off everywhere) and
@@ -727,9 +726,8 @@ class DispatchPipeline:
         # lockstep engine whose shards are all this process's own.
         self.rpc_enabled = self.enabled and not engine.multiprocess
         # always-on per-executable window clock (observability/devprof.py):
-        # dispatch→fetch-ready wall time per drain, labelled by the arm the
-        # census probe counts.  None (no metrics) keeps the commit path at
-        # one attribute check.
+        # dispatch→fetch-ready wall time per drain, labelled by its arm.
+        # None (no metrics) keeps the commit path at one attribute check.
         self.devclock = None
         if metrics is not None:
             from gubernator_tpu.observability.devprof import WindowClock
@@ -799,19 +797,6 @@ class DispatchPipeline:
         # composed GLOBAL window, never mixed into regular ListJobs
         self._gsingles: List[tuple] = []  # (req, fut)
         self._jobs: List[object] = []     # FIFO of RpcJob/ListJob
-        # fused-path adoption (observability): does this engine's drain
-        # lower to the fused megakernel?  Read once — same build-time
-        # discipline as the engine's compiled-builder cache keys.
-        from gubernator_tpu.core.engine import _use_pallas_staged
-        from gubernator_tpu.ops.pallas_kernel import fused_enabled
-        B = engine.batch_per_shard
-        self.fused_serving = fused_enabled(False) and (B & (B - 1)) == 0
-        # staged drain (ISSUE 17): the fused windows further collapse into
-        # ONE K-grid pallas_call plus the pair-GLOBAL and analytics
-        # finisher kernels — single-digit kernels/window.  Same read-once
-        # build-time discipline: the engine's compiled builders key on the
-        # same flag, so this mirrors what the drains actually lower to.
-        self.staged_serving = self.fused_serving and _use_pallas_staged()
         self._in_flight = 0
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         # observability: RPCs fully served by this lane (tests assert the
@@ -2177,8 +2162,6 @@ class DispatchPipeline:
             # to a differently-shaped dispatch.
             an_args = (self._analytics_stage(res, packed, K, now)
                        if self.analytics is not None else None)
-            # devprof arm: which census executable family this dispatch
-            # lowers to (scripts/probe_census.py's arm names)
             res.arm = ("composed_analytics" if an_args is not None
                        else "composed_drain")
             res.lanes = B
@@ -2246,8 +2229,7 @@ class DispatchPipeline:
                 res.stats = stats
                 res.an_decay = an_args[1]
         elif k_used:  # an all-forwarded drain has nothing to dispatch
-            res.arm = ("fused_window" if self.fused_serving
-                       else "compact32_xla")
+            res.arm = "compact32_xla"
             kb = next(b for b in self._k_buckets if b >= k_used)
             res.lanes = lanes = self._drain_lanes(fills, k_used)
             # the C router stages a shard's lanes as a prefix, so the

@@ -204,26 +204,6 @@ class Instance:
         pipeline.rpc_enabled — see server.py / core/pipeline.py)."""
         return not self.mesh_mode and self._picker.size() == 0
 
-    def _publish_census(self) -> None:
-        """Set guber_tpu_kernels_per_window from the census table
-        (observability/devprof.py) — the arm matching this instance's
-        serving mode.  The census is a property of the traced program,
-        but tracing the arms costs seconds, so only the daemon boot runs
-        this (on a background thread, off the serving path); embedded /
-        in-process-cluster instances leave the gauge to the admin kernels
-        endpoint, which refreshes it on access.  Best-effort:
-        observability must never take the service down."""
-        try:
-            from gubernator_tpu.observability.devprof import census_table
-            table = census_table()
-            arm = ("composed_analytics" if self.analytics is not None
-                   else "composed_drain")
-            kpw = table.get(arm) or table.get("composed_drain")
-            if kpw:
-                self.metrics.kernels_per_window.set(kpw)
-        except Exception:  # noqa: BLE001 — telemetry, not serving
-            log.debug("census gauge publish failed", exc_info=True)
-
     # ------------------------------------------------------------ public API
 
     def add_to_server(self, server, *, v1: bool = True,

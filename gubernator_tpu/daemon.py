@@ -199,16 +199,6 @@ class Daemon:
             await self.grpc.start()
             log.info("gRPC listening on %s", self.grpc.address)
 
-        # Kernel-ladder scoreboard: publish guber_tpu_kernels_per_window
-        # at boot so operators see the ladder height without running
-        # bench.  Tracing the census arms costs seconds, so it runs off
-        # the serving path on a daemon thread — and only here, in the
-        # long-running daemon: embedded instances (in-process clusters,
-        # tests) leave the gauge to the admin kernels endpoint.
-        import threading
-        threading.Thread(target=self.instance._publish_census,
-                         name="guber-census", daemon=True).start()
-
         static_peers = os.environ.get("GUBER_STATIC_PEERS", "")
         if mesh_peers is not None:
             # mesh membership is fixed by process rank; discovery backends

@@ -1,10 +1,8 @@
 """Device-time flight recorder: measured kernel attribution for the
 serving window.
 
-The kernel census (`scripts/probe_census.py`) counts executed kernels
-from the traced jaxpr — a box-independent program property — but cannot
-say which kernels own the ~0.15 ms/kernel dispatch wall.  This module is
-the measurement side of that reconciliation (ROADMAP item 1):
+What the device spent, by kernel and by serving arm, read from profiler
+captures of the running system:
 
   * `parse_run_dir` / `load_trace_events` — read the `.xplane.pb` files
     a `jax.profiler` capture leaves under its run dir (through
@@ -15,7 +13,7 @@ the measurement side of that reconciliation (ROADMAP item 1):
     serving arm by the `guber_*` trace annotations the engine stamps
     around dispatch/fetch/analytics (core/engine.py);
   * `KernelTable` — a rolling fold of those rows, normalized to
-    ms/window, joined against the SAME arm classes the census counts;
+    ms/window per arm;
   * `WindowClock` — the always-on dispatch→fetch-ready clock the
     pipeline feeds per drain (EWMA + `guber_tpu_device_window_ms{arm}`
     histogram; disabled path = one attribute check) with a bounded ring
@@ -24,10 +22,10 @@ the measurement side of that reconciliation (ROADMAP item 1):
   * `DevprofController` — the `GUBER_DEVPROF=periodic` continuous mode:
     a shedding background thread that re-arms an N-drain capture,
     parses, folds into the rolling table, and discards the trace dir;
-  * `build_census_arms` / `measure_census_arms` — the five census arm
-    programs as runnable specs, so the census count and the measured
-    ms/window for one arm come from the SAME traced program
-    (probe_census.py and the tier-1 devprof suite both build from here).
+  * `build_probe_arms` / `measure_probe_arms` — the serving arms as
+    runnable specs over a tiny probe engine, each run under an arm-scoped
+    capture (`GET /v1/admin/kernels?measure=1`, the admin plane's offline
+    probe).
 
 Malformed or empty traces degrade to a logged no-op — a broken capture
 must never fail a request or a bench run.
@@ -47,11 +45,11 @@ from gubernator_tpu.config import env_float, env_int
 
 log = logging.getLogger("gubernator.devprof")
 
-# serving-arm vocabulary: the census arm classes (probe_census.py) plus
-# the runtime-only buckets the trace annotations distinguish
+# serving-arm vocabulary: the executables the engine dispatches plus the
+# runtime-only buckets the trace annotations distinguish
 ARM_DRAIN = "composed_drain"
 ARM_ANALYTICS = "composed_analytics"
-ARM_FUSED = "fused_window"
+ARM_STEP = "legacy_step"
 ARM_FETCH = "fetch"
 ARM_OTHER = "xla_shoulder"
 
@@ -61,7 +59,7 @@ ANNOTATION_ARMS: Tuple[Tuple[str, str], ...] = (
     ("guber_analytics", ARM_ANALYTICS),
     ("guber_fetch", ARM_FETCH),
     ("guber_drain", ARM_DRAIN),
-    ("guber_window", ARM_FUSED),
+    ("guber_window", ARM_STEP),
 )
 
 # host-side scaffolding that must not masquerade as device kernels in the
@@ -139,8 +137,8 @@ def self_times(events: List[dict],
 
     Self time = duration minus same-track nested children, so a fusion
     inside an executable wrapper counts once.  Arm attribution: the
-    `arm_hint` when the whole capture is arm-scoped (measured census
-    probe), else the narrowest `guber_*` annotation interval covering the
+    `arm_hint` when the whole capture is arm-scoped (measure_probe_arms),
+    else the narrowest `guber_*` annotation interval covering the
     event midpoint — annotations and kernels land on DIFFERENT threads
     (the annotation on the engine thread, the kernel on the runtime's
     executor), and drains serialize on one engine thread, so time-window
@@ -236,8 +234,7 @@ class KernelTable:
         return len(rows)
 
     def ms_per_window(self) -> Dict[str, float]:
-        """Measured ms/window decomposition per arm — the table the
-        census's kernels/window is reconciled against."""
+        """Measured ms/window decomposition per arm."""
         with self._lock:
             if not self._windows:
                 return {}
@@ -269,7 +266,7 @@ class KernelTable:
 class WindowClock:
     """Always-on per-executable window clock: the pipeline feeds one
     dispatch→fetch-ready observation per drain, keyed by the executable
-    arm (fused_window / composed_drain / composed_analytics).  Keeps a
+    arm (compact32_xla / composed_drain / composed_analytics).  Keeps a
     per-arm EWMA, feeds the `guber_tpu_device_window_ms{arm}` histogram,
     and records slow windows into a bounded ring WITH the trace-ID
     exemplars of the requests that rode them — the p99 link back to a
@@ -475,20 +472,12 @@ class Devprof:
             out["controller"] = self.controller.status()
         return out
 
-    def kernels_snapshot(self, census: Optional[dict] = None,
-                         top: int = 50) -> dict:
-        """The `/v1/admin/kernels` payload: census count × measured ms
-        side-by-side per arm, plus the rolling kernel table and the
-        window clock."""
+    def kernels_snapshot(self, top: int = 50) -> dict:
+        """The `/v1/admin/kernels` payload: measured ms/window per arm,
+        plus the rolling kernel table and the window clock."""
         table = self.table.snapshot(top=top)
-        measured = table["ms_per_window"]
-        arms = {}
-        for arm in sorted(set(list(measured) + list(census or {}))):
-            arms[arm] = {
-                "census_kernels_per_window":
-                    (census or {}).get(arm),
-                "measured_ms_per_window": measured.get(arm),
-            }
+        arms = {arm: {"measured_ms_per_window": ms}
+                for arm, ms in sorted(table["ms_per_window"].items())}
         out = {"arms": arms, "table": table["rows"],
                "windows": table["windows"]}
         if self.clock is not None:
@@ -498,17 +487,14 @@ class Devprof:
         return out
 
 
-# ------------------------------------------------- census arms, measured pass
+# --------------------------------------------------- probe arms, measured pass
 
 
-def build_census_arms(k: int = 8):
-    """The serving-arm programs the kernel census counts
-    (probe_census.py), as runnable specs over a tiny single-device probe
-    engine: [{name, fn, args, windows, measure_fn}].  `fn` is what the
-    census traces (identical numbers to the historical probe); the
-    measured pass compiles `measure_fn` (only fused_window differs — the
-    Pallas megakernel needs interpret mode off-TPU) and runs it under a
-    real `jax.profiler` capture."""
+def build_probe_arms(k: int = 8):
+    """The serving-arm programs as runnable specs over a tiny
+    single-device probe engine: [{name, fn, args, windows}], for
+    measure_probe_arms to compile and run under a `jax.profiler`
+    capture."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -516,7 +502,7 @@ def build_census_arms(k: int = 8):
     from gubernator_tpu.config import AnalyticsConfig
     from gubernator_tpu.core import engine as em
     from gubernator_tpu.core.engine import RateLimitEngine
-    from gubernator_tpu.ops import kernel, pallas_kernel as pk
+    from gubernator_tpu.ops import kernel
     from gubernator_tpu.parallel.mesh import make_mesh
 
     t0 = 1_700_000_000_000
@@ -536,38 +522,25 @@ def build_census_arms(k: int = 8):
         return kernel.window_step(state, kernel.decode_batch(packed), now)
 
     def c32(state, packed, now):
-        st, out = pk.window_step_compact32_xla(
+        st, out = kernel.window_step_compact32(
             state, kernel.decode_batch(packed), now)
         return st, kernel.encode_output_word(out, now)
-
-    def fusedw(state, packed, now):
-        return pk.window_step_fused(state, packed, now, interpret=False)
-
-    interp = jax.default_backend() != "tpu"
-
-    def fusedw_measure(state, packed, now):
-        return pk.window_step_fused(state, packed, now, interpret=interp)
 
     packed = np.zeros((k, s, b, 2), np.int64)
     nows = np.full(k, t0, np.int64)
     gb, ga, upd = eng.empty_drain_control()
-    fdrain = em._compiled_pipeline_step_global_impl(eng.mesh, False, True,
-                                                    True, True)
+    fdrain = em._compiled_pipeline_step_global(eng.mesh)
     conf = AnalyticsConfig()
     eng.enable_analytics(conf)
-    geom = (conf.sketch_depth, conf.sketch_width, conf.tenant_slots,
-            conf.topk, conf.over_weight)
-    fan = em._compiled_pipeline_step_global_impl(eng.mesh, False, True, True,
-                                                 True, geom)
+    fan = em._compiled_pipeline_step_global(
+        eng.mesh, (conf.sketch_depth, conf.sketch_width, conf.tenant_slots,
+                   conf.topk, conf.over_weight))
     ten = np.zeros((k, s, b), np.int32)
 
     # mixed-algorithm composed window: every wire algorithm (token, leaky,
-    # GCRA, sliding-window, concurrency) live in ONE packed window's lanes.
-    # The census is data-independent, so this arm traces the SAME program
-    # as composed_drain — which is the point the scoreboard makes: the
-    # algorithm plane rides the ladder as select-chain depth, not extra
-    # kernels.  The measured pass drives real mixed-algorithm lanes
-    # through all five transition ladders.
+    # GCRA, sliding-window, concurrency) live in ONE packed window's lanes,
+    # so the measured pass drives all five transition ladders through the
+    # same program as composed_drain.
     lane = np.arange(b, dtype=np.int64)
     mix1 = kernel.encode_batch_host(
         lane % eng.capacity_per_shard, np.ones(b, np.int64),
@@ -582,33 +555,28 @@ def build_census_arms(k: int = 8):
                 nows)
     an_args = drain_args + (eng._an_sketch, ten, jnp.int64(0))
     return [
-        {"name": "int64_xla", "fn": xla64, "args": one, "windows": 1,
-         "measure_fn": xla64},
-        {"name": "compact32_xla", "fn": c32, "args": one_arena, "windows": 1,
-         "measure_fn": c32},
-        {"name": "fused_window", "fn": fusedw, "args": one_arena,
-         "windows": 1, "measure_fn": fusedw_measure},
+        {"name": "int64_xla", "fn": xla64, "args": one, "windows": 1},
+        {"name": "compact32_xla", "fn": c32, "args": one_arena, "windows": 1},
         {"name": "composed_drain", "fn": fdrain, "args": drain_args,
-         "windows": k, "measure_fn": fdrain},
+         "windows": k},
         {"name": "composed_mixed_algos", "fn": fdrain, "args": mix_args,
-         "windows": k, "measure_fn": fdrain},
+         "windows": k},
         {"name": "composed_analytics", "fn": fan, "args": an_args,
-         "windows": k, "measure_fn": fan},
+         "windows": k},
     ]
 
 
-def measure_census_arms(arms=None, iters: int = 2,
-                        table: Optional[KernelTable] = None) -> dict:
-    """Compile each census arm, warm it, run `iters` iterations under an
+def measure_probe_arms(arms=None, iters: int = 2,
+                       table: Optional[KernelTable] = None) -> dict:
+    """Compile each probe arm, warm it, run `iters` iterations under an
     arm-scoped `jax.profiler` capture, and parse the trace into measured
-    ms/window — the join key is the arm NAME, so every census kernel
-    class gets a measured entry from a real parsed trace.  Returns
+    ms/window, keyed by the arm's name.  Returns
     {"arms": {name: {...}}, "kernel_table": snapshot} and folds into
     `table` when given (the Instance's rolling table)."""
     import jax
 
     if arms is None:
-        arms = build_census_arms()
+        arms = build_probe_arms()
     if table is None:
         table = KernelTable()
     measured: Dict[str, dict] = {}
@@ -617,7 +585,7 @@ def measure_census_arms(arms=None, iters: int = 2,
     jits: Dict[int, object] = {}
     for spec in arms:
         name, windows = spec["name"], spec["windows"]
-        fn = spec.get("measure_fn") or spec["fn"]
+        fn = spec["fn"]
         jf = jits.get(id(fn))
         if jf is None:
             jf = jits[id(fn)] = jax.jit(fn)
@@ -645,27 +613,3 @@ def measure_census_arms(arms=None, iters: int = 2,
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
     return {"arms": measured, "kernel_table": table.snapshot()}
-
-
-_census_cache: Optional[Dict[str, float]] = None
-_census_lock = threading.Lock()
-
-
-def census_table(refresh: bool = False) -> Dict[str, float]:
-    """Per-arm census kernels/window (cached — tracing five arms costs
-    seconds, and the census only changes when the program does)."""
-    global _census_cache
-    with _census_lock:
-        if _census_cache is not None and not refresh:
-            return _census_cache
-        import jax
-
-        from gubernator_tpu.ops import pallas_kernel as pk
-
-        out: Dict[str, float] = {}
-        for spec in build_census_arms():
-            total = pk.kernel_census(
-                jax.make_jaxpr(spec["fn"])(*spec["args"]))
-            out[spec["name"]] = round(total / spec["windows"], 1)
-        _census_cache = out
-        return out

@@ -8,8 +8,8 @@ summaries — every number from the same accessors the control loops read,
 so what the operator sees is what the controllers saw.
 
 `ProfileCapture` records the next N pipeline drains under
-`jax.profiler.start_trace/stop_trace` (the GUBER_PROFILE plumbing from
-bench.py, armable at runtime via `POST /v1/admin/profile`).  The profiler
+`jax.profiler.start_trace/stop_trace` (armable at runtime via
+`POST /v1/admin/profile`).  The profiler
 is started and stopped on a thread of the capture's own: the stop writes
 the trace for seconds, and the single engine thread must not sit through
 it.  The engine thread only counts the armed drains down.
@@ -56,7 +56,7 @@ class ProfileCapture:
 
     def arm(self, drains: int, trace_dir: str = "") -> dict:
         """Start a capture of the next `drains` dispatches.  Default
-        directory comes from GUBER_PROFILE (bench.py's knob) or a
+        directory comes from GUBER_PROFILE or a
         timestamped /tmp path."""
         trace_dir = (trace_dir or os.environ.get("GUBER_PROFILE", "")
                      or f"/tmp/guber-profile-{int(time.time())}")
@@ -220,8 +220,6 @@ def build_debug_snapshot(instance) -> dict:
             "rpc_served": pipe.rpc_served,
             "decisions_staged": pipe.decisions_staged,
             "lanes_staged": pipe.lanes_staged,
-            "fused_serving": pipe.fused_serving,
-            "staged_serving": pipe.staged_serving,
             "lockstep": pipe.lockstep,
             "depth": pipe.depth,
             "overlap": pipe.overlap_snapshot(),

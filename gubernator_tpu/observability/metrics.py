@@ -191,18 +191,6 @@ class Metrics:
             "Wall time of one device window step.",
             registry=self.registry,
         )
-        # kernel-ladder scoreboard (daemon boot + the devprof admin
-        # endpoint): executed-kernel census of the composed serving
-        # arm, kernels per window.  A property of the traced program — the
-        # same number on every box — so a step in this gauge across a
-        # deploy IS a serving-ladder regression (scripts/bench_compare.py
-        # gates the same census absolutely)
-        self.kernels_per_window = Gauge(
-            "guber_tpu_kernels_per_window",
-            "Executed-kernel census of the composed serving window "
-            "(traced-program property; lower is better).",
-            registry=self.registry,
-        )
         # overlapped drain pipeline (core/pipeline.py): concurrent drains in
         # flight (the overlap ratio and the arena ring's reuse counts are
         # in /v1/admin/debug, pipeline.overlap)
@@ -222,7 +210,7 @@ class Metrics:
         )
         # device-time flight recorder (observability/devprof.py): the
         # always-on dispatch->fetch-ready window clock per executable arm
-        # (fused_window / composed_drain / composed_analytics; its EWMA is
+        # (compact32_xla / composed_drain / composed_analytics; its EWMA is
         # in /v1/admin/debug, devprof.clock) and the continuous-mode
         # capture outcomes
         self.device_window_ms = Histogram(

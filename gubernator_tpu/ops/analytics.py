@@ -6,9 +6,9 @@ initialized a bucket — it just throws them away after encoding the
 response words.  `shard_stats` is a second, tiny executable over the SAME
 arrays the drain consumed/produced (the compact request stack and the
 response words of `engine.pipeline_dispatch`, plus the resident expiry
-plane), so it composes with every drain lowering unchanged: compact32-XLA,
-the fused Pallas megakernel, and the mesh's GLOBAL-composed drain all feed
-it the identical (packed, words) pair.  Per shard it accumulates:
+plane), so it composes with both drains unchanged: the standalone drain
+and the mesh's GLOBAL-composed drain feed it the identical (packed, words)
+pair.  Per shard it accumulates:
 
   * outcome counts — occupied lanes, total hits, under/over-limit, inits
     (arena churn), plus post-drain live/expired slot counts from the
@@ -125,8 +125,8 @@ def shard_stats(sketch, packed, words, tenants, expire, now, decay, *,
     d = _decode(jnp, packed, words)
     cslot = jnp.clip(d.slot, 0, C - 1).ravel()
 
-    # Dense per-slot aggregation of THIS drain (O(C) scratch, like the
-    # fused path's plane conversion — amortized over all K windows).
+    # Dense per-slot aggregation of THIS drain (O(C) scratch, amortized
+    # over all K windows).
     # ONE [C, 3] scatter-add instead of three [C] ones: integer adds are
     # exact and per-column independent, so the split arrays are
     # bit-identical to the oracle's three np.add.at passes — at a third
@@ -178,71 +178,6 @@ def shard_stats(sketch, packed, words, tenants, expire, now, decay, *,
     over = d.over.sum()
     header = jnp.stack([
         lanes, d.hits.sum(), lanes - over, over, d.is_init.sum(),
-        jnp.sum((expire > now).astype(jnp.int64)),
-        jnp.sum(((expire != 0) & (expire <= now)).astype(jnp.int64)),
-        jnp.int64(0),
-    ])
-    return new_sketch, jnp.concatenate([header, trows.ravel(), cand.ravel()])
-
-
-def staged_stats_tail(sketch, drain_stats, expire, now, decay, *,
-                      tenant_slots: int, topk: int, over_weight: int):
-    """Finish the staged drain's in-kernel stats planes into the canonical
-    (new_sketch, stats vector) pair — bit-identical to `shard_stats` over
-    the same drain, with the whole per-lane decode/scatter half already
-    folded INTO the drain megakernel (ops/pallas_kernel.py
-    window_drain_fused_planes).  What remains here is only what the kernel
-    cannot or should not do: the count-min scatter (the hash lattice is a
-    pure function of the slot ids, so it traces as a numpy CONSTANT — zero
-    equations for the hashing itself), the top-k candidate ranking, and
-    the expiry-plane occupancy counts.
-
-    drain_stats: the nine i32 planes from the drain kernel —
-    (d_occ, d_over, d_hlo, d_hhi) [C], (t_occ, t_over, t_hlo, t_hhi)
-    [tenant_slots], hdr [8] = [lanes, hits_lo, hits_hi, over, init, 0,0,0].
-    Hit counts travel as exact (lo, hi) i32 pairs (see the drain kernel's
-    limb-split accumulation) and reassemble here by bitcast."""
-    d_occ, d_over, d_hlo, d_hhi, t_occ, t_over, t_hlo, t_hhi, hdr = (
-        drain_stats)
-    C = d_occ.shape[0]
-    D, W = sketch.shape
-    pair64 = lambda lo, hi: jax.lax.bitcast_convert_type(
-        jnp.stack([lo, hi], axis=-1), jnp.int64)
-    dense_h = pair64(d_hlo, d_hhi)
-    dense_o = d_over.astype(jnp.int64)
-    touched = d_occ.astype(jnp.int64)
-    dense_w = dense_h + over_weight * dense_o
-
-    # the hash lattice is data-independent — numpy at trace time, so the
-    # multiply-xorshift mix contributes ZERO jaxpr equations (the staged
-    # census budget counts every surviving op)
-    all_slots = np.arange(C, dtype=np.int64)
-    h_np = np.stack([hash_slots(np, all_slots, r, W) for r in range(D)])
-    flat_idx = (np.arange(D, dtype=np.int64)[:, None] * W + h_np).ravel()
-    flat = (sketch >> decay).ravel().at[flat_idx].add(
-        jnp.broadcast_to(dense_w, (D, C)).ravel())
-    new_sketch = flat.reshape(D, W)
-    est = jnp.min(jnp.take_along_axis(new_sketch, jnp.asarray(h_np),
-                                      axis=1), axis=0)
-
-    score = jnp.where(touched > 0, est, jnp.int64(-1))
-    top_est, top_slot = jax.lax.top_k(score, topk)
-    valid = top_est >= 0
-    cand = jnp.stack([
-        jnp.where(valid, top_slot.astype(jnp.int64), -1),
-        jnp.where(valid, top_est, 0),
-        jnp.where(valid, dense_h[top_slot], 0),
-        jnp.where(valid, dense_o[top_slot], 0),
-    ], axis=-1)
-
-    trows = jnp.stack([t_occ.astype(jnp.int64), pair64(t_hlo, t_hhi),
-                       t_over.astype(jnp.int64)], axis=-1)
-
-    lanes = hdr[0].astype(jnp.int64)
-    hits_total = pair64(hdr[1:2], hdr[2:3])[0]
-    over = hdr[3].astype(jnp.int64)
-    header = jnp.stack([
-        lanes, hits_total, lanes - over, over, hdr[4].astype(jnp.int64),
         jnp.sum((expire > now).astype(jnp.int64)),
         jnp.sum(((expire != 0) & (expire <= now)).astype(jnp.int64)),
         jnp.int64(0),

@@ -108,8 +108,7 @@ AGG_SLOT_BIT = 1 << 30
 I32 = jnp.int32
 I64 = jnp.int64
 U64 = jnp.uint64
-# status constants as int32 scalars: a python int would trace as a weak
-# int64 whose narrowing convert Mosaic cannot lower (it recurses forever)
+# status constants as int32 scalars (a python int would trace as a weak int64)
 _UNDER = np.int32(UNDER_LIMIT)
 _OVER = np.int32(OVER_LIMIT)
 
@@ -125,8 +124,8 @@ def floordiv(a, b):
     about twenty.  When the program lowers for a TPU, int64 operands
     therefore divide through a rolled restoring long division (64 steps, 8
     per loop trip) that compiles in ~0.4 s.  Every other backend, and
-    int32 operands everywhere (the compact32 serving body, every Mosaic
-    kernel), keep the native op."""
+    int32 operands everywhere (the compact32 serving body), keep the
+    native op."""
     if jnp.result_type(a, b) != I64:
         return a // b
     a, b = jnp.broadcast_arrays(jnp.asarray(a, I64), jnp.asarray(b, I64))
@@ -247,6 +246,43 @@ def join64(lo, hi):
     bit 63: two's complement, so negatives round-trip).  Inverse of
     split64."""
     return (hi.astype(np.int64) << 32) | lo.astype(np.int64)
+
+
+# The compact32 clip range: a time enters the rebased-int32 window body (and
+# the compact32 snapshot layout) as clip(t - now, -REBASE_LIM, REBASE_LIM).
+# The compact wire's duration cap (COMPACT_MAX_DURATION) is the same number,
+# which is what keeps every live time inside the range.
+REBASE_LIM = 2**31 - 16
+
+
+def _u32(x):
+    return lax.bitcast_convert_type(x, jnp.uint32)
+
+
+def pair_rebase(t_lo, t_hi, n_lo, n_hi):
+    """clip(t - now, -REBASE_LIM, REBASE_LIM) on (lo, hi) int32 halves.
+
+    Exact vs the int64 form for every input: the borrow subtract yields the
+    wrapped i64 difference's halves; when it fits int32 the clip sees the
+    true difference, otherwise the hi half's sign picks the saturation end
+    — identical to clipping the i64 value (verified over random i64s in
+    tests/test_wire_window.py)."""
+    d_lo = t_lo - n_lo
+    borrow = (_u32(t_lo) < _u32(n_lo)).astype(I32)
+    d_hi = t_hi - n_hi - borrow
+    fits = d_hi == (d_lo >> 31)
+    lim = jnp.int32(REBASE_LIM)
+    return jnp.where(fits, jnp.clip(d_lo, -lim, lim),
+                     jnp.where(d_hi < 0, -lim, lim))
+
+
+def pair_reabs(rel, n_lo, n_hi):
+    """now + rel on (lo, hi) int32 halves (exact i64 add: sign-extended rel,
+    carry from unsigned lo overflow)."""
+    a_lo = n_lo + rel
+    carry = (_u32(a_lo) < _u32(rel)).astype(I32)
+    a_hi = n_hi + (rel >> 31) + carry
+    return a_lo, a_hi
 
 
 def arena_from_rows(rows: BucketState) -> ArenaPlanes:
@@ -371,10 +407,9 @@ def transition(reg: _Reg, hits, req_limit, req_duration, req_algo, now, fresh,
     is_gcra = req_algo == GCRA
     is_sliding = req_algo == SLIDING_WINDOW
     is_conc = req_algo == CONCURRENCY
-    # counter dtype follows the inputs: i64 normally; the Pallas TPU path
-    # runs the same ladder in rebased i32 (Mosaic has no 64-bit vectors,
-    # and the compact-format range caps make i32 exact — see
-    # ops/pallas_kernel.py)
+    # counter dtype follows the inputs: i64 normally; the serving drain
+    # runs the same ladder in rebased i32 (the compact-format range caps
+    # make i32 exact — see window_step_compact32)
     Z = jnp.asarray(0, h.dtype)
     ONE = jnp.asarray(1, h.dtype)
 
@@ -455,7 +490,7 @@ def transition(reg: _Reg, hits, req_limit, req_duration, req_algo, now, fresh,
     rate = jnp.maximum(rate, ONE)
     leak = floordiv(now - T, rate)  # :110-111
     # :113-115 clamp to stored limit; written add-after-min (equivalent
-    # given R <= L) so the i32 Pallas path cannot overflow on R + leak
+    # given R <= L) so the rebased-i32 body cannot overflow on R + leak
     R2 = R + jnp.minimum(leak, L - R)
     T2 = jnp.where(h != 0, now, T)  # :118-121 ts advances only on hits
     lb_at_zero = R2 == 0  # :130-134 -> OVER, reset now+rate
@@ -650,20 +685,6 @@ def transition(reg: _Reg, hits, req_limit, req_duration, req_algo, now, fresh,
     return _Reg(*new_reg), WindowOutput(*out)
 
 
-def transition_precompute(reg_duration, reg_tstamp, req_limit, now):
-    """The two integer divisions of `transition`'s leaky path, factored out
-    so a Mosaic lowering can run them in int64 XLA BEFORE entering a pair-
-    arithmetic kernel (ops/pallas_kernel.py global_combined_staged): both
-    depend only on pre-psum data (stored duration/tstamp + request limit),
-    never on the evolving balance, so hoisting them is exact.  Must stay
-    textually in lockstep with transition's rate/leak lines above."""
-    ONE = jnp.asarray(1, reg_duration.dtype)
-    rate = floordiv(reg_duration, jnp.maximum(req_limit, ONE))
-    rate = jnp.maximum(rate, ONE)
-    leak = floordiv(now - reg_tstamp, rate)
-    return rate, leak
-
-
 def fold_entering(reg: _Reg, fresh0, h0, l0, d0, a0, pos, nz, n_lead,
                   hstar, now):
     """Closed-form ENTERING register for lane `pos` of a foldable segment
@@ -812,12 +833,9 @@ def segment_structure(s_slot, s_valid, s_init):
     (the lanes whose final register may land in the arena).
 
     Segments are VIRTUAL: they break at slot changes AND at is_init lanes
-    (see window_prep's docstring for why).  Written in kernel-safe
-    primitives only — shifted compares via `jnp.take`, `lax.cummax` /
-    `lax.cummin` scans — because this exact function also runs INSIDE the
-    fused Pallas megakernel (ops/pallas_kernel.py window_step_fused), where
-    Mosaic has no concatenate-shift or scatter forms.  Sharing the one
-    implementation is what keeps the XLA and fused paths from drifting.
+    (see window_prep's docstring for why).  Gathers and scans only —
+    shifted compares via `jnp.take`, `lax.cummax` / `lax.cummin` — no
+    scatter.
 
     Returns (seg_start, seg_start_idx, pos, seg_len, commit_mask).
     """
@@ -853,8 +871,7 @@ def segment_count(flag, seg_start_idx, seg_len):
 
     Cumsum range-count instead of a scatter-add (`.at[seg].add`): counts
     the flagged lanes inside [seg_start, seg_start+len) from an inclusive
-    prefix sum — gather-only, so the SAME code runs in window_prep's XLA
-    trace and inside the fused Pallas megakernel.
+    prefix sum — gather-only.
     """
     f = flag.astype(I32)
     csum = jnp.cumsum(f)
@@ -928,8 +945,8 @@ def fold_classify(s_hits, s_limit, s_duration, s_algo, s_agg,
 class WindowPrep(NamedTuple):
     """Everything window_step derives from a window before the transition
     math: sorted request lanes, segment structure, gathered registers, and
-    uniform-segment classification.  Shared verbatim by the XLA path below
-    and the Pallas lowering (ops/pallas_kernel.py) so the two cannot drift.
+    uniform-segment classification.  Shared verbatim by window_step and
+    window_step_compact32, so the two cannot drift.
     """
 
     order: jax.Array
@@ -1079,7 +1096,7 @@ def window_commit(state, prep: WindowPrep, fin: _Reg,
                   outs_sorted: WindowOutput):
     """Scatter the final segment registers back to the arena (one write per
     touched slot — the window's net effect) and un-sort the responses to
-    arrival order.  Shared by the XLA and Pallas paths.
+    arrival order.
 
     commit_mask keeps the scatter one-write-per-SLOT: when eviction recycled
     a slot mid-window the slot has several virtual segments, and only the
@@ -1108,10 +1125,9 @@ def window_math(now, max_pos, s_valid, s_hits, s_limit, s_duration,
     every lane of foldable segments (entering registers reconstructed in
     closed form by fold_entering) plus every singleton and pos-0 lane,
     then replay rounds run only for the residual irregular segments.
-    Pure function of [B] lane vectors — the SAME body runs as a Pallas
-    VMEM kernel (ops/pallas_kernel.py), as plain traced XLA in rebased
-    int32 (the engine's compact serving default), and as the int64 oracle
-    (window_step below), so the three lowerings cannot drift.
+    Pure function of [B] lane vectors — the SAME body runs in rebased
+    int32 (window_step_compact32, the engine's serving drain) and in int64
+    (window_step below, the oracle), so the two cannot drift.
 
     Register state is REPLICATED at every lane of its segment (the arena
     gather outside already yields that), so a replay round is elementwise
@@ -1212,9 +1228,9 @@ def window_step(state: BucketState, batch: WindowBatch, now) -> tuple[BucketStat
     one device computation.  Responses are positionally aligned with the batch
     (the reference demuxes by index, peers.go:204-207).
 
-    This is the int64 oracle: prep → window_math → commit, the same three
-    stages every other lowering (compact32 XLA, Pallas, fused megakernel)
-    composes, in full-width arithmetic.
+    This is the int64 oracle, and the body of the full-format call sites:
+    prep → window_math → commit, the same three stages
+    window_step_compact32 composes, in full-width arithmetic.
     """
     now = jnp.asarray(now, dtype=I64)
     prep = window_prep(state, batch, now)
@@ -1224,6 +1240,64 @@ def window_step(state: BucketState, batch: WindowBatch, now) -> tuple[BucketStat
         prep.seg_start_idx, prep.seg_fold, prep.h0, prep.l0, prep.d0,
         prep.a0, prep.fresh_seg, prep.cur, prep.nz, prep.n_lead,
         prep.hstar)
+    return window_commit(state, prep, fin, out_sorted)
+
+
+def window_step_compact32(state, batch: WindowBatch, now):
+    """The serving drain's window step: window_step with the window math in
+    int32, times REBASED to the window's `now` (prep and commit are the same
+    functions; the TPU emulates int64 arithmetic as i32-pair ops, so the
+    int64 ladder pays roughly double the math for nothing inside the compact
+    ranges).
+
+    Exact iff every lane satisfies the compact wire-format ranges
+    (COMPACT_MAX_*: hits < 2^28, limit < 2^31, duration < 2^31-16) AND the
+    arena rows it reads were written under the same caps — both guaranteed
+    on the engine's compact serving path (the engine permanently drops to
+    the full-format path, window_step, the first time an out-of-range config
+    appears: core/engine.py _dispatch).  Rebased time identities: every
+    absolute time the ladder computes is now+X with X in (-2^31, 2^31);
+    non-fresh registers satisfy |t - now| <= max request duration < 2^31-16
+    (token: tstamp = expire >= now and <= write_now+duration; leaky: expire
+    = last-decrement now+duration >= now) PROVIDED the window clock is
+    monotonic — the engine's serving clocks are.  A clock that jumps
+    backward by D ms can push a stored time up to D past the rebase range;
+    the clip then bounds the resulting expiry error to D (graceful, not
+    wrong-branch)."""
+    now = jnp.asarray(now, dtype=I64)
+    prep = window_prep(state, batch, now)
+    lim = jnp.int64(REBASE_LIM)
+    rel = lambda t: jnp.clip(t - now, -lim, lim).astype(I32)
+    cnt = lambda x: x.astype(I32)
+    cur = prep.cur
+    out_sorted, fin = window_math(
+        jnp.int32(0), prep.max_pos, prep.s_valid, cnt(prep.s_hits),
+        cnt(prep.s_limit), cnt(prep.s_duration), prep.s_algo, prep.s_agg,
+        prep.pos, prep.seg_len, prep.seg_start_idx, prep.seg_fold,
+        cnt(prep.h0), cnt(prep.l0), cnt(prep.d0), prep.a0, prep.fresh_seg,
+        _Reg(limit=cnt(cur.limit), duration=cnt(cur.duration),
+             remaining=cnt(cur.remaining), tstamp=rel(cur.tstamp),
+             expire=rel(cur.expire), algo=cur.algo),
+        prep.nz, prep.n_lead, cnt(prep.hstar))
+    # re-absolutize.  reset_time: leaky and concurrency use 0 as the "no
+    # reset" sentinel (leaky's non-zero resets are now+rate with rate >= 1;
+    # concurrency resets are ALWAYS the sentinel), so rel == 0 distinguishes
+    # exactly; token/GCRA/sliding lanes always carry a real time (rel 0 ==
+    # "resets at now") and never the sentinel (algorithms.go:130-141 vs
+    # :69-74).
+    leaky_lane = (prep.s_algo == LEAKY_BUCKET) | (prep.s_algo == CONCURRENCY)
+    reset64 = jnp.where(
+        leaky_lane & (out_sorted.reset_time == 0), jnp.int64(0),
+        out_sorted.reset_time.astype(I64) + now)
+    out_sorted = WindowOutput(
+        status=out_sorted.status, limit=out_sorted.limit.astype(I64),
+        remaining=out_sorted.remaining.astype(I64), reset_time=reset64)
+    fin = _Reg(limit=fin.limit.astype(I64),
+               duration=fin.duration.astype(I64),
+               remaining=fin.remaining.astype(I64),
+               tstamp=fin.tstamp.astype(I64) + now,
+               expire=fin.expire.astype(I64) + now,
+               algo=fin.algo)
     return window_commit(state, prep, fin, out_sorted)
 
 
@@ -1284,7 +1358,7 @@ def split_outputs(fused, lanes: int) -> tuple[WindowOutput, WindowOutput]:
 
 COMPACT_MAX_HITS = 1 << 28
 COMPACT_MAX_LIMIT = 1 << 31
-COMPACT_MAX_DURATION = (1 << 31) - 16
+COMPACT_MAX_DURATION = REBASE_LIM
 
 
 def decode_batch(packed) -> WindowBatch:

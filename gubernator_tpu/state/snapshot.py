@@ -17,9 +17,8 @@ Two on-disk time layouts, chosen per snapshot:
   "compact32" tstamp/expire stored as int32 deltas REBASED against the
               snapshot timestamp, and limit/duration/remaining truncated to
               int32 — half the plane bytes.  The rebase runs through
-              ops/pallas_kernel's _pair_rebase/_pair_reabs (the fused
-              megakernel's own helpers), so the snapshot codec CANNOT drift
-              from the serving path's int32 time math.  Chosen only when
+              ops/kernel's pair_rebase/pair_reabs, which clip at the
+              serving body's own limit (kernel.REBASE_LIM).  Chosen only when
               every live value round-trips exactly (engine export checks),
               so restore is bit-identical to the int64 layout either way.
 
@@ -51,7 +50,12 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from gubernator_tpu.ops import kernel
 
 log = logging.getLogger("gubernator.snapshot")
 
@@ -59,7 +63,7 @@ MAGIC = b"GUBSNAP\x01"
 VERSION = 1
 
 # int32 sentinel marking a never-initialized slot's times in the compact32
-# layout (expire == 0 on device).  Outside the +/-_REBASE_LIM clip range, so
+# layout (expire == 0 on device).  Outside the +/-REBASE_LIM clip range, so
 # it can never collide with a real rebased delta.
 DEAD_REL = -(2 ** 31)
 
@@ -125,24 +129,19 @@ class ArenaSnapshot:
 
 
 def _pair_codec():
-    """The fused megakernel's (lo, hi) int32 rebase helpers, jitted once
-    over flat arrays.  Importing lazily keeps `state` free of jax at module
-    import (host-only tools load this module too)."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from gubernator_tpu.ops import pallas_kernel as pk
+    """ops/kernel's (lo, hi) int32 rebase helpers, jitted once over flat
+    arrays."""
 
     @jax.jit
     def enc(t, now):
         pair = lax.bitcast_convert_type(t, jnp.int32)       # [N, 2]
         npair = lax.bitcast_convert_type(now, jnp.int32)    # [2]
-        return pk._pair_rebase(pair[:, 0], pair[:, 1], npair[0], npair[1])
+        return kernel.pair_rebase(pair[:, 0], pair[:, 1], npair[0], npair[1])
 
     @jax.jit
     def dec(rel, now):
         npair = lax.bitcast_convert_type(now, jnp.int32)
-        lo, hi = pk._pair_reabs(rel, npair[0], npair[1])
+        lo, hi = kernel.pair_reabs(rel, npair[0], npair[1])
         return lax.bitcast_convert_type(
             jnp.stack([lo, hi], axis=-1), jnp.int64)
 
@@ -160,7 +159,7 @@ def _codec_fns():
 
 
 def rebase_encode(times: np.ndarray, dead: np.ndarray, now: int) -> np.ndarray:
-    """int64 ms-epoch -> int32 delta vs `now` via _pair_rebase; dead slots
+    """int64 ms-epoch -> int32 delta vs `now` via pair_rebase; dead slots
     (expire == 0 on device) carry the DEAD_REL sentinel instead."""
     enc, _ = _codec_fns()
     rel = np.asarray(enc(np.ascontiguousarray(times, np.int64).reshape(-1),
@@ -187,7 +186,7 @@ def compact_encodable(snap: "ArenaSnapshot") -> bool:
     every value plane must fit int32 (the same caps the compact serving
     wire enforces — engine._compact_sound implies them for live rows, but a
     pre-soundness-trip arena may hold wider values, so check the data)."""
-    lim = (2 ** 31) - 16  # pallas_kernel._REBASE_LIM
+    lim = kernel.REBASE_LIM
     i32 = 2 ** 31
 
     def _planes_ok(planes):

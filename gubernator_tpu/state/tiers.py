@@ -10,9 +10,9 @@ Three tiers, coldest reconstructible from nothing:
         from the arena, held in the snapshot serialization from
         state/snapshot.py — either absolute int64 times or compact32
         pair-rebased deltas against the store epoch, encoded/decoded in
-        BATCHES through the fused megakernel's own jitted codec
-        (snapshot.rebase_encode/rebase_decode) so the warm image cannot
-        drift from the serving path's int32 time math.
+        BATCHES through the snapshot's jitted codec
+        (snapshot.rebase_encode/rebase_decode), which clips at the
+        serving body's own rebase limit (ops/kernel.py REBASE_LIM).
   cold  nothing stored.  A miss in both tiers re-initializes from the
         request's self-describing config — exactly the reference's
         stateless-client semantics, so "arena full" becomes a cache-miss
@@ -51,6 +51,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from gubernator_tpu.ops.kernel import REBASE_LIM
 from gubernator_tpu.state.snapshot import rebase_decode, rebase_encode
 
 log = logging.getLogger("gubernator.tiers")
@@ -59,8 +60,6 @@ _ROW_FIELDS = ("limit", "duration", "remaining", "tstamp", "expire", "algo")
 _VAL_FIELDS = ("limit", "duration", "remaining")
 _TIME_FIELDS = ("tstamp", "expire")
 
-# pallas_kernel._REBASE_LIM: the compact32 clip range around the epoch
-_REBASE_LIM = (2 ** 31) - 16
 _I32 = 2 ** 31
 
 
@@ -143,7 +142,7 @@ class WarmStore:
                 return False
         for f in _TIME_FIELDS:
             d = row[f] - self.epoch
-            if not (-_REBASE_LIM <= d <= _REBASE_LIM):
+            if not (-REBASE_LIM <= d <= REBASE_LIM):
                 return False
         return True
 

@@ -1,7 +1,7 @@
 """Multi-node open-loop scale-out harness (gubernator_tpu/cluster.py).
 
 Boots an N-node consistent-hash ring on loopback (>= 3 nodes for a real
-run; N=1 is the degenerate single-box smoke `make bench-smoke` uses),
+run; N=1 is the degenerate single-box smoke),
 optionally fronts every node with the multi-process front door, and
 drives OPEN-LOOP load: each node receives RPCs at a fixed offered rate
 regardless of how fast responses come back — the load does not slow down

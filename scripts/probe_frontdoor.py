@@ -17,8 +17,7 @@ spreads them across the acceptor workers — and prints:
     t_e2e ~= max(worker_parse, engine_drain) shows up here as the engine
     split no longer being gated on host parse time.
 
-`make bench-smoke` runs a short 0-vs-2 sweep after the overlap probe;
-standalone:
+Standalone:
 
     JAX_PLATFORMS=cpu python scripts/probe_frontdoor.py
     GUBER_PROBE_FD_WORKERS=1,2,4 GUBER_PROBE_SECONDS=5 \

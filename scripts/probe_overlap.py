@@ -15,8 +15,7 @@ cost model (`t_pipelined ~= max(stage)`, not the sum):
 
 Depth 1 vs configured depth shows what the overlap itself contributes
 on this box, separate from the columnar host-path wins (which depth 1
-keeps).  `make bench-smoke` runs the default sweep (depths 1 and 3,
-~3 s each) after the regression gate; standalone:
+keeps).  The default sweep is depths 1 and 3, ~3 s each:
 
     JAX_PLATFORMS=cpu python scripts/probe_overlap.py
     GUBER_PROBE_DEPTHS=1,2,3 GUBER_PROBE_SECONDS=5 ... # custom sweep
@@ -33,6 +32,22 @@ _setup()
 import jax  # noqa: E402
 
 
+def _zipf_payloads(pb, n_payloads, items, keyspace, name):
+    import numpy as np
+
+    rng = np.random.default_rng(11)
+    payloads = []
+    for _ in range(n_payloads):
+        keys = (rng.zipf(1.1, size=items) - 1) % keyspace
+        msg = pb.GetRateLimitsReq(requests=[
+            pb.RateLimitReq(name=name, unique_key=f"k{keys[i]}", hits=1,
+                            limit=1_000_000, duration=60_000,
+                            algorithm=int(keys[i]) % 2)
+            for i in range(items)])
+        payloads.append(msg.SerializeToString())
+    return payloads
+
+
 def probe_depth(depth: int, seconds: float, capacity: int, lanes: int,
                 concurrency: int) -> dict:
     """One saturated open-loop run at a fixed pipeline depth."""
@@ -43,8 +58,6 @@ def probe_depth(depth: int, seconds: float, capacity: int, lanes: int,
     from gubernator_tpu.config import BehaviorConfig
     from gubernator_tpu.core.batcher import WindowBatcher
     from gubernator_tpu.core.engine import RateLimitEngine
-
-    import bench as b
 
     os.environ["GUBER_PIPELINE_DEPTH"] = str(depth)
     from gubernator_tpu.parallel.mesh import make_mesh
@@ -58,7 +71,7 @@ def probe_depth(depth: int, seconds: float, capacity: int, lanes: int,
         batcher.close()
         return {}
     N = 1000
-    payloads = b._zipf_payloads(pb, 16, N, 100_000, "overlap")
+    payloads = _zipf_payloads(pb, 16, N, 100_000, "overlap")
     eng.warmup()
 
     async def run():

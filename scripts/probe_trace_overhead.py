@@ -28,8 +28,7 @@ re-arms short jax.profiler captures on a background thread while the
 sweep drains, so the median round shows what always-on attribution
 costs the serving path.  This one IS asserted: overhead past
 GUBER_DEVPROF_OVERHEAD_PCT (default 2.0, median-of-rounds so a lone
-capture round cannot trip it) exits nonzero, which is how
-`make bench-smoke` gates the continuous mode.
+capture round cannot trip it) exits nonzero.
 """
 import asyncio
 import os

@@ -67,3 +67,23 @@ class KernelHarness:
 
     def one(self, req: RateLimitReq, now: Optional[int] = None) -> RateLimitResp:
         return self.window([req], now)[0]
+
+
+def wire_window(state, packed, now):
+    """One compact-encoded window through the body the chip runs, as the
+    scan body of engine._drain_scan composes it: decode_batch →
+    window_step_compact32 → encode_output_word, plus the limit lanes and
+    the limit-mismatch flag.  Returns (state, words, limits, mism)."""
+    bt = kernel.decode_batch(packed)
+    state, out = kernel.window_step_compact32(state, bt, now)
+    mism = jnp.any((out.limit != bt.limit) & (bt.slot >= 0))
+    return state, kernel.encode_output_word(out, now), out.limit, mism
+
+
+def count_eqns(jaxpr) -> int:
+    """Equations of a (Closed)Jaxpr, those of its sub-jaxprs (scan, cond,
+    shard_map, pjit bodies) included."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    return sum(1 + sum(count_eqns(sub)
+                       for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)

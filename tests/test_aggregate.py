@@ -128,17 +128,14 @@ def test_agg_mixed_with_plain_lanes():
 
 
 @pytest.mark.parametrize("algo", [0, 1])
-def test_agg_lane_pallas_compact32(algo):
-    """The aggregated branch flows through the Pallas compact32 kernel."""
-    from gubernator_tpu.ops.pallas_kernel import window_step_pallas
-
+def test_agg_lane_compact32(algo):
+    """The aggregated branch flows through the compact32 serving body."""
     state_x = kernel.BucketState.zeros(16)
     state_p = kernel.BucketState.zeros(16)
     batch = _batch([1 | AGG, 3], [5, 1], [4, 7], [60_000, 60_000],
                    [algo, algo], [True, True])
     state_x, out_x = kernel.window_step(state_x, batch, T0)
-    state_p, out_p = window_step_pallas(state_p, batch, T0,
-                                        interpret=True, compact32=True)
+    state_p, out_p = kernel.window_step_compact32(state_p, batch, T0)
     for f in kernel.BucketState._fields:
         np.testing.assert_array_equal(
             np.asarray(getattr(state_x, f)), np.asarray(getattr(state_p, f)),

@@ -1,14 +1,14 @@
 """Algorithm-plane suite: the GCRA / sliding-window / concurrency ladders
 against the plain-python serial oracles (algorithms/oracles.py), on every
-lowering that serves them.
+window body that serves them.
 
 The oracles mirror ops/kernel.py transition() branch for branch but share
 no code with it (only format constants), so each differential here compares
 two independent derivations of the reference semantics:
 
-  * kernel-vs-oracle per algorithm on all four lowerings — the int64
-    oracle path, the compact32-XLA serving form, the per-window Pallas
-    body (interpret), and the fused megakernel through the packed wire;
+  * kernel-vs-oracle per algorithm on both window bodies — the int64
+    path and the compact32 serving body — and the serving body again
+    through the packed wire;
   * a mixed stream that switches one key across all five algorithm values
     (each switch must re-init, per the device's fresh-lane rule);
   * the engine end-to-end (batcher, router, compact gating, fold) vs the
@@ -42,20 +42,17 @@ from gubernator_tpu.api.types import (
 )
 from gubernator_tpu.core.engine import RateLimitEngine
 from gubernator_tpu.ops import kernel
-from gubernator_tpu.ops import pallas_kernel as pk
 from gubernator_tpu.state import snapshot as snapmod
+
+from .harness import wire_window
 
 pytestmark = pytest.mark.algorithms
 
 T0 = 1_754_000_000_000
 
 _step_int64 = jax.jit(kernel.window_step)
-_step_c32 = jax.jit(pk.window_step_compact32_xla)
-
-
-def _step_pallas(st, batch, now):
-    return pk.window_step_pallas(st, batch, now, interpret=True,
-                                 compact32=True)
+_step_c32 = jax.jit(kernel.window_step_compact32)
+_wire_window = jax.jit(wire_window)
 
 
 def _fresh_state(C):
@@ -65,7 +62,7 @@ def _fresh_state(C):
                               algo=jnp.zeros(C, jnp.int32))
 
 
-def _stream(algo, seed, W=6, C=8):  # C power-of-two: the fused wire needs it
+def _stream(algo, seed, W=6, C=8):
     """W windows of C lanes (slot i = lane i), fixed config per slot,
     hit sizes spanning reads / partial / drain / over-ask (and negative
     releases for concurrency), dts spanning in-window and past-expiry."""
@@ -116,17 +113,16 @@ def _assert_state_matches_rows(st, rows, tag):
 
 ALGOS = [kernel.TOKEN_BUCKET, kernel.LEAKY_BUCKET, kernel.GCRA,
          kernel.SLIDING_WINDOW, kernel.CONCURRENCY]
-XLA_LOWERINGS = {
+WINDOW_BODIES = {
     "int64": _step_int64,
     "compact32": _step_c32,
-    "pallas": _step_pallas,
 }
 
 
-@pytest.mark.parametrize("lowering", sorted(XLA_LOWERINGS))
+@pytest.mark.parametrize("lowering", sorted(WINDOW_BODIES))
 @pytest.mark.parametrize("algo", ALGOS)
 def test_kernel_matches_oracle(algo, lowering):
-    step = XLA_LOWERINGS[lowering]
+    step = WINDOW_BODIES[lowering]
     for seed in range(3):
         windows = _stream(algo, 1000 * algo + seed)
         st = _fresh_state(windows[0][0].slot.shape[0])
@@ -143,23 +139,22 @@ def test_kernel_matches_oracle(algo, lowering):
             st, rows, f"algo {algo} {lowering} seed {seed}")
 
 
-@pytest.mark.fused_staging
 @pytest.mark.parametrize("algo", ALGOS)
-def test_fused_matches_oracle(algo):
+def test_wire_matches_oracle(algo):
     """The same differential through the packed wire: compact-encoded
-    requests into the fused megakernel, response words out, vs the oracle
-    outputs pushed through the device word encoder."""
+    requests into the serving body over the resident planes, response
+    words out, vs the oracle outputs pushed through the device word
+    encoder."""
     for seed in range(2):
         windows = _stream(algo, 2000 * algo + seed)
-        st = _fresh_state(windows[0][0].slot.shape[0])
+        st = kernel.ArenaPlanes.zeros(windows[0][0].slot.shape[0])
         rows = {}
         for w, (batch, now) in enumerate(windows):
             packed = jnp.asarray(kernel.encode_batch_host(
                 np.asarray(batch.slot), np.asarray(batch.hits),
                 np.asarray(batch.limit), np.asarray(batch.duration),
                 np.asarray(batch.algo), np.asarray(batch.is_init)))
-            st, words, limits, _ = pk.window_step_fused(
-                st, packed, jnp.int64(now), interpret=True)
+            st, words, limits, _ = _wire_window(st, packed, jnp.int64(now))
             want = _oracle_window(rows, batch, now)
             want_words = kernel.encode_output_word(
                 kernel.WindowOutput(
@@ -170,11 +165,12 @@ def test_fused_matches_oracle(algo):
                 jnp.int64(now))
             np.testing.assert_array_equal(
                 np.asarray(words), np.asarray(want_words),
-                err_msg=f"algo {algo} seed {seed} window {w} fused words")
+                err_msg=f"algo {algo} seed {seed} window {w} wire words")
             np.testing.assert_array_equal(
                 np.asarray(limits), want.limit,
-                err_msg=f"algo {algo} seed {seed} window {w} fused limits")
-        _assert_state_matches_rows(st, rows, f"algo {algo} fused s{seed}")
+                err_msg=f"algo {algo} seed {seed} window {w} wire limits")
+        _assert_state_matches_rows(kernel.arena_to_rows(st), rows,
+                                   f"algo {algo} wire s{seed}")
 
 
 def test_mixed_algorithm_stream_matches_oracle():

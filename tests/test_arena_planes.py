@@ -196,13 +196,12 @@ def test_full_format_step_on_planes_equals_oracle(seed):
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_compact32_serving_step_on_planes_equals_oracle(seed):
-    """The serving drain's window step (rebased int32 math, XLA) on the
+    """The serving drain's window step (rebased int32 math) on the
     planes against the int64 oracle on rows, inside the compact caps."""
-    from gubernator_tpu.ops.pallas_kernel import window_step_compact32_xla
     rng = np.random.default_rng(100 + seed)
     st_rows = _seed_rows(rng, wide=False)
     st_planes = kernel.arena_from_rows(st_rows)
-    step_c32 = jax.jit(window_step_compact32_xla)
+    step_c32 = jax.jit(kernel.window_step_compact32)
     for w in range(6):
         bt, now = _window(rng, wide=False), jnp.int64(T0 + 700 * w)
         st_rows, out_r = _step(st_rows, bt, now)
@@ -224,7 +223,7 @@ def test_engine_drain_equals_oracle(seed):
     """The compiled serving drain (shard_map, scan, donation: everything
     around the step) against the oracle chained window by window, twice,
     so the donated planes carry from one dispatch to the next."""
-    from .test_mesh_fused_drain import _oracle_drain, _random_stack
+    from .test_mesh_drain import _oracle_drain, _random_stack
     rng = np.random.default_rng(seed)
     eng = _mk_engine()
     assert isinstance(eng.state, kernel.ArenaPlanes)

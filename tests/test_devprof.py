@@ -1,11 +1,9 @@
 """Device-time flight recorder: measured kernel attribution, window
 clocks, and trace exemplars (observability/devprof.py).
 
-The headline assertion is the census-vs-measured join: every kernel
-class the census counts (probe_census.py arm vocabulary) must get a
-NONZERO measured ms/window entry from a REAL parsed `jax.profiler`
-trace — the census and the measurement are built from the SAME arm
-specs (`build_census_arms`), so the join can never drift.  Around it:
+The headline assertion: every probe arm (`build_probe_arms`) must get a
+NONZERO measured ms/window entry from a REAL parsed `jax.profiler` trace.
+Around it:
 
   * trace parsing: synthetic chrome-trace events exercise self-time
     nesting and annotation-window arm attribution deterministically;
@@ -42,9 +40,9 @@ from gubernator_tpu.observability.devprof import (
     DevprofController,
     KernelTable,
     WindowClock,
-    build_census_arms,
+    build_probe_arms,
     load_trace_events,
-    measure_census_arms,
+    measure_probe_arms,
     parse_run_dir,
     self_times,
 )
@@ -52,9 +50,8 @@ from gubernator_tpu.observability.metrics import Metrics
 
 pytestmark = pytest.mark.devprof
 
-CENSUS_CLASSES = ("int64_xla", "compact32_xla", "fused_window",
-                  "composed_drain", "composed_mixed_algos",
-                  "composed_analytics")
+PROBE_ARMS = ("int64_xla", "compact32_xla", "composed_drain",
+              "composed_mixed_algos", "composed_analytics")
 
 
 # --------------------------------------------------------------- trace parsing
@@ -137,8 +134,8 @@ def test_self_times_nesting_and_arm_attribution():
     assert rows["stray.4"] == (0.01, ARM_OTHER)
     # an arm-scoped capture overrides the annotation join wholesale
     hinted = {arm for _n, _ms, arm in
-              self_times(events, arm_hint="fused_window")}
-    assert hinted == {"fused_window"}
+              self_times(events, arm_hint="compact32_xla")}
+    assert hinted == {"compact32_xla"}
 
 
 def test_kernel_table_keys_by_arm_and_name():
@@ -147,38 +144,27 @@ def test_kernel_table_keys_by_arm_and_name():
            "ts": 0.0, "dur": 100.0}]
     t = KernelTable()
     assert t.fold(ev, windows=1, arm_hint="composed_drain") == 1
-    assert t.fold(ev, windows=1, arm_hint="fused_window") == 1
+    assert t.fold(ev, windows=1, arm_hint="compact32_xla") == 1
     mpw = t.ms_per_window()
-    assert set(mpw) == {"composed_drain", "fused_window"}
+    assert set(mpw) == {"composed_drain", "compact32_xla"}
     assert mpw["composed_drain"] == pytest.approx(0.05)
-    assert mpw["fused_window"] == pytest.approx(0.05)
+    assert mpw["compact32_xla"] == pytest.approx(0.05)
     arms_in_rows = {r["arm"] for r in t.snapshot()["rows"]}
-    assert arms_in_rows == {"composed_drain", "fused_window"}
+    assert arms_in_rows == {"composed_drain", "compact32_xla"}
 
 
-# ------------------------------------------------------- measured census join
+# --------------------------------------------------------- measured probe arms
 
 
-def test_every_census_class_gets_measured_time():
-    """ISSUE acceptance: every census kernel class gets a nonzero
-    measured ms/window entry from a real parsed trace, and the admin
-    payload joins census x measured per arm."""
-    import jax
-
-    from gubernator_tpu.ops import pallas_kernel as pk
-
-    arms = build_census_arms(k=2)
-    assert {s["name"] for s in arms} == set(CENSUS_CLASSES)
-    census = {
-        s["name"]:
-            pk.kernel_census(jax.make_jaxpr(s["fn"])(*s["args"]))
-            / s["windows"]
-        for s in arms}
-    assert all(v > 0 for v in census.values())
+def test_every_probe_arm_gets_measured_time():
+    """Every probe arm gets a nonzero measured ms/window entry from a real
+    parsed trace, and the admin payload carries it per arm."""
+    arms = build_probe_arms(k=2)
+    assert {s["name"] for s in arms} == set(PROBE_ARMS)
 
     dev = Devprof()
-    out = measure_census_arms(arms=arms, iters=1, table=dev.table)
-    for name in CENSUS_CLASSES:
+    out = measure_probe_arms(arms=arms, iters=1, table=dev.table)
+    for name in PROBE_ARMS:
         row = out["arms"][name]
         assert row["kernel_events"] > 0, f"{name}: no kernel events parsed"
         assert row["measured_ms_per_window"] > 0, \
@@ -186,11 +172,9 @@ def test_every_census_class_gets_measured_time():
     kt = out["kernel_table"]
     assert kt["rows"] and kt["windows"] > 0
 
-    snap = dev.kernels_snapshot(census=census)
-    for name in CENSUS_CLASSES:
+    snap = dev.kernels_snapshot()
+    for name in PROBE_ARMS:
         slot = snap["arms"][name]
-        assert slot["census_kernels_per_window"] > 0
-        assert slot["measured_ms_per_window"] is not None
         assert slot["measured_ms_per_window"] > 0
     json.dumps(snap)  # admin-plane payload must be JSON-safe
 
@@ -335,9 +319,7 @@ def test_admin_kernels_endpoint(inst):
                                      "duration": "60000"}]}
             r = await client.post("/v1/GetRateLimits", json=payload)
             assert r.status == 200
-            # census=0 keeps the endpoint cheap (the join itself is
-            # covered by test_every_census_class_gets_measured_time)
-            r = await client.get("/v1/admin/kernels?census=0")
+            r = await client.get("/v1/admin/kernels")
             assert r.status == 200
             out = await r.json()
             json.dumps(out)
@@ -346,13 +328,13 @@ def test_admin_kernels_endpoint(inst):
             arms = out["clock"]["arms"]
             assert arms, "no window-clock observation for a served request"
             for arm, stats in arms.items():
-                assert arm in ("compact32_xla", "fused_window",
-                               "composed_drain", "composed_analytics")
+                assert arm in ("compact32_xla", "composed_drain",
+                               "composed_analytics")
                 assert stats["count"] >= 1
                 assert stats["ewma_ms"] >= 0.0
             # a measure request conflicts with an armed capture
             assert inst.batcher.profile.arm(4, "/tmp/gtd-armed")["armed"]
-            r = await client.get("/v1/admin/kernels?measure=1&census=0")
+            r = await client.get("/v1/admin/kernels?measure=1")
             assert r.status == 409
             inst.batcher.profile.cancel()
             # devprof status rides the debug snapshot

@@ -22,17 +22,17 @@ fold exactly or reject to the replay:
   * recycle inits mid-run (is_init starts a fresh virtual segment);
   * arena rows violating the leaky invariant (remaining > limit).
 
-Both lowerings are pinned: the int64 oracle path against the serial
-contract, and the compact32-XLA path against the int64 path on the same
+Both window bodies are pinned: the int64 oracle path against the serial
+contract, and the compact32 serving body against the int64 path on the same
 windows (all values inside the compact caps by construction).
 
-The fused-staging seeds push the SAME adversarial windows through the
-packed wire — compact-encoded requests in, response words out — and pin
-both fused layouts against the host decode → oracle → encode path: the
-K-grid staged drain (plane-form carry across grid steps) and K chained
-single-window megakernel calls on the int64 state.  The replay fallback
-inside the fused body is exercised by construction (hstar violations and
-AGG lanes inside multi-lane runs force fold_classify to bail).
+The drain seeds push the SAME adversarial windows through the packed wire
+— compact-encoded requests in, response words out — as ONE K-window stack
+through the serving drain executable (engine._compiled_pipeline_step, the
+arena carried in its resident plane form), against the host decode →
+oracle → encode path.  The replay fallback inside the body is exercised by
+construction (hstar violations and AGG lanes inside multi-lane runs force
+fold_classify to bail).
 """
 
 import numpy as np
@@ -42,8 +42,9 @@ import gubernator_tpu  # noqa: F401  (enables x64)
 import jax
 import jax.numpy as jnp
 
+from gubernator_tpu.core import engine as engine_mod
 from gubernator_tpu.ops import kernel
-from gubernator_tpu.ops import pallas_kernel as pk
+from gubernator_tpu.parallel.mesh import make_mesh
 
 T0 = 1_754_000_000_000
 
@@ -127,7 +128,7 @@ def test_fold_adversarial_segments_match_serial(seed):
     st_serial = kernel.BucketState(*[jnp.asarray(np.asarray(a))
                                      for a in st_batch])
     step = jax.jit(kernel.window_step)
-    step_c32 = jax.jit(pk.window_step_compact32_xla)
+    step_c32 = jax.jit(kernel.window_step_compact32)
     for w in range(4):
         now += int(rng.integers(1, 300_000))  # cross expiry boundaries
         batch = _adversarial_batch(rng, B, C)
@@ -171,7 +172,7 @@ def _run_fold_vs_serial(st0, windows, tag):
     st_serial = kernel.BucketState(*[jnp.asarray(np.asarray(a))
                                      for a in st0])
     step = jax.jit(kernel.window_step)
-    step_c32 = jax.jit(pk.window_step_compact32_xla)
+    step_c32 = jax.jit(kernel.window_step_compact32)
     for w, (batch, now) in enumerate(windows):
         nj = jnp.int64(now)
         valid = np.asarray(batch.slot) >= 0
@@ -319,33 +320,30 @@ def _has_replay_shape(batch):
     return False
 
 
-@pytest.mark.fused_staging
 @pytest.mark.parametrize("seed", list(range(6)))
-def test_fused_staging_drain_matches_host_oracle(seed):
-    """Fused-staging differential: packed wire in / packed wire out through
-    the new K-grid drain body vs the host decode → int64 oracle → encode
+def test_drain_matches_host_oracle(seed):
+    """Drain differential: packed wire in / packed wire out through the
+    serving drain executable vs the host decode → int64 oracle → encode
     path, on the fold fuzz's adversarial windows (replay-fallback shapes
-    guaranteed by construction).  Both layouts pinned: the plane-form grid
-    carry and K chained single-window fused calls on the int64 state."""
-    _run_fused_vs_host(np.random.default_rng(9000 + seed), seed, algo_hi=2)
+    guaranteed by construction)."""
+    _run_drain_vs_host(np.random.default_rng(9000 + seed), seed, algo_hi=2)
 
 
-@pytest.mark.fused_staging
 @pytest.mark.algorithms
 # two seeds in the per-commit run; the deeper sweep rides the slow lane
 # (tier-1 wall budget on a 1-core box)
 @pytest.mark.parametrize("seed", [0, 1,
                                   pytest.param(2, marks=pytest.mark.slow),
                                   pytest.param(3, marks=pytest.mark.slow)])
-def test_fused_staging_drain_all_algorithms(seed):
-    """The fused differential over the full algorithm range: GCRA /
+def test_drain_all_algorithms(seed):
+    """The drain differential over the full algorithm range: GCRA /
     sliding / concurrency lanes (negative conc hits sign-extended through
     the 28-bit compact hits field) through the same packed wire."""
-    _run_fused_vs_host(np.random.default_rng(10_000 + seed), seed,
+    _run_drain_vs_host(np.random.default_rng(10_000 + seed), seed,
                        algo_hi=5)
 
 
-def _run_fused_vs_host(rng, seed, algo_hi):
+def _run_drain_vs_host(rng, seed, algo_hi):
     K, B, C = 4, 32, 24
     st0 = _adversarial_state(rng, C, T0, algo_hi)
 
@@ -379,38 +377,22 @@ def _run_fused_vs_host(rng, seed, algo_hi):
             (np.asarray(out.limit) != np.asarray(bt.limit))
             & (np.asarray(bt.slot) >= 0))))
 
-    # layout 1: the staged K-grid drain, plane-form carry across grid steps
-    new32, words, limits, mism, stats = pk.window_drain_fused_planes(
-        pk.fused_state_to_planes(st0), packed, nows_j, interpret=True)
-    assert stats is None
+    # the serving drain: one K-window stack, one shard, resident planes
+    drain = engine_mod._compiled_pipeline_step(
+        make_mesh(jax.devices("cpu")[:1]))
+    planes = jax.tree.map(lambda a: a[None], kernel.arena_from_rows(st0))
+    planes, words, limits, mism = drain(planes, packed[:, None], nows_j)
     np.testing.assert_array_equal(
-        np.asarray(words), np.stack(ref_words),
+        np.asarray(words)[:, 0], np.stack(ref_words),
         err_msg=f"seed {seed} drain response words")
     np.testing.assert_array_equal(
-        np.asarray(limits), np.stack(ref_limits),
+        np.asarray(limits)[:, 0], np.stack(ref_limits),
         err_msg=f"seed {seed} drain limit lanes")
     np.testing.assert_array_equal(
-        np.asarray(mism), np.asarray(ref_mism),
+        np.asarray(mism)[:, 0], np.asarray(ref_mism),
         err_msg=f"seed {seed} drain mismatch flags")
-    for name, a, b in zip(kernel.BucketState._fields,
-                          pk.fused_state_from_planes(new32), st_ref):
+    got = kernel.arena_to_rows(jax.tree.map(lambda a: a[0], planes))
+    for name, a, b in zip(kernel.BucketState._fields, got, st_ref):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b),
             err_msg=f"seed {seed} drain state.{name}")
-
-    # layout 2: K chained single-window fused calls on the int64 state
-    st_f = st0
-    for k in range(K):
-        st_f, w_f, l_f, m_f = pk.window_step_fused(
-            st_f, packed[k], jnp.int64(nows[k]), interpret=True)
-        np.testing.assert_array_equal(
-            np.asarray(w_f), ref_words[k],
-            err_msg=f"seed {seed} window {k} fused words")
-        np.testing.assert_array_equal(
-            np.asarray(l_f), ref_limits[k],
-            err_msg=f"seed {seed} window {k} fused limits")
-        assert bool(m_f) == ref_mism[k], f"seed {seed} window {k} fused mism"
-    for name, a, b in zip(kernel.BucketState._fields, st_f, st_ref):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b),
-            err_msg=f"seed {seed} fused state.{name}")

@@ -73,8 +73,6 @@ NATIVE_NAMES = (
     # device-time flight recorder (observability/devprof.py)
     "guber_tpu_device_window_ms",
     "guber_tpu_devprof_captures",
-    # kernel-ladder scoreboard (daemon boot, staged drain)
-    "guber_tpu_kernels_per_window",
     # algorithm plane + concurrency-lease book (algorithms/leases.py)
     "guber_tpu_decisions_total",
     "guber_tpu_lease_held_slots",
@@ -196,6 +194,7 @@ REMOVED_NAMES = (
     "guber_tpu_window_buffer_reuse_total",
     "guber_tpu_device_window_ewma_ms",
     "guber_tpu_frontdoor_trace_drops_total",
+    "guber_tpu_kernels_per_window",
 )
 
 

@@ -396,7 +396,7 @@ def test_profiler_stop_does_not_stall_the_engine_thread(monkeypatch):
 def test_engine_thread_code_never_calls_the_profiler():
     """The grep of the acceptance criteria, kept as a test: the serving
     path's start_trace and stop_trace are in observability/introspect.py
-    alone (devprof.measure_census_arms is the admin plane's offline probe:
+    alone (devprof.measure_probe_arms is the admin plane's offline probe:
     its own executables on a thread of the default executor)."""
     hits = []
     for path in glob.glob(os.path.join(REPO, "gubernator_tpu", "**", "*.py"),

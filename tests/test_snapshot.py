@@ -6,9 +6,9 @@ host oracle (tests/pyref.py) runs straight through while the engine is
 snapshotted, destroyed, and restored mid-workload, with the clock resumed
 both INSIDE live windows (remaining must survive) and PAST window/expiry
 boundaries (lazy TTL must fire exactly as it would have).  Both wire
-layouts are covered: the compact32 layout runs through the fused
-megakernel's own pair-rebase helpers, so these tests also pin that codec
-against the int64 truth.
+layouts are covered: the compact32 layout runs through ops/kernel's
+pair-rebase helpers, so these tests also pin that codec against the int64
+truth.
 
 Corruption: a truncated or bit-flipped snapshot must degrade to a logged
 cold start (restore_engine), never a crash or a half-restore.
@@ -110,7 +110,7 @@ def _clone_oracle(oracle):
 @pytest.mark.parametrize("use_native", _backends())
 def test_layouts_restore_bit_identically(use_native):
     """int64 and compact32 must restore the SAME device state: the
-    compact32 rebase runs through ops/pallas_kernel's pair helpers and may
+    compact32 rebase runs through ops/kernel's pair helpers and may
     not drift from the plain int64 path by even one bit."""
     rng = np.random.default_rng(11)
     eng = _mk_engine(use_native)

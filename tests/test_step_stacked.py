@@ -130,14 +130,15 @@ def _inert_stack(eng, k):
 
 
 def test_empty_global_skip_census():
-    """The GLOBAL-skipping stacked variant must execute strictly fewer
-    kernels than the composed twin: the per-window GLOBAL gathers,
-    scatters and psum are gone, and the once-per-stack control apply is
-    gone too (op-count cut the round-5 calibration prescribes)."""
+    """The GLOBAL-skipping stacked variant must trace to strictly fewer
+    equations than the composed twin, and to no collective: the per-window
+    GLOBAL gathers, scatters and psum are gone, and the once-per-stack
+    control apply is gone too."""
     import jax
 
     from gubernator_tpu.core import engine as eng_mod
-    from gubernator_tpu.ops import pallas_kernel as pk
+
+    from .harness import count_eqns
 
     eng = make_engine(False)
     args = _inert_stack(eng, 2)
@@ -146,9 +147,10 @@ def test_empty_global_skip_census():
     skip = jax.make_jaxpr(
         eng_mod._compiled_multi_step(eng.mesh, with_global=False))(
         eng.state, eng.gstate, eng.gcfg, *args)
-    cf, cs = pk.kernel_census(full), pk.kernel_census(skip)
+    cf, cs = count_eqns(full), count_eqns(skip)
     assert cs < cf, (
-        f"GLOBAL-skip variant census {cs} not below composed census {cf}")
+        f"GLOBAL-skip variant traces to {cs} equations, composed to {cf}")
+    assert "psum" in str(full) and "psum" not in str(skip)
 
 
 def test_empty_global_skip_matches_sequential(monkeypatch):

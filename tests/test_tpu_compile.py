@@ -2,10 +2,7 @@
 described (not attached) TPU v5e, at the widths chip_smoke.py serves.
 
 Nothing runs — a pass says the TPU compiler accepts the program and it
-fits the device, never that it is fast or right.  The default-path
-executables must compile; each Pallas lowering the compiler still refuses
-is a strict xfail carrying the compiler's own words, so the PR that makes
-it lower has to remove the mark.
+fits the device, never that it is fast or right.
 
 The topology is described inside a module-scoped fixture (only one
 process may load the TPU library, so never at import), everything built
@@ -124,20 +121,7 @@ class _Shapes:
 
 
 def _compile(fn, *args):
-    if not hasattr(fn, "lower"):
-        fn = fn.__wrapped__  # past engine._recursion_guarded
-    # Room for a deep-but-finite Mosaic lowering, yet shallow enough that a
-    # lowering that recurses without end fails in seconds (the engine's own
-    # 20000-frame guard takes minutes to get there).  The error is re-raised
-    # without its thousands of frames: pytest prunes a recursive traceback
-    # in time quadratic in its depth.
-    from gubernator_tpu.ops.pallas_kernel import mosaic_recursion_guard
-    try:
-        with mosaic_recursion_guard(4000):
-            return fn.lower(*args).compile()
-    except RecursionError as e:
-        msg = str(e)
-    raise RecursionError(msg)
+    return fn.lower(*args).compile()
 
 
 def _fits(compiled, budget_bytes: int = 16 * 1024 ** 3) -> None:
@@ -198,7 +182,7 @@ def _arena_stays_in_place(compiled, C: int, staged_ok: bool) -> None:
             f"a prefetch: {line[:300]}")
 
 
-# ----------------------------------------------------- default path: compiles
+# ------------------------------------------------- the executables: compile
 
 
 @pytest.mark.parametrize("C,B,K", [(SMOKE_C, SMOKE_B, SMOKE_K),
@@ -208,13 +192,11 @@ def _arena_stays_in_place(compiled, C: int, staged_ok: bool) -> None:
                          ids=["smoke-10M", "daemon-default",
                               "smoke-10M-1024-lanes", "smoke-10M-4096-lanes"])
 def test_default_drain_compiles(one_chip, C, B, K):
-    """The serving drain every default deployment runs: K compact windows,
-    compact32-XLA body (GUBER_* lowering flags all at their defaults); and
-    the single-window drain at the two narrower lane buckets, which the
-    benchmark's edge cells run."""
+    """The serving drain every deployment runs: K compact windows of the
+    compact32 body; and the single-window drain at the two narrower lane
+    buckets, which the benchmark's edge cells run."""
     s = _Shapes(one_chip, C, B, K)
-    fn = engine_mod._compiled_pipeline_step_impl(
-        one_chip, False, True, False, True)
+    fn = engine_mod._compiled_pipeline_step(one_chip)
     c = _compile(fn, s.state, s.packed, s.nows)
     _fits(c)
     assert "tpu_custom_call" not in c.as_text()  # the XLA body, no Mosaic
@@ -225,8 +207,7 @@ def test_default_drain_compiles(one_chip, C, B, K):
 
 def test_global_drain_compiles_one_chip(one_chip):
     s = _Shapes(one_chip, DEF_C, DEF_B, SMOKE_K)
-    fn = engine_mod._compiled_pipeline_step_global_impl(
-        one_chip, False, True, False, True)
+    fn = engine_mod._compiled_pipeline_step_global(one_chip)
     _fits(_compile(fn, s.state, s.gstate, s.gcfg, s.packed, s.gbatch,
                    s.gacc, s.upd, s.nows))
 
@@ -235,8 +216,7 @@ def test_global_drain_compiles_four_chips(four_chips):
     """The lockstep mesh drain: four arena shards, ONE all-reduce (the
     GLOBAL hit-delta psum) in the whole K-window program."""
     s = _Shapes(four_chips, DEF_C, DEF_B, SMOKE_K)
-    fn = engine_mod._compiled_pipeline_step_global_impl(
-        four_chips, False, True, False, True)
+    fn = engine_mod._compiled_pipeline_step_global(four_chips)
     c = _compile(fn, s.state, s.gstate, s.gcfg, s.packed, s.gbatch,
                  s.gacc, s.upd, s.nows)
     _fits(c)
@@ -248,10 +228,9 @@ def test_global_drain_compiles_four_chips(four_chips):
 def test_global_drain_with_analytics_compiles(one_chip):
     conf = AnalyticsConfig()
     s = _Shapes(one_chip, DEF_C, DEF_B, SMOKE_K)
-    fn = engine_mod._compiled_pipeline_step_global_impl(
-        one_chip, False, True, False, True,
-        (conf.sketch_depth, conf.sketch_width, conf.tenant_slots,
-         conf.topk, conf.over_weight))
+    fn = engine_mod._compiled_pipeline_step_global(
+        one_chip, (conf.sketch_depth, conf.sketch_width, conf.tenant_slots,
+                   conf.topk, conf.over_weight))
     _fits(_compile(fn, s.state, s.gstate, s.gcfg, s.packed, s.gbatch,
                    s.gacc, s.upd, s.nows, s.sketch(conf), s.tenants,
                    s.now))
@@ -259,14 +238,14 @@ def test_global_drain_with_analytics_compiles(one_chip):
 
 def test_legacy_step_compiles(one_chip):
     s = _Shapes(one_chip, DEF_C, DEF_B, 1)
-    fn = engine_mod._compiled_step_impl(one_chip, False)
+    fn = engine_mod._compiled_step(one_chip)
     _fits(_compile(fn, s.state, s.gstate, s.gcfg, s.batch, s.gbatch,
                    s.gacc, s.upd, s.ups, s.now))
 
 
 def test_compact_step_compiles(one_chip):
     s = _Shapes(one_chip, DEF_C, DEF_B, 1)
-    fn = engine_mod._compiled_step_compact_impl(one_chip, False, True, False)
+    fn = engine_mod._compiled_step_compact(one_chip)
     _fits(_compile(fn, s.state, s.gstate, s.gcfg, s.packed1, s.gbatch,
                    s.gacc, s.upd, s.ups, s.now))
 
@@ -274,7 +253,7 @@ def test_compact_step_compiles(one_chip):
 @pytest.mark.parametrize("with_global", [True, False])
 def test_multi_step_compiles(one_chip, with_global):
     s = _Shapes(one_chip, DEF_C, DEF_B, SMOKE_K)
-    fn = engine_mod._compiled_multi_step_impl(one_chip, False, with_global)
+    fn = engine_mod._compiled_multi_step(one_chip, with_global)
     _fits(_compile(fn, s.state, s.gstate, s.gcfg, s.batches, s.gbatches,
                    s.gaccs, s.upd, s.ups, s.nows))
 
@@ -287,56 +266,3 @@ def test_analytics_reduce_compiles(one_chip):
         conf.topk, conf.over_weight)
     _fits(_compile(fn, s.sketch(conf), s.state.expire_lo, s.state.expire_hi,
                    s.packed, s.words, s.tenants, s.now, s.now))
-
-
-# ------------------------------------------- opt-in Pallas lowerings: refused
-#
-# Each case is the drain executable one GUBER_PALLAS* flag selects, at the
-# daemon's default widths.  The reasons are the chip compiler's own words
-# (PR 24).  A strict xfail: the PR that makes one lower must delete its mark
-# AND the matching entry of engine._MOSAIC_REFUSED, which is what makes the
-# engine raise at construction when the flag is set on a TPU mesh.
-
-_PALLAS_CASES = {
-    # name: ((pallas, c32xla, fused, staged), raises, the compiler's words)
-    "GUBER_PALLAS": (
-        (True, True, False, True), RecursionError,
-        "RecursionError: maximum recursion depth exceeded (Mosaic's lowering "
-        "of _window_math_kernel recurses without end on a 64-bit to 32-bit "
-        "convert_element_type: python-int operands of jnp.clip/jnp.where "
-        "trace as weak int64 under x64)"),
-    "GUBER_PALLAS_FUSED+STAGED=0": (
-        (False, True, True, False), NotImplementedError,
-        "NotImplementedError: Only 2D gather is supported (the fused body's "
-        "bitonic sort gathers 1-D (B,) lanes with jnp.take)"),
-    "GUBER_PALLAS_FUSED": (
-        (False, True, True, True), ValueError,
-        "ValueError: The Pallas TPU lowering currently requires that the last "
-        "two dimensions of your block shape are divisible by 8 and 128 "
-        "respectively, or be equal to the respective dimensions of the "
-        "overall array. Block spec for args[0] in pallas_call drain_kernel "
-        "has block shape (1, 2), array shape (8, 2)"),
-}
-
-
-@pytest.mark.parametrize("name", [
-    pytest.param(n, marks=pytest.mark.xfail(strict=True, raises=exc,
-                                            reason=why))
-    for n, (_flags, exc, why) in _PALLAS_CASES.items()])
-def test_pallas_drain_lowering(one_chip, name):
-    s = _Shapes(one_chip, DEF_C, DEF_B, SMOKE_K)
-    fn = engine_mod._compiled_pipeline_step_impl(one_chip,
-                                                 *_PALLAS_CASES[name][0])
-    c = _compile(fn, s.state, s.packed, s.nows)
-    assert "tpu_custom_call" in c.as_text()
-
-
-def test_refused_flags_raise_at_engine_construction(one_chip, monkeypatch):
-    """A refused lowering's flag on a TPU mesh is an error before any
-    executable is built — never a silent XLA body."""
-    for flag in engine_mod._MOSAIC_REFUSED:
-        monkeypatch.setenv(flag, "1")
-        with pytest.raises(RuntimeError, match=flag):
-            engine_mod._check_lowering_flags(one_chip)
-        monkeypatch.delenv(flag)
-    engine_mod._check_lowering_flags(one_chip)  # defaults: accepted
