@@ -260,8 +260,9 @@ class WindowBatcher:
                     legacy = must or any(windows)
                 if drain_fut is not None:
                     count("drain")
-                elif pipe is not None and pipe._hold_reason in ("gate",
-                                                                "depth"):
+                elif pipe is not None and pipe._hold_reason not in (
+                        None, "empty"):
+                    # queued work the pump holds back, whatever the reason
                     count("held")
                 elif not legacy:
                     count("idle")

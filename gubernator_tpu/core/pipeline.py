@@ -44,11 +44,18 @@ arrays at submit time (RequestColumns), so window packing takes zero-copy
 column slices instead of walking request objects.
 
 The pump is occupancy-gated (GUBER_PIPELINE_GATE): with a drain already in
-flight, a new drain dispatches only once the estimated staged lanes would
-fill ~one window (GUBER_PIPELINE_GATE_FRAC of B·S).  On a host whose
-dispatch cost is fill-independent this maximizes decisions-per-dispatch
-without adding latency — an outstanding completion always re-pumps, and
-the gate disarms at in_flight == 0, so it can never deadlock.
+flight, the next one is held for one of two reasons, both observed.  Less
+than one batch (coalesce_min decisions, the estimate _pump compares with
+it when nothing is out) is queued: below a batch a drain's cost is fixed,
+so accumulating while one is out is free.  Or the engine thread has not
+finished packing and enqueuing the drain before (_predispatch >= 1): a
+drain pumped now would only wait in the single-thread executor with its
+jobs already taken, so it is left to absorb what arrives meanwhile.
+Otherwise it goes, and its pack runs while the device executes the drain
+in flight: from a batch upward a drain's cost follows its fill (lane
+buckets, a pack linear in its items), so waiting for a full window buys
+nothing.  _on_dispatched and every completion re-pump and the gate is off
+at in_flight == 0, so it can never strand work.
 
 Reference analog: a peer draining its queue ships batches back-to-back
 without waiting for each response (peers.go:143-172); the reference's
@@ -107,7 +114,8 @@ from gubernator_tpu.config import (CHAIN_LINGER_MS_DEFAULT,
 from gubernator_tpu.core.engine import PIPELINE_K_BUCKETS
 from gubernator_tpu.core.window_buffers import RequestColumns, WindowArenaRing
 from gubernator_tpu.net.faults import FAULTS, SEAM_ENGINE_DISPATCH
-from gubernator_tpu.observability.metrics import (LOCKSTEP_LANES,
+from gubernator_tpu.observability.metrics import (DRAIN_AHEAD,
+                                                  LOCKSTEP_LANES,
                                                   LOCKSTEP_TICK_KINDS,
                                                   PUMP_HOLD_REASONS)
 from gubernator_tpu.observability.tracing import current_context
@@ -700,13 +708,10 @@ class DispatchPipeline:
         # differential suite compares against.
         self.depth = env_int("GUBER_PIPELINE_DEPTH", 3) if depth is None \
             else depth
-        # occupancy gate (see module docstring): with a drain in flight,
-        # hold the next dispatch until ~gate_frac of one window's lanes
-        # are pending.  Dispatch cost is fill-independent (the executable
-        # shape is fixed per bucket), so fuller windows are strictly more
-        # decisions per unit of engine-thread time.
+        # occupancy gate (see module docstring and _held): with a drain in
+        # flight, hold the next dispatch while less than one batch is
+        # queued or the engine thread is still busy with the drain before.
         self.gate_enabled = env_bool("GUBER_PIPELINE_GATE", True)
-        self.gate_frac = env_float("GUBER_PIPELINE_GATE_FRAC", 1.0)
         # DEBUG ONLY: block until the device finishes each dispatch so the
         # stage stamps attribute wall time exactly (host-encode vs device
         # vs fetch).  This is a deliberate host sync point — it serializes
@@ -777,6 +782,9 @@ class DispatchPipeline:
         self.pump_hold = dict.fromkeys(PUMP_HOLD_REASONS, 0.0)
         # drains dispatched per lane width (engine thread; see _drain_lanes)
         self.drain_widths = dict.fromkeys(engine._lane_bucket_list, 0)
+        # drains by how many others were in flight when the pump let them
+        # go (loop thread; see _note_dispatch)
+        self.drain_overlap = dict.fromkeys(DRAIN_AHEAD, 0)
         # reply_wake of the requests that have resumed since the last
         # commit (plain floats; the next commit flushes them)
         self._wake_seconds = 0.0
@@ -1165,12 +1173,7 @@ class DispatchPipeline:
         if self._held(depth):
             return
         if not force and self.coalesce_wait > 0:
-            # RpcJobs are unparsed here: estimate items from the wire size
-            # (>= ~16B/item, so this overestimates — big RPCs never wait)
-            pending = (len(self._singles)
-                       + sum(len(j.data) // 16 if isinstance(j, RpcJob)
-                             else j.n for j in self._jobs))
-            if 0 < pending < self.coalesce_min:
+            if 0 < self._pending_decisions() < self.coalesce_min:
                 if self._coalesce_handle is None:
                     self._coalesce_handle = self._loop.call_later(
                         self.coalesce_wait, self._coalesce_fire)
@@ -1190,13 +1193,20 @@ class DispatchPipeline:
                 self._chain_flush()
             self._note_hold("empty")
             return
-        self._note_hold(None)
-        self._note_inflight(1)
-        self._predispatch += 1
+        self._note_dispatch()
         fut = self._loop.run_in_executor(self._engine_executor,
                                          self._drain_sync, jobs, None, None,
                                          None, cols, time.monotonic())
         fut.add_done_callback(lambda f: self._on_dispatched(f, jobs))
+
+    def _pending_decisions(self) -> int:
+        """Decisions queued behind the pipeline (loop thread).  RpcJobs are
+        unparsed here: their items are estimated from the wire size (an
+        item is >= ~16 B, so this overestimates: a big RPC always counts
+        as a batch)."""
+        return (len(self._singles) + len(self._gsingles)
+                + sum(len(j.data) // 16 if isinstance(j, RpcJob)
+                      else j.n for j in self._jobs))
 
     def _held(self, depth: int) -> bool:
         """Do the pipeline's depth or its occupancy gate hold the next
@@ -1207,26 +1217,38 @@ class DispatchPipeline:
             self._note_hold("depth" if self._singles or self._jobs
                             or self._gsingles else None)
             return True
-        if self.gate_enabled and self._in_flight >= 1 and self.gate_frac > 0:
-            # occupancy gate: a drain is already hiding the device time, so
-            # hold the next dispatch until the pending work would fill
-            # ~gate_frac of one window's lanes.  Estimate lanes from queued
-            # decisions via the live duplicate-fold factor.  No timer
-            # needed: the in-flight drain's completion re-pumps (in
-            # lockstep the next tick asks again), and at in_flight == 0
-            # the gate is off — it can never strand work.
-            fold = (self.decisions_staged / self.lanes_staged
-                    if self.lanes_staged > MAX_BATCH_SIZE else 1.0)
-            pending = (len(self._singles) + len(self._gsingles)
-                       + sum(len(j.data) // 16 if isinstance(j, RpcJob)
-                             else j.n for j in self._jobs))
-            lanes_est = pending / max(fold, 1.0)
-            eng = self.engine
-            if lanes_est < (self.gate_frac * eng.batch_per_shard
-                            * eng.num_local_shards):
+        if self.gate_enabled and self._in_flight >= 1:
+            # occupancy gate: a drain is already hiding the device time.
+            # Under one batch a drain's cost is fixed, so what is queued
+            # goes on accumulating (`gate`); with the engine thread still
+            # on the drain before, a drain pumped now would only wait in
+            # its executor with its jobs already taken (`engine`).  No
+            # timer needed: _on_dispatched and the in-flight drain's
+            # completion re-pump (in lockstep the next tick asks again),
+            # and at in_flight == 0 the gate is off — it can never strand
+            # work.
+            pending = self._pending_decisions()
+            if pending < self.coalesce_min:
                 self._note_hold("gate" if pending else "empty")
                 return True
+            if self._predispatch >= 1:
+                self._note_hold("engine")
+                return True
         return False
+
+    def _note_dispatch(self) -> None:
+        """The pump lets a drain go (loop thread): the hold ends, the drain
+        counts under how many others are in flight ahead of it
+        (guber_tpu_drain_overlap_total; DRAIN_AHEAD's last value stands
+        for itself and more), and it is in flight and heading for the
+        engine thread."""
+        self._note_hold(None)
+        ahead = DRAIN_AHEAD[min(self._in_flight, len(DRAIN_AHEAD) - 1)]
+        self.drain_overlap[ahead] += 1
+        if self.metrics is not None:
+            self.metrics.drain_overlap.labels(ahead=ahead).inc()
+        self._note_inflight(1)
+        self._predispatch += 1
 
     def _note_hold(self, reason: Optional[str]) -> None:
         """_pump returns without dispatching for `reason`, or dispatches
@@ -1284,12 +1306,9 @@ class DispatchPipeline:
         thread) — the stride controller's growth signal."""
         fold = (self.decisions_staged / self.lanes_staged
                 if self.lanes_staged > MAX_BATCH_SIZE else 1.0)
-        pending = (len(self._singles)
-                   + sum(len(j.data) // 16 if isinstance(j, RpcJob)
-                         else j.n for j in self._jobs))
         eng = self.engine
         lanes = eng.batch_per_shard * eng.num_local_shards
-        return (pending / max(fold, 1.0)) / max(lanes, 1)
+        return (self._pending_decisions() / max(fold, 1.0)) / max(lanes, 1)
 
     def _chain_add(self, res: _DrainResult) -> None:
         """Append a dispatched-but-unfetched drain to the chain (loop
@@ -1458,12 +1477,10 @@ class DispatchPipeline:
                 return None
             if self._held(self.depth):
                 return None
-            self._note_hold(None)
         jobs, cols = self._take_jobs() if not self._closed else ([], None)
         gjob = self._take_global_job() if not self._closed else None
         all_jobs = jobs + ([gjob] if gjob is not None else [])
-        self._note_inflight(1)
-        self._predispatch += 1
+        self._note_dispatch()
         pumped = time.monotonic()
         fut = self._loop.run_in_executor(
             self._engine_executor,

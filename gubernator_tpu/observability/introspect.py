@@ -225,6 +225,7 @@ def build_debug_snapshot(instance) -> dict:
             "overlap": pipe.overlap_snapshot(),
             "pump_hold_seconds": pipe.pump_hold_snapshot(),
             "drain_widths": dict(pipe.drain_widths),
+            "drain_overlap": dict(pipe.drain_overlap),
         }
         if pipe.lockstep:
             clock = instance.batcher.clock

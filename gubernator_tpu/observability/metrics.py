@@ -57,13 +57,17 @@ REQUEST_STAGES = ("queue_wait", "in_drain", "reply_wake")
 
 # Why _pump returned without dispatching
 # (guber_tpu_pump_hold_seconds_total): `empty` = room for a drain and
-# nothing queued, `gate` = the occupancy gate, `coalesce` = the batch-wait
-# timer, `depth` = work queued behind a full pipeline.
-PUMP_HOLD_REASONS = ("empty", "gate", "coalesce", "depth")
+# nothing queued, `gate` = the occupancy gate (a drain in flight and less
+# than one batch queued), `coalesce` = the batch-wait timer, `depth` = work
+# queued behind a full pipeline, `engine` = a batch queued and room under
+# the depth, but the engine thread is still packing or enqueuing the drain
+# before.
+PUMP_HOLD_REASONS = ("empty", "gate", "coalesce", "depth", "engine")
 
 # What a lockstep tick did (guber_tpu_lockstep_ticks_total): `drain` = it
 # dispatched staged work, `idle` = nothing was queued, `held` = work was
-# queued behind the pipeline's depth or its occupancy gate, `skipped` =
+# queued behind the pipeline's depth, its occupancy gate or a busy engine
+# thread, `skipped` =
 # whole periods passed over because the host was behind its deadlines
 # (they are no ticks: nothing ran).  How a lockstep decision came
 # (guber_tpu_lockstep_decisions_total): `raw` = in a whole RPC staged by
@@ -77,6 +81,13 @@ LOCKSTEP_LANES = ("raw", "item", "legacy")
 # values, so a reader can name them whatever the engine's B is; the count
 # per width is `pipeline.drain_widths` in /v1/admin/debug.
 DRAIN_WIDTHS = ("narrow", "full")
+
+# How many other drains were in flight (pumped and not yet committed) when
+# the pump let a drain go (guber_tpu_drain_overlap_total): "0" = the
+# pipeline was empty, so the drain runs alone unless a later one joins it,
+# "2" = two or more.  The same counts are `pipeline.drain_overlap` in
+# /v1/admin/debug.
+DRAIN_AHEAD = ("0", "1", "2")
 
 
 class _StageRing:
@@ -404,7 +415,8 @@ class Metrics:
         self.pump_hold_seconds = Counter(
             "guber_tpu_pump_hold_seconds_total",
             "Seconds the pump held no dispatch, by reason (empty = room "
-            "for a drain and nothing queued | gate | coalesce | depth).",
+            "for a drain and nothing queued | gate | coalesce | depth | "
+            "engine = the engine thread still busy with the drain before).",
             ["reason"],
             registry=self.registry,
         )
@@ -413,6 +425,13 @@ class Metrics:
             "Drains dispatched, by the lane width of their executable "
             "(narrow = a lane bucket below batch_per_shard | full).",
             ["width"],
+            registry=self.registry,
+        )
+        self.drain_overlap = Counter(
+            "guber_tpu_drain_overlap_total",
+            "Drains dispatched, by how many others were in flight when "
+            "the pump let them go (0 | 1 | 2 = two or more).",
+            ["ahead"],
             registry=self.registry,
         )
         self.lockstep_ticks = Counter(
@@ -459,6 +478,8 @@ class Metrics:
             self.pump_hold_seconds.labels(reason=reason)
         for width in DRAIN_WIDTHS:
             self.drains.labels(width=width)
+        for ahead in DRAIN_AHEAD:
+            self.drain_overlap.labels(ahead=ahead)
         for kind in LOCKSTEP_TICK_KINDS:
             self.lockstep_ticks.labels(kind=kind)
         for lane in LOCKSTEP_LANES:

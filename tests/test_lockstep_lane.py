@@ -6,6 +6,7 @@ batch, and a profile capture under lockstep serving."""
 
 import asyncio
 import os
+import threading
 
 import jax
 import numpy as np
@@ -321,6 +322,62 @@ def test_whole_rpcs_with_global_items_match_the_reference_answer_by_answer():
     assert 'guber_tpu_lockstep_decisions_total{lane="raw"} 3000.0' in text
     assert "guber_tpu_global_decisions_total 300.0" in text
     assert 'guber_tpu_stage_duration_ms_count{stage="tick_lag"}' in text
+
+
+@pytestmark_native
+def test_a_second_lockstep_drain_goes_while_one_is_out():
+    """The mesh's tick asks the same gate as the standalone pump: with a
+    drain out and the engine thread free, an RPC queued meanwhile (a batch
+    by itself) goes on the next tick, counted ahead="1"; each drain answers
+    under its own tick's timestamp, and the hot tenant's hits of the first
+    have landed, once, before the second reads the row."""
+    m = Metrics()
+    eng, b, nows = lockstep_batcher(m)
+    p = b.pipeline
+    assert p.gate_enabled and p.depth >= 2
+    a, c = thousand(4), thousand(5)
+    ref = Reference()
+    fetch_go = threading.Event()
+    inner = p._complete_sync
+
+    def held_fetch(res):
+        fetch_go.wait(30.0)     # a dispatched drain stays in flight
+        return inner(res)
+    p._complete_sync = held_fetch
+
+    async def until(cond):
+        while not cond():
+            await asyncio.sleep(0.005)
+
+    async def run():
+        b.start_lockstep()
+        first = asyncio.ensure_future(b.submit_rpc(rpc_bytes(a)))
+        await until(lambda: p._in_flight == 1 and p._predispatch == 0)
+        second = asyncio.ensure_future(b.submit_rpc(rpc_bytes(c)))
+        await until(lambda: p._in_flight == 2)
+        fetch_go.set()
+        return await asyncio.gather(first, second)
+    try:
+        with time_limit(240):
+            first, second = asyncio.run(run())
+    finally:
+        fetch_go.set()
+        b.close()
+    assert p.drain_overlap == {"0": 1, "1": 1, "2": 0}
+    assert m.registry.get_sample_value(
+        "guber_tpu_drain_overlap_total", {"ahead": "1"}) == 1.0
+    served = [n for n in nows if n[1]]
+    assert [n[1] for n in served] == [1000, 1000], nows
+    assert served[0][0] < served[1][0]
+    for data, reqs, (now, _, _) in ((first, a, served[0]),
+                                    (second, c, served[1])):
+        want = ref.window([reqs], now)
+        for i, g in enumerate(answers(data)):
+            same(g, want[0][i], (now, i))
+    hot = [i for i, r in enumerate(a) if r.unique_key == "tenant:0"]
+    assert {answers(second)[i].remaining
+            for i, r in enumerate(c) if r.unique_key == "tenant:0"} == \
+        {100_000 - len(hot)}
 
 
 @pytestmark_native

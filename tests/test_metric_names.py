@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from gubernator_tpu.observability.metrics import (DRAIN_WIDTHS,
+from gubernator_tpu.observability.metrics import (DRAIN_AHEAD, DRAIN_WIDTHS,
                                                   LOCKSTEP_LANES,
                                                   LOCKSTEP_TICK_KINDS,
                                                   PUMP_HOLD_REASONS,
@@ -50,6 +50,8 @@ NATIVE_NAMES = (
     "guber_tpu_pump_hold_seconds_total",
     # lane-bucketed serving drain (core/pipeline.py _drain_lanes)
     "guber_tpu_drains_total",
+    # drains let go beside a drain in flight (core/pipeline.py _held)
+    "guber_tpu_drain_overlap_total",
     # deferred-fetch dispatch chain (core/pipeline.py)
     "guber_tpu_chain_fetch_stride",
     # multi-process front door (frontdoor.py, core/shm_ring.py)
@@ -211,6 +213,7 @@ def test_removed_series_stay_removed(name):
     ("guber_tpu_request_stage_requests_total", "stage", REQUEST_STAGES),
     ("guber_tpu_pump_hold_seconds_total", "reason", PUMP_HOLD_REASONS),
     ("guber_tpu_drains_total", "width", DRAIN_WIDTHS),
+    ("guber_tpu_drain_overlap_total", "ahead", DRAIN_AHEAD),
     ("guber_tpu_lockstep_ticks_total", "kind", LOCKSTEP_TICK_KINDS),
     ("guber_tpu_lockstep_decisions_total", "lane", LOCKSTEP_LANES),
 ])

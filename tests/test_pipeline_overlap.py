@@ -14,9 +14,13 @@ and the completion queue only demuxes.  This suite pins that:
     the faulted drain's jobs with NO partial commit; neighbors and
     subsequent drains serve normally
   * window arenas actually recycle (reuse accounting + metric)
+  * the occupancy gate's rule, and the same differential with the gate ON
+    (the served configuration): a batch queued behind a drain in flight
+    goes as soon as the engine thread is free
 """
 
 import asyncio
+import threading
 
 import numpy as np
 import pytest
@@ -24,11 +28,12 @@ import pytest
 import gubernator_tpu  # noqa: F401
 from gubernator_tpu import native
 from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
-from gubernator_tpu.config import BehaviorConfig
+from gubernator_tpu.config import MAX_BATCH_SIZE, BehaviorConfig
 from gubernator_tpu.core.batcher import WindowBatcher
 from gubernator_tpu.core.engine import RateLimitEngine
 from gubernator_tpu.net.faults import FAULTS, SEAM_ENGINE_DISPATCH
 from gubernator_tpu.observability.metrics import Metrics
+from tests.benchmark.helpers import time_limit
 
 pytestmark = [
     pytest.mark.overlap,
@@ -273,6 +278,127 @@ def test_commit_queue_ordering_under_fault_between_drains():
     want3 = ref.process(r3, now=T0)  # round 2 on the oracle: r2 never landed
     _check(got1, want1, "round1")
     _check(got3, want3, "round3")
+
+
+def _thousand(rng, tag, keys=40):
+    """One whole batch (upstream's limit, 1000 items): token and leaky,
+    every key many times over (folds), some with no hits."""
+    return [RateLimitReq(name="gt", unique_key=f"{tag}{rng.integers(0, keys)}",
+                         hits=int(rng.integers(0, 3)), limit=500,
+                         duration=60_000, algorithm=int(rng.integers(0, 2)))
+            for _ in range(MAX_BATCH_SIZE)]
+
+
+@pytest.mark.parametrize("queued,engine_busy,want", [
+    (MAX_BATCH_SIZE, False, None),          # a batch, engine free: it goes
+    (MAX_BATCH_SIZE - 1, False, "gate"),    # under a batch: accumulates
+    (MAX_BATCH_SIZE, True, "engine"),       # a batch, engine thread busy
+])
+def test_gate_with_a_drain_in_flight(queued, engine_busy, want):
+    """The gate as served (on), with one drain out and room under the
+    depth: a queued batch is dispatched as soon as the engine thread is
+    free (the pipeline holds two drains, the second counted ahead="1");
+    less than a batch is held under `gate` until the drain out commits; a
+    batch behind a busy engine thread is held under `engine` and goes
+    beside the first drain the moment that one has been dispatched.  Every
+    answer is the oracle's."""
+    eng = _engine()
+    ref = _engine(False)
+    m = Metrics()
+    b = _batcher(eng, 3, metrics=m)
+    p = b.pipeline
+    p.gate_enabled = True
+    assert p.coalesce_min == MAX_BATCH_SIZE
+    rng = np.random.default_rng(53)
+    first = _burst(rng, 0)
+    second = _thousand(rng, "g")[:queued]
+    fetch_go, engine_go = threading.Event(), threading.Event()
+    inner = p._complete_sync
+
+    def held_fetch(res):
+        fetch_go.wait(10.0)     # a dispatched drain stays in flight
+        return inner(res)
+    p._complete_sync = held_fetch
+
+    def ahead(n):
+        return m.registry.get_sample_value(
+            "guber_tpu_drain_overlap_total", {"ahead": n})
+
+    async def until(cond):
+        while not cond():
+            await asyncio.sleep(0.005)
+
+    async def run():
+        if engine_busy:
+            p._engine_executor.submit(engine_go.wait, 10.0)
+        t1 = asyncio.ensure_future(b.submit_now(first))
+        # pumped; and, with the engine thread free, dispatched
+        await until(lambda: p._in_flight == 1
+                    and p._predispatch == int(engine_busy))
+        assert (ahead("0"), ahead("1")) == (1.0, 0.0)
+        t2 = asyncio.ensure_future(b.submit_now(second))
+        await asyncio.sleep(0.05)
+        state = (p._hold_reason, p._in_flight, ahead("1"))
+        if engine_busy:
+            engine_go.set()     # the first is dispatched; its fetch waits
+            await until(lambda: p._in_flight == 2)
+        fetch_go.set()
+        return state, await asyncio.gather(t1, t2)
+
+    try:
+        with time_limit(120):
+            state, (got1, got2) = asyncio.run(run())
+    finally:
+        fetch_go.set()
+        engine_go.set()
+        b.close()
+    if want is None:
+        assert state == (None, 2, 1.0), state
+    else:
+        assert state == (want, 1, 0.0), state
+        assert p.pump_hold[want] >= 0.04, p.pump_hold
+    # held under `gate`, the work went when the drain out had committed,
+    # with nothing ahead; in the other two it went beside the first
+    assert p.drain_overlap == ({"0": 2, "1": 0, "2": 0} if want == "gate"
+                               else {"0": 1, "1": 1, "2": 0})
+    _check(got1, ref.process(first, now=T0), "first")
+    _check(got2, ref.process(second, now=T0), "second")
+    assert p._in_flight == 0 and p._predispatch == 0
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_gate_on_whole_batches_back_to_back_match_the_serial_oracle(depth):
+    """The differential with the gate ON: 1000-item jobs submitted back to
+    back ride overlapped drains (the gate lets each go once the engine
+    thread is free, up to the depth) and every answer equals the depth-1
+    oracle's, batch by batch in submission order."""
+    eng = _engine()
+    ref = _engine(False)
+    rng = np.random.default_rng(61 + depth)
+    batches = [_thousand(rng, "b") for _ in range(6)]
+    b = _batcher(eng, depth)
+    b.pipeline.gate_enabled = True
+
+    async def run():
+        tasks = []
+        for batch in batches:
+            tasks.append(asyncio.ensure_future(b.submit_now(batch)))
+            await asyncio.sleep(0)
+        return await asyncio.gather(*tasks)
+
+    try:
+        got = asyncio.run(run())
+    finally:
+        b.close()
+    for i, batch in enumerate(batches):
+        _check(got[i], ref.process(batch, now=T0), i)
+    p = b.pipeline
+    assert p.decisions_staged == 6 * MAX_BATCH_SIZE
+    # the served configuration overlapped: some drain went beside another
+    assert p.drain_overlap["1"] + p.drain_overlap["2"] >= 1, p.drain_overlap
+    assert sum(p.drain_overlap.values()) >= 2
+    if depth == 2:
+        assert p.drain_overlap["2"] == 0
 
 
 def test_arena_ring_recycles_buffers():

@@ -24,7 +24,8 @@ from gubernator_tpu.core import pipeline as pipeline_mod
 from gubernator_tpu.core.batcher import WindowBatcher
 from gubernator_tpu.core.engine import RateLimitEngine
 from gubernator_tpu.core.service import Instance
-from gubernator_tpu.observability.metrics import (PUMP_HOLD_REASONS,
+from gubernator_tpu.observability.metrics import (DRAIN_AHEAD, DRAIN_WIDTHS,
+                                                  PUMP_HOLD_REASONS,
                                                   REQUEST_STAGES, Metrics)
 from gubernator_tpu.server import GrpcServer
 from tests.benchmark import xplane_writer
@@ -36,7 +37,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 METHOD = {"method": "/pb.gubernator.V1/GetRateLimits"}
 NEW_LAYER_METRICS = ("queue_wait_ms", "loop_hop_ms", "pump_empty_pct",
                      "pump_gated_pct", "decode_ms", "reply_wake_ms",
-                     "server_rpc_ms", "outside_server_ms")
+                     "server_rpc_ms", "outside_server_ms",
+                     "overlapped_drain_pct")
 
 
 def reqs(prefix, n=8):
@@ -284,6 +286,56 @@ def test_debug_snapshot_and_cli_show_memory_and_holds(node, capsys,
     out = capsys.readouterr().out
     assert "device memory: bytes_in_use=5.0MB peak_bytes_in_use=7.0MB" in out
     assert "pump held (s): empty=" in out
+    assert "drain_overlap={'0': " in out and "drain_widths={" in out
+
+
+@pytest.mark.parametrize("name", ["overlapped_drain_pct.tput",
+                                  "overlapped_drain_pct.lat"])
+def test_overlapped_drain_pct_reads_the_pumps_counter(node, name):
+    """Whole batches sent side by side: every dispatched drain is counted
+    once under the drains that were ahead of it, /metrics and
+    /v1/admin/debug agree, the file gives 100 x (ahead 1 + ahead 2) / all;
+    a program without the series (the parent) gives nothing to read."""
+    loop, inst, server, http = node
+    spec = harness.Bench(REPO).layer_file(name)
+
+    def counts(snap):
+        return [snap["prom"][("guber_tpu_drain_overlap_total",
+                              (("ahead", a),))] for a in DRAIN_AHEAD]
+
+    async def body():
+        before = await snapshot(http)
+        assert harness.evaluate(
+            spec["read"], {"before": before, "after": before}) is None
+        client = AsyncClient(server.address)
+        try:
+            for _ in range(3):
+                got = await asyncio.gather(*[
+                    client.get_rate_limits(reqs(f"o{i}_", 1000))
+                    for i in range(6)])
+                assert all(r.error == "" for rs in got for r in rs)
+        finally:
+            await client.close()
+        await settle(inst)
+        after = await snapshot(http)
+        c0, c1 = counts(before), counts(after)
+        assert after["debug"]["pipeline"]["drain_overlap"] == dict(
+            zip(DRAIN_AHEAD, map(int, c1)))
+        went = [b - a for a, b in zip(c0, c1)]
+        drains = sum(
+            after["prom"][("guber_tpu_drains_total", (("width", w),))]
+            - before["prom"][("guber_tpu_drains_total", (("width", w),))]
+            for w in DRAIN_WIDTHS)
+        assert sum(went) == drains >= 3
+        assert went[0] >= 1                 # the first of each round
+        v = harness.evaluate(spec["read"], {"before": before, "after": after})
+        assert v == pytest.approx(100.0 * (went[1] + went[2]) / sum(went))
+        parent = {"prom": {k: x for k, x in after["prom"].items()
+                           if k[0] != "guber_tpu_drain_overlap_total"},
+                  "debug": after["debug"]}
+        assert harness.evaluate(
+            spec["read"], {"before": parent, "after": parent}) is None
+    loop.run_until_complete(body())
 
 
 # ------------------------------------------------------ (c) the pump's holds
@@ -293,8 +345,10 @@ def test_debug_snapshot_and_cli_show_memory_and_holds(node, capsys,
 def test_pump_hold_adds_to_its_reason_and_to_no_other(reason):
     m = Metrics()
     b, p = make_batcher(m, depth=1 if reason == "depth" else 3)
-    if reason == "gate":
-        p.gate_enabled, p.gate_frac = True, 1.0
+    if reason in ("gate", "engine"):
+        p.gate_enabled = True
+    if reason == "engine":
+        p.coalesce_min = 8                  # the 8 queued requests: a batch
     if reason == "coalesce":
         p.coalesce_wait = 0.05
     gate = threading.Event()
@@ -311,8 +365,9 @@ def test_pump_hold_adds_to_its_reason_and_to_no_other(reason):
             # one queued request, room for a drain: the batch-wait timer
             await asyncio.gather(*[p.submit_one(r) for r in reqs("c", 2)])
             return
-        # depth and gate: a drain in flight (the engine thread is held),
-        # and work queued behind it
+        # depth, gate and engine: a drain in flight (the engine thread is
+        # held), and work queued behind it: under a batch of it (gate), or
+        # a batch that the busy engine thread keeps waiting (engine)
         p._engine_executor.submit(gate.wait, 5.0)
         first = asyncio.ensure_future(b.submit_now(reqs("f")))
         await asyncio.sleep(0.01)
